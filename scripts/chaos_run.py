@@ -9,7 +9,7 @@ three ways and checks convergence:
    (device loss on one jterator batch, an IO fault on another, both
    outlasting every retry).  The run must *survive* by quarantining the
    two batches under the 0.5 failure budget.
-3. **resume** — the plan cleared (the "relay came back" moment),
+3. **resume** — the plan cleared (the "device came back" moment),
    ``resume=True``.  The store must now equal the reference bit-for-bit.
 
 Exit code 0 and ``CHAOS PASS`` on convergence; 1 otherwise.  This is
@@ -32,7 +32,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-# a down relay must not hang the smoke run itself
+# a CI smoke is a CPU run, whatever is attached
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np  # noqa: E402

@@ -43,7 +43,7 @@ REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 sys.path.insert(0, str(REPO / "scripts"))
 
-# a down relay must not hang the smoke run itself
+# a CI smoke is a CPU run, whatever is attached
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from chaos_run import make_source, make_store  # noqa: E402

@@ -259,7 +259,7 @@ def perf_section() -> list[str]:
            "",
            "Surfaced via `tmx perf --root DIR [--top N] [--json]`, "
            "`tmx perf history`, `tmx_perf_*` metrics in `tmx metrics`, "
-           "and the CI/watcher sentinel `scripts/bench_regression.py` "
+           "and the CI sentinel `scripts/bench_regression.py` "
            "(exit 0 ok / 1 regression / 2 stale / 3 no baseline).",
            "",
            "| symbol | role |", "|---|---|"]

@@ -4,10 +4,11 @@
 bounding boxes, convex hulls) is a plain ctypes shared library, not a
 CPython extension — so instead of Extension/build_ext machinery it is
 compiled with the ambient C++ compiler and shipped as package data
-(``tmlibrary_tpu/libtmnative.so``).  ``tmlibrary_tpu.native`` searches the
-package directory first, then the source tree, and can rebuild from source
-at import time, so editable installs and compiler-less environments both
-keep working (every native entry point has a scipy/numpy fallback).
+(``tmlibrary_tpu/libtmnative.so``).  ``tmlibrary_tpu.native`` builds from
+the tracked source whenever the source tree is there (a checkout, an
+editable install) and uses the packaged copy only in a wheel install,
+which has no source tree; compiler-less environments keep working (every
+native entry point has a scipy/numpy fallback, and the loader says so).
 """
 
 import shutil

@@ -149,9 +149,26 @@ def test_ivf_recall_across_top_p(rng):
     assert aidx.measure_recall(x, cent, mem, k=k) >= 0.95
     # wider probes never hurt
     assert self_recall(16) >= self_recall(4) - 1e-9
-    # top_p == n_cells probes every cell: exact brute force, recall 1.0
+    # top_p == n_cells probes every cell, so the candidate set is the
+    # whole population.  The two paths still round |q|^2 + |x|^2 - 2 q.x
+    # in different orders (per-cell gather vs one dense matmul), so two
+    # candidates whose true distances differ by less than one float32
+    # ulp (1.2e-7 relative) may swap at the k-th boundary.  Tolerance:
+    # every miss must be such a tie, judged on float64 distances.
+    n = x.shape[0]
+    rows = np.linspace(0, n - 1, min(aidx.RECALL_SAMPLE, n)).astype(np.int64)
+    q = x[rows]
+    exact_q, _ = ops.knn(x, k, queries=q)
+    ivf_q, _ = aidx.ivf_search_arrays(x, cent, mem, k, queries=q, top_p=c)
+    d = np.sqrt(((q[:, None, :].astype(np.float64)
+                  - x[None, :, :].astype(np.float64)) ** 2).sum(-1))
+    kth = np.sort(d, axis=1)[:, k - 1]
+    for r, (a, b) in enumerate(zip(ivf_q.tolist(), exact_q.tolist())):
+        for j in set(a) ^ set(b):
+            assert abs(d[r, j] - kth[r]) <= 1.2e-7 * kth[r], (r, j)
     assert self_recall(c) == 1.0
-    assert aidx.measure_recall(x, cent, mem, k=k, top_p=c) == 1.0
+    # one swapped tie in the 128 x 10 sample reads 0.99922
+    assert aidx.measure_recall(x, cent, mem, k=k, top_p=c) >= 1 - 2 / 1280
 
 
 def test_ivf_search_contract(rng):

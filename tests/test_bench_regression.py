@@ -1,7 +1,7 @@
 """The bench-regression sentinel: ``perf.compare_history`` verdicts and
 the pinned exit codes of ``scripts/bench_regression.py`` (0 ok/improvement,
-1 regression, 2 stale, 3 no baseline), plus the re-capture queue handoff
-into ``scripts/tpu_watch.py``."""
+1 regression, 2 stale, 3 no baseline), plus the re-capture queue the
+sentinel writes."""
 import json
 import os
 import subprocess
@@ -87,14 +87,14 @@ def test_compare_no_baseline():
 
 
 def test_compare_backend_class_collapse():
-    # cpu_forced and cpu_fallback are the same evidence class, and
-    # tpu_cached counts as hardware
+    # a forced CPU rehearsal is the same evidence class as a CPU run,
+    # and never the chip's
     hist = [_rec(100.0, backend="cpu_forced", age_h=30),
-            _rec(120.0, backend="cpu_fallback", age_h=1)]
+            _rec(120.0, backend="cpu", age_h=1)]
     assert perf.compare_history(hist, now=NOW)["status"] == "improvement"
     hist = [_rec(100.0, backend="tpu", age_h=30),
-            _rec(80.0, backend="tpu_cached", age_h=1)]
-    assert perf.compare_history(hist, now=NOW)["status"] == "regression"
+            _rec(80.0, backend="cpu_forced", age_h=1)]
+    assert perf.compare_history(hist, now=NOW)["status"] == "no_baseline"
 
 
 def test_compare_filters_and_sweep_label():
@@ -108,7 +108,7 @@ def test_compare_filters_and_sweep_label():
     assert v["recapture"] == ["sweep:volume"]  # sweep records re-sweep
     # error / non-positive records never participate
     hist = [_rec(100.0, age_h=30), _rec(0.0, age_h=2),
-            {**_rec(1.0, age_h=1), "error": "relay died"}]
+            {**_rec(1.0, age_h=1), "error": "device lost"}]
     v = perf.compare_history(hist, now=NOW)
     assert v["latest"]["value"] == 100.0
 
@@ -207,8 +207,7 @@ def test_cli_absent_and_empty_history_is_friendly_no_baseline(tmp_path):
 def test_workflow_status_survives_absent_bench_history(
         monkeypatch, tmp_path, capsys):
     """``tmx workflow status`` must render (exit 0) when the bench
-    history and the on-hardware bench cache are both absent — the
-    staleness advisory line just stays silent."""
+    history is absent."""
     from tmlibrary_tpu.cli import main
     from tmlibrary_tpu.models.experiment import Experiment
     from tmlibrary_tpu.models.store import ExperimentStore
@@ -218,8 +217,6 @@ def test_workflow_status_survives_absent_bench_history(
     store = ExperimentStore.create(tmp_path / "e", placeholder)
     monkeypatch.setenv("BENCH_HISTORY",
                        str(tmp_path / "no" / "BENCH_HISTORY.jsonl"))
-    monkeypatch.setenv("BENCH_TPU_CACHE",
-                       str(tmp_path / "no" / "BENCH_TPU.json"))
     assert main(["workflow", "status", "--root", str(store.root)]) == 0
     out = capsys.readouterr().out
     assert "bench records stale" not in out
@@ -255,63 +252,3 @@ def test_cli_writes_recapture_queue(tmp_path):
     doc = json.loads(queue.read_text())
     assert doc["items"] == ["bench:3"]
     assert "regression" in doc["reason"]
-
-
-# ------------------------------------------- tpu_watch queue pickup
-def test_tpu_watch_picks_up_validated_labels(monkeypatch, tmp_path):
-    monkeypatch.syspath_prepend(REPO)
-    from scripts import tpu_watch
-
-    queue = tmp_path / "RECAPTURE.json"
-    monkeypatch.setenv("WATCH_RECAPTURE", str(queue))
-    monkeypatch.delenv("WATCH_ONLY", raising=False)
-    assert tpu_watch.recapture_pending() == []
-
-    perf.write_recapture([
-        "bench:3",                  # known bench item
-        "sweep:volume",             # known sweep config
-        "sweep-capacity:4",         # known capacity-sweep config
-        "bench:nonsense",           # unknown: must be ignored
-        "sweep-capacity:pyramid",   # not a capacity config: ignored
-        "tune:pipeline",            # not a re-capture label shape
-    ])
-    assert tpu_watch.recapture_pending() == [
-        "bench:3", "sweep:volume", "sweep-capacity:4"]
-
-    # a fired capture clears its label; unknown labels stay in the file
-    # (harmless) but never reach the watcher
-    tpu_watch._clear_recapture("sweep:volume")
-    tpu_watch._clear_recapture("sweep-capacity:4")
-    assert tpu_watch.recapture_pending() == ["bench:3"]
-    tpu_watch._clear_recapture("bench:3")
-    assert tpu_watch.recapture_pending() == []
-    assert perf.load_recapture() == [
-        "bench:nonsense", "sweep-capacity:pyramid", "tune:pipeline"]
-
-
-def test_all_pending_dedupes_recapture(monkeypatch, tmp_path):
-    monkeypatch.syspath_prepend(REPO)
-    import bench
-    from scripts import tpu_watch
-
-    (tmp_path / "tuning").mkdir()
-    monkeypatch.setattr(tpu_watch, "CACHE_PATH",
-                        str(tmp_path / "tuning" / "BENCH_TPU.json"))
-    monkeypatch.setattr(tpu_watch, "TUNING_PATH",
-                        str(tmp_path / "tuning" / "TUNING.json"))
-    monkeypatch.setattr(tpu_watch, "PROFILE_PATH",
-                        str(tmp_path / "tuning" / "PROFILE_TPU.json"))
-    monkeypatch.setenv("TMX_TUNING_JSON",
-                       str(tmp_path / "tuning" / "TUNING.json"))
-    monkeypatch.setattr(bench, "REPO", str(tmp_path))
-    monkeypatch.setenv("WATCH_RECAPTURE",
-                       str(tmp_path / "tuning" / "RECAPTURE.json"))
-    monkeypatch.delenv("WATCH_ONLY", raising=False)
-
-    perf.write_recapture(["bench:3", "sweep:volume"])
-    pending = tpu_watch.all_pending()
-    # queued re-captures fire early (before the not-yet-done bench items
-    # would list them again) and exactly once
-    assert pending.count("bench:3") == 1
-    assert pending.count("sweep:volume") == 1
-    assert pending.index("bench:3") < pending.index("bench:4")

@@ -1,0 +1,212 @@
+"""Ask the chip's compiler, without the chip.
+
+Every kernel of ``ops/pallas_kernels.py`` and ``ops/fused_measure.py`` and
+the whole-site jterator batch programs are compiled NON-interpreted for a
+*described* TPU v5e (``jax.experimental.topologies``): what the chip's
+compiler refuses — a block shape off the (8, 128) tiling, a kernel over
+its VMEM budget, a program that does not fit HBM or does not finish
+compiling — fails here, at no chip time.  Interpret mode, which every
+other test uses, shows none of that.  Nothing runs: a compile that passes
+is not a chip run (``chip_smoke.py`` is).
+
+One file on purpose: only one process may load the TPU's library, so the
+topology is described inside a module-scoped fixture — after a test of
+this file has started, never at import — and every case lives with it.
+"""
+
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+#: the field ``chip_smoke.py`` feeds the workflow phase, and its capacity
+SMOKE_FIELD = 2160
+SMOKE_CAPACITY = 1024
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A described-chip executable is written to the persistent cache but
+    cannot be read back without a chip, so the cache is off around these
+    compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def on_tpu(monkeypatch, one_chip, no_compile_cache):
+    """Shapes placed on the described chip, and the program's own
+    backend-name dispatch steered to its ``tpu`` branch (the process's
+    real backend is the CPU's, so unsteered code would compile its CPU
+    twin — scatter reductions, native callbacks — for the chip)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    return shape
+
+
+def _compile(fn, *args):
+    t0 = time.perf_counter()
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled, time.perf_counter() - t0
+
+
+# ------------------------------------------------------- whole-site kernels
+def _kernel_case(name, S):
+    from tmlibrary_tpu.ops import pallas_kernels as pk
+
+    site = (256, 256)
+    vol = (16, 128, 128)
+    cases = {
+        "cc4": (lambda m: pk.cc_min_propagate(m, 4, interpret=False),
+                [S(site, jnp.bool_)]),
+        "cc8": (lambda m: pk.cc_min_propagate(m, 8, interpret=False),
+                [S(site, jnp.bool_)]),
+        "watershed": (
+            lambda i, s, m: pk.watershed_flood(i, s, m, interpret=False),
+            [S(site, jnp.float32), S(site, jnp.int32), S(site, jnp.bool_)]),
+        "fill": (lambda m: pk.fill_holes_flood(m, interpret=False),
+                 [S(site, jnp.bool_)]),
+        "distance": (lambda m: pk.distance_transform(m, interpret=False),
+                     [S(site, jnp.bool_)]),
+        "cc3d": (lambda m: pk.cc3d_min_propagate(m, 26, interpret=False),
+                 [S(vol, jnp.bool_)]),
+        "watershed3d": (
+            lambda i, s, m: pk.watershed3d_flood(i, s, m, 8, interpret=False),
+            [S(vol, jnp.float32), S(vol, jnp.int32), S(vol, jnp.bool_)]),
+    }
+    return cases[name]
+
+
+@pytest.mark.parametrize(
+    "kernel",
+    ["cc4", "cc8", "watershed", "fill", "distance", "cc3d", "watershed3d"])
+def test_whole_site_kernel_compiles_for_v5e(kernel, on_tpu):
+    fn, args = _kernel_case(kernel, on_tpu)
+    compiled, _ = _compile(fn, *args)
+    assert "tpu_custom_call" in compiled.as_text()  # the kernel is in there
+
+
+# ------------------------------------------------------ fused megakernels
+@pytest.mark.parametrize("capacity", [64, 2048])
+@pytest.mark.parametrize("family", ["stats", "hist", "glcm"])
+def test_fused_measure_kernel_compiles_for_v5e(family, capacity, on_tpu):
+    from tmlibrary_tpu.ops import fused_measure as fm
+
+    S = on_tpu
+    lab, img = S((256, 256), jnp.int32), S((256, 256), jnp.float32)
+    bounds = [S((capacity,), jnp.float32), S((capacity,), jnp.float32)]
+    if family == "stats":
+        fn, args = (lambda l, a: fm.grouped_stats(
+            l, [jnp.ones_like(a), a, a * a], capacity, interpret=False),
+            [lab, img])
+    elif family == "hist":
+        fn, args = (lambda l, a, lo, hi: fm.intensity_hist(
+            l, a, capacity, 256, (lo, hi), interpret=False),
+            [lab, img, *bounds])
+    else:
+        fn, args = (lambda l, a, lo, hi: fm.glcm_all(
+            l, a, capacity, 32, [(0, 1), (1, 1), (1, 0), (1, -1)],
+            (lo, hi), interpret=False),
+            [lab, img, *bounds])
+    compiled, _ = _compile(fn, *args)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_whole_site_kernels_stay_out_of_auto_dispatch_at_a_full_field(
+        monkeypatch):
+    """A 2160x2160 plane neither fits VMEM nor finishes compiling as a
+    whole-site kernel: ``method="auto"`` keeps the XLA twin there even
+    when the kernels are switched on, and takes the kernel at 256x256."""
+    from tmlibrary_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("TMX_PALLAS", "1")
+    assert pk.pallas_enabled("cc", (256, 256))
+    assert pk.pallas_enabled("cc3d", (16, 128, 128))
+    assert not pk.pallas_enabled("cc", (SMOKE_FIELD, SMOKE_FIELD))
+    assert not pk.pallas_enabled("watershed", (1080, 1080))
+    assert pk.pallas_enabled("cc")  # no shape given: the verdict alone
+
+
+# ---------------------------------------------------- XLA twins, full field
+@pytest.mark.parametrize("op", ["cc", "fill"])
+def test_xla_twin_compiles_at_the_full_field(op, on_tpu):
+    """The fixpoints the full field runs (the whole-site kernels are out
+    of dispatch there).  Both used to take the TPU compiler longer than
+    25 minutes at 2160x2160 — column scans along the second-minor axis,
+    a flat 4.7-Mpixel cumsum, a bool carry between the scans."""
+    from tmlibrary_tpu.ops import label
+
+    fn = {"cc": lambda m: label.connected_components(m, 8, method="xla"),
+          "fill": lambda m: label.fill_holes(m, method="xla")}[op]
+    _, seconds = _compile(fn, on_tpu((SMOKE_FIELD, SMOKE_FIELD), jnp.bool_))
+    assert seconds < 300, f"{op} took {seconds:.0f}s to compile"
+
+
+# ------------------------------------------------------- whole-site programs
+def _program_case(config):
+    from tmlibrary_tpu import benchmarks
+
+    if config == "2":
+        return benchmarks.smooth_threshold_description(), ("DAPI",)
+    if config == "3":
+        return benchmarks.cell_painting_description(), ("DAPI", "Actin")
+    channels = benchmarks.FULL_STACK_CHANNELS[:3]
+    return benchmarks.full_feature_description(channels=channels), channels
+
+
+@pytest.mark.parametrize("config,size,batch,capacity", [
+    ("2", 256, 8, 64),
+    ("3", 256, 8, 64),
+    ("4", 256, 8, 64),
+    # the smoke's workflow phase: one acquisition-geometry field per batch
+    # (what the engine's batch resolver gives a 2160x2160 site on device)
+    ("3", SMOKE_FIELD, 1, SMOKE_CAPACITY),
+])
+def test_whole_site_program_compiles_for_v5e(config, size, batch, capacity,
+                                             on_tpu):
+    from tmlibrary_tpu.jterator.pipeline import ImageAnalysisPipeline
+
+    S = on_tpu
+    desc, channels = _program_case(config)
+    fn = ImageAnalysisPipeline(desc, max_objects=capacity).build_batch_fn()
+    raw = {c: S((batch, size, size), jnp.float32) for c in channels}
+    compiled = fn.lower(raw, {}, S((batch, 2), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    resident = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+    print(f"config {config} {size}x{size} batch {batch} capacity {capacity}: "
+          f"args {mem.argument_size_in_bytes / 1e6:.0f} MB, out "
+          f"{mem.output_size_in_bytes / 1e6:.0f} MB, temp "
+          f"{mem.temp_size_in_bytes / 1e6:.0f} MB")
+    # one program next to a pipelined window of its own inputs/outputs
+    assert resident < 8e9, f"{resident / 1e9:.1f} GB of a 16 GB chip"

@@ -622,3 +622,68 @@ def test_site_glcm_bit_identical_to_scatter(rng):
         l, i, 48, levels=16, glcm_method="scatter")))(labels, img)
     for k in f_nat:
         np.testing.assert_array_equal(np.asarray(f_nat[k]), np.asarray(f_sca[k]))
+
+
+# ------------------------------------------------------------------ loader
+@pytest.fixture
+def fresh_loader(tmp_path, monkeypatch):
+    """The loader's module state reset, its build cache moved to a tmp
+    directory; the real library comes back afterwards."""
+    from tmlibrary_tpu import utils
+
+    saved = native._lib, native._load_attempted, dict(native._status)
+    native._lib, native._load_attempted = None, False
+    monkeypatch.setattr(
+        utils, "checkout_cache_dir", lambda name: str(tmp_path / name))
+    yield tmp_path
+    native._lib, native._load_attempted = saved[0], saved[1]
+    native._status.clear()
+    native._status.update(saved[2])
+
+
+def test_loader_builds_from_tracked_source_not_a_leftover_binary(
+        fresh_loader, monkeypatch):
+    """A ``libtmnative.so`` left in ``native/`` (git-ignored, from any
+    older source) is never loaded: the library in use is built from
+    ``native/tmnative.cpp`` under a name that carries its digest."""
+    import hashlib
+
+    fake_native = fresh_loader / "native"
+    fake_native.mkdir()
+    source = fake_native / "tmnative.cpp"
+    source.write_bytes(native._SOURCE.read_bytes())
+    (fake_native / "libtmnative.so").write_bytes(b"stale, not even ELF")
+    monkeypatch.setattr(native, "_NATIVE_DIR", fake_native)
+    monkeypatch.setattr(native, "_SOURCE", source)
+
+    st = native.status()
+    digest = hashlib.sha256(source.read_bytes()).hexdigest()[:16]
+    assert st["state"] == "loaded" and st["source_digest"] == digest
+    assert st["path"] == str(
+        fresh_loader / "native" / f"libtmnative-{digest}.so")
+    labels, n = native.cc_label_host(np.eye(4, dtype=bool), 8)
+    assert n == 1
+
+    # an edited source is a different digest, hence a different library
+    source.write_bytes(source.read_bytes() + b"\n// edited\n")
+    native._lib, native._load_attempted = None, False
+    assert native.status()["source_digest"] != digest
+
+
+def test_missing_compiler_is_logged_and_reported(fresh_loader, monkeypatch,
+                                                 caplog):
+    import subprocess
+
+    def no_gxx(*a, **k):
+        raise FileNotFoundError("g++")
+
+    monkeypatch.setattr(subprocess, "run", no_gxx)
+    with caplog.at_level("WARNING", logger="tmlibrary_tpu.native"):
+        st = native.status()
+    assert st["state"] == "no_compiler" and st["path"] is None
+    assert st["source_digest"] == native._source_digest()
+    assert any("no g++" in r.getMessage() for r in caplog.records)
+    assert not native.available()
+    # the scipy fallback still answers
+    labels, n = native.cc_label_host(np.eye(4, dtype=bool), 4)
+    assert n == 4

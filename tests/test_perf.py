@@ -67,12 +67,12 @@ def test_cost_analysis_raising_degrades_to_none():
     assert perf.cost_flops(_NoLower(), 1) == (None, None)
 
 
-def test_cost_analysis_list_and_empty_shapes():
-    class _ListCompiled:
+def test_cost_analysis_dict_and_empty_shapes():
+    class _DictCompiled:
         def cost_analysis(self):
-            return [{"flops": 12.0, "bytes accessed": 4.0}]
+            return {"flops": 12.0, "bytes accessed": 4.0}
 
-    cost = perf.cost_from_compiled(_ListCompiled())
+    cost = perf.cost_from_compiled(_DictCompiled())
     assert (cost.flops, cost.bytes) == (12.0, 4.0)
 
     class _EmptyCompiled:
@@ -89,23 +89,88 @@ def test_cost_analysis_list_and_empty_shapes():
 
 
 def test_flops_fields_carries_roofline_verdict():
-    out = perf.flops_fields(1e9, 100, 0.5, "tpu", nbytes=1e8)
+    out = perf.flops_fields(1e9, 100, 0.5, "TPU v5 lite", nbytes=1e8)
     assert out["achieved_tflops_per_sec"] == pytest.approx(0.002)
     assert out["mfu_vs_v5e_bf16_peak"] is not None
     assert out["arithmetic_intensity"] == pytest.approx(10.0)
     assert out["bound_by"] == "memory"  # 10 flops/B << v5e ridge ~240
     # off-device runs never claim device-fraction numbers
-    cpu = perf.flops_fields(1e9, 100, 0.5, "cpu", nbytes=1e8)
+    assert perf.attached_device_kind() is None  # the suite runs on CPU
+    cpu = perf.flops_fields(1e9, 100, 0.5, None, nbytes=1e8)
     assert cpu["mfu_vs_v5e_bf16_peak"] is None
     assert cpu["hbm_frac_vs_v5e_peak"] is None
     assert cpu["bound_by"] == "memory"
+    # a chip without published peaks is an error, never a v5e assumed
+    with pytest.raises(KeyError, match="no published peaks"):
+        perf.flops_fields(1e9, 100, 0.5, "TPU v9", nbytes=1e8)
 
 
-def test_backend_peaks():
-    assert perf.backend_peaks("tpu") == (perf.V5E_BF16_PEAK_FLOPS,
-                                         perf.V5E_HBM_PEAK_BPS)
-    assert perf.backend_peaks("cpu") == (None, None)
+def test_device_peaks_keyed_by_device_kind():
+    assert perf.device_peaks("TPU v5 lite") == (197e12, 819e9)
+    for unknown in ("tpu", "cpu", "TPU v4"):
+        with pytest.raises(KeyError, match="no published peaks"):
+            perf.device_peaks(unknown)
     assert perf.ridge_point() == pytest.approx(197e12 / 819e9)
+
+
+# ---------------------------------------- AOT executable failure reporting
+class _FailingCompiled:
+    """Stands in for an AOT executable that fails at call time."""
+
+    def __init__(self, consume):
+        self.consume = consume
+
+    def cost_analysis(self):
+        return {}
+
+    def __call__(self, x):
+        if self.consume:
+            x.delete()  # what a donating executable does before failing
+        raise RuntimeError("executable refused these inputs")
+
+
+class _Lowerable:
+    def __init__(self, consume):
+        self.consume = consume
+        self.jit_calls = 0
+
+    def lower(self, *a, **k):
+        consume = self.consume
+
+        class _Lowered:
+            def compile(self):
+                return _FailingCompiled(consume)
+
+        return _Lowered()
+
+    def __call__(self, x):
+        self.jit_calls += 1
+        return x + 1.0
+
+
+def test_failed_aot_executable_is_reported_and_rerouted_when_inputs_live(
+        caplog):
+    fn = _Lowerable(consume=False)
+    wrapped = perf.instrument_batch_fn(fn, program="prog@aotfail")
+    with caplog.at_level("WARNING", logger="tmlibrary_tpu.perf"):
+        out = wrapped(jnp.ones(3))
+    np.testing.assert_array_equal(np.asarray(out), 2.0)
+    assert any("executable refused these inputs" in r.getMessage()
+               for r in caplog.records)
+    # dropped for good: the next call goes straight to jit, no new warning
+    caplog.clear()
+    wrapped(jnp.ones(3))
+    assert fn.jit_calls == 2 and not caplog.records
+
+
+def test_failed_aot_executable_never_retries_on_donated_buffers(caplog):
+    fn = _Lowerable(consume=True)
+    wrapped = perf.instrument_batch_fn(fn, program="prog@aotdonated")
+    with caplog.at_level("WARNING", logger="tmlibrary_tpu.perf"):
+        with pytest.raises(RuntimeError, match="refused these inputs"):
+            wrapped(jnp.ones(3))
+    assert fn.jit_calls == 0  # no second run on deleted inputs
+    assert any("prog@aotdonated" in r.getMessage() for r in caplog.records)
 
 
 # ------------------------------------------------- instrumented batch fn
@@ -193,38 +258,6 @@ def test_cached_batch_fn_returns_raw_fn_when_disabled():
     assert cached_batch_fn(desc, 8) is wrapped
 
 
-# ----------------------------------------------------- staleness gauges
-def test_bench_record_staleness_rows_and_gauges(tmp_path, monkeypatch):
-    cache = tmp_path / "BENCH_TPU.json"
-    now = time.time()
-    cache.write_text(json.dumps({"records": {
-        "3": {"record": {"metric": "m3"}, "measured_at": "fresh",
-              "measured_at_unix": now - 3600},
-        "volume": {"record": {"metric": "mv"}, "measured_at": "old",
-                   "measured_at_unix": now - 100 * 3600},
-    }}))
-    monkeypatch.setenv("BENCH_TPU_CACHE", str(cache))
-    rows = {r["config"]: r for r in perf.bench_record_staleness(now=now)}
-    assert rows["3"]["stale"] is False
-    assert rows["3"]["age_hours"] == pytest.approx(1.0)
-    assert rows["volume"]["stale"] is True
-    assert rows["volume"]["age_hours"] == pytest.approx(100.0)
-
-    reg = telemetry.reset_registry(enabled=True)
-    perf.set_bench_staleness_gauges(now=now)
-    snap = reg.snapshot()
-    gauges = {(g["name"], g["labels"]["config"]): g["value"]
-              for g in snap["gauges"]}
-    assert gauges[("tmx_bench_record_age_hours", "volume")] == 100.0
-    assert gauges[("tmx_bench_record_stale", "volume")] == 1.0
-    assert gauges[("tmx_bench_record_stale", "3")] == 0.0
-
-
-def test_bench_record_staleness_missing_cache(tmp_path, monkeypatch):
-    monkeypatch.setenv("BENCH_TPU_CACHE", str(tmp_path / "nope.json"))
-    assert perf.bench_record_staleness() == []
-
-
 # ------------------------------------------------------ history plumbing
 def test_append_and_load_bench_history(tmp_path, monkeypatch):
     path = tmp_path / "BENCH_HISTORY.jsonl"
@@ -246,9 +279,4 @@ def test_recapture_queue_roundtrip(tmp_path, monkeypatch):
     perf.write_recapture(["bench:3", "sweep:3"], reason="test")
     perf.write_recapture(["bench:3", "bench:4"])  # merge + dedupe
     assert perf.load_recapture() == ["bench:3", "sweep:3", "bench:4"]
-    perf.clear_recapture("sweep:3")
-    assert perf.load_recapture() == ["bench:3", "bench:4"]
-    perf.clear_recapture("bench:3")
-    perf.clear_recapture("bench:4")
-    assert perf.load_recapture() == []
-    assert not path.exists()  # empty queue removes the file
+    assert json.loads(path.read_text())["reason"] == ""

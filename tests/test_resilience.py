@@ -133,7 +133,7 @@ def test_retry_policy_deterministic_backoff():
 
 # -------------------------------------------------------------- classifier
 @pytest.mark.parametrize("exc,expected", [
-    (TransientDeviceError("relay gone"), TRANSIENT),
+    (TransientDeviceError("device gone"), TRANSIENT),
     (TimeoutError("x"), TRANSIENT),
     (OSError("disk hiccup"), TRANSIENT),
     (MemoryError(), TRANSIENT),
@@ -246,19 +246,18 @@ def test_circuit_breaker_lifecycle():
 
 
 # -------------------------------------------------------- DeviceHealthGuard
-def test_guard_degrades_to_cpu_on_hanging_probe(tmp_path):
+def test_guard_raises_transient_on_hanging_probe():
     import time as _time
 
-    ledger = RunLedger(tmp_path / "ledger.jsonl")
     guard = DeviceHealthGuard(probe=lambda: _time.sleep(5), timeout=0.05,
                               failure_threshold=1, cooldown=3600.0)
-    assert guard.ensure_backend(ledger, where="run") == "cpu"
-    assert guard.degraded
-    ev = ledger.degraded_backend()
-    assert ev is not None and ev["backend"] == "cpu" and ev["where"] == "run"
-    # subsequent calls stay degraded without re-probing (circuit open)
+    with pytest.raises(TransientDeviceError, match="device path is down"):
+        guard.ensure_backend(where="run")
+    assert classify(TransientDeviceError("x")) == TRANSIENT
+    # subsequent calls fail at once without re-probing (circuit open)
     t0 = _time.monotonic()
-    assert guard.ensure_backend(ledger) == "cpu"
+    with pytest.raises(TransientDeviceError):
+        guard.ensure_backend()
     assert _time.monotonic() - t0 < 0.05
 
 
@@ -266,8 +265,8 @@ def test_guard_healthy_path_caches_probe():
     calls = []
     guard = DeviceHealthGuard(probe=lambda: calls.append(1), timeout=1.0,
                               probe_ttl=3600.0)
-    assert guard.ensure_backend(None) == "device"
-    assert guard.ensure_backend(None) == "device"
+    guard.ensure_backend()
+    guard.ensure_backend()
     assert len(calls) == 1  # TTL cache: one probe
 
 
@@ -606,10 +605,11 @@ def test_crash_mid_append_then_resume(store):
     assert outs == [f"out_{i:03d}.txt" for i in range(4)]
 
 
-def test_workflow_guard_integration_degrades_and_completes(store):
-    """A hanging device probe (relay down) trips the breaker; the run
-    degrades to CPU with a ``backend_degraded`` ledger event and still
-    completes — instead of hanging for hours."""
+def test_workflow_guard_integration_fails_loudly_then_resumes(store):
+    """A hanging device probe trips the breaker; the run stops with the
+    transient error instead of hanging for hours or carrying on on
+    another backend, runs no batch, and a resume with the device back
+    completes."""
     import time as _time
 
     res = fast_resilience()
@@ -617,10 +617,15 @@ def test_workflow_guard_integration_degrades_and_completes(store):
                                   timeout=0.05, failure_threshold=1,
                                   cooldown=3600.0)
     wf = Workflow(store, dummy_description(), resilience=res)
-    summary = wf.run()
+    with pytest.raises(TransientDeviceError, match="device path is down"):
+        wf.run()
+    events = [e["event"] for e in wf.ledger.events()]
+    assert "backend_degraded" not in events and "batch_done" not in events
+    assert wf.ledger.completed_steps() == set()
+    res.guard = DeviceHealthGuard(probe=lambda: True, timeout=1.0)
+    summary = Workflow(store, dummy_description(),
+                       resilience=res).run(resume=True)
     assert summary["chaosdummy"]["n_batches"] == 4
-    ev = wf.ledger.degraded_backend()
-    assert ev is not None and ev["backend"] == "cpu"
     assert wf.ledger.completed_steps() == {"chaosdummy"}
 
 

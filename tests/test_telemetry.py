@@ -224,7 +224,6 @@ def _seed_era_events():
                                                     "max_s": 0.6},
                                        "persist": {"total_s": 3.0,
                                                    "max_s": 1.8}}}},
-        {"event": "backend_degraded", "backend": "cpu", "where": "jterator"},
     ]
 
 
@@ -237,7 +236,6 @@ def test_registry_from_seed_era_ledger():
     assert reg.counter("tmx_batches_quarantined_total",
                        step="jterator").value == 1.0
     assert reg.counter("tmx_steps_partial_total", step="jterator").value == 1.0
-    assert reg.counter("tmx_backend_degradations_total").value == 1.0
     assert reg.gauge("tmx_pipeline_depth", step="jterator").value == 4.0
     assert reg.gauge("tmx_pipeline_phase_seconds_total", step="jterator",
                      phase="persist").value == 3.0

@@ -52,9 +52,13 @@ def _knn_tile(q: jax.Array, x: jax.Array, base: jax.Array, k: int,
     ``x``.  ``base`` (traced, so every tile shares one compiled program)
     is the tile's starting row in ``x``; with ``exclude_self`` the
     diagonal is masked out (self-kNN)."""
+    # HIGHEST: the TPU's default matmul rounds f32 operands to bf16, and
+    # the cancellation in |q|^2 - 2 q.x + |x|^2 then misranks neighbours
+    # against the exact float32 answer; the CPU computes f32 either way
+    cross = jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST)
     d2 = (
         jnp.sum(q * q, axis=1, keepdims=True)
-        - 2.0 * q @ x.T
+        - 2.0 * cross
         + jnp.sum(x * x, axis=1)[None]
     )
     if exclude_self:

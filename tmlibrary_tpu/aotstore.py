@@ -1,6 +1,6 @@
 """Content-addressed serialized AOT executable store (cold-start plane).
 
-The steady-state headline (BENCH_r05) never pays XLA compile, but every
+A steady-state run never pays XLA compile, but every
 daemon restart, bucket-ladder escalation and newly joined fleet host
 compiles cold on the critical path — tens of seconds before the first
 batch lands.  This module makes compiled executables *durable and
@@ -25,7 +25,7 @@ Keying contract (stale artifacts can never load):
 
 Store layout (``TMX_AOT_STORE_DIR`` env > ``TM_AOT_STORE_DIR`` config >
 process default (serve daemons point this at the shared serve root) >
-``~/.cache/tmlibrary_tpu/aot``)::
+next to the compile cache, ``<checkout>/.cache/aot``)::
 
     <dir>/<digest>.bin    pickled {payload, in_tree, out_tree}
     <dir>/<digest>.json   meta sidecar: program/capacity/strategy,
@@ -129,7 +129,9 @@ def set_process_default_dir(directory: str | None) -> None:
 
 def store_dir(directory: str | None = None) -> str:
     """Resolve the store directory: explicit arg > ``TMX_AOT_STORE_DIR``
-    env > config > process default > ``~/.cache/tmlibrary_tpu/aot``."""
+    env > config > process default > next to the compile cache
+    (``$JAX_COMPILATION_CACHE_DIR/aot`` when that variable is set, else
+    ``<checkout>/.cache/aot``)."""
     if directory:
         return str(directory)
     env = os.environ.get(ENV_DIR)
@@ -146,7 +148,12 @@ def store_dir(directory: str | None = None) -> str:
     with _LOCK:
         if _PROCESS_DEFAULT_DIR:
             return _PROCESS_DEFAULT_DIR
-    return os.path.expanduser("~/.cache/tmlibrary_tpu/aot")
+    cache_root = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if cache_root:
+        return os.path.join(cache_root, "aot")
+    from tmlibrary_tpu.utils import checkout_cache_dir
+
+    return checkout_cache_dir("aot")
 
 
 def max_store_bytes() -> int:
@@ -303,8 +310,15 @@ def export_entry(compiled: Any, *, program: str, step: str = "jterator",
         from jax.experimental.serialize_executable import serialize
 
         payload, in_tree, out_tree = serialize(compiled)
+        # the devices the executable was compiled for: loading it onto
+        # every device of the backend instead makes a one-device program
+        # demand one input shard per device
+        device_ids = [
+            d.id for d in compiled._executable.xla_executable.local_devices()
+        ]
         blob = pickle.dumps(
-            {"payload": payload, "in_tree": in_tree, "out_tree": out_tree},
+            {"payload": payload, "in_tree": in_tree, "out_tree": out_tree,
+             "device_ids": device_ids},
             protocol=pickle.HIGHEST_PROTOCOL,
         )
     except Exception as exc:
@@ -404,8 +418,12 @@ def import_entry(*, program: str, capacity: int | None = None,
             deserialize_and_load,
         )
 
+        import jax
+
+        by_id = {d.id: d for d in jax.devices()}
         compiled = deserialize_and_load(
-            doc["payload"], doc["in_tree"], doc["out_tree"]
+            doc["payload"], doc["in_tree"], doc["out_tree"],
+            execution_devices=[by_id[i] for i in doc["device_ids"]],
         )
     except Exception as exc:
         logger.warning(

@@ -2,7 +2,7 @@
 
 The static-shape policy pads every object-indexed output to a per-site
 ``max_objects`` capacity so one fused XLA program serves all sites — but
-a sparse plate (BENCH_r05: ``saturated_sites: 0`` at cap 64) then spends
+a sparse plate (no saturated site at cap 64) then spends
 most of its per-object FLOPs on empty slots: the one-hot contractions,
 quantile histograms and GLCM tables all scale with the capacity, not
 with the objects that exist.

@@ -1,16 +1,22 @@
 """ctypes loader for the first-party native host kernels.
 
-See ``native/tmnative.cpp``.  The library auto-builds with ``g++`` on first
-use if the ``.so`` is missing; every entry point has a pure-Python/scipy
-fallback, so the framework works without a compiler (the native path is a
-performance + golden-reference layer, mirroring how the reference leans on
-cv2/mahotas binaries).
+See ``native/tmnative.cpp``.  The library in use is always the one built
+from that tracked source: on first use it is compiled with ``g++`` into
+``<checkout>/.cache/native/libtmnative-<source digest>.so`` and loaded
+from there, so a binary left over from an older source is never picked
+up.  Every entry point has a pure-Python/scipy fallback, so the
+framework works without a compiler — but that state is logged and
+reported by :func:`status`, never silent (the native path is a
+performance + golden-reference layer, mirroring how the reference leans
+on cv2/mahotas binaries).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
+import os
 import subprocess
 import threading
 from pathlib import Path
@@ -20,41 +26,58 @@ import numpy as np
 logger = logging.getLogger(__name__)
 
 _NATIVE_DIR = Path(__file__).resolve().parent.parent / "native"
-#: search order: wheel-installed copy (setup.py build_py drops the compiled
-#: library inside the package), then the source tree's native/ directory
-_SO_CANDIDATES = (
-    Path(__file__).resolve().parent / "libtmnative.so",
-    _NATIVE_DIR / "libtmnative.so",
-)
-_SO_PATH = _NATIVE_DIR / "libtmnative.so"
+_SOURCE = _NATIVE_DIR / "tmnative.cpp"
+#: a wheel carries no source tree: setup.py compiled the same source
+#: into the package at install time, and only then is this copy used
+_PACKAGED_SO = Path(__file__).resolve().parent / "libtmnative.so"
 _lib = None
 _load_attempted = False
+#: what the loader found, for :func:`status`: ``state`` is ``loaded``,
+#: ``no_source``, ``no_compiler``, ``build_failed`` or ``load_failed``
+_status: dict = {"state": "not_loaded", "source_digest": None, "path": None}
 #: first load may g++-build the library; concurrent callers (e.g. the
 #: imextract decode thread pool) must not race that build
 _load_lock = threading.Lock()
 
 
-def _build() -> bool:
-    src = _NATIVE_DIR / "tmnative.cpp"
-    if not src.exists():
-        return False
+def _source_digest() -> str | None:
+    """First 16 hex digits of the sha256 of ``native/tmnative.cpp``."""
+    try:
+        return hashlib.sha256(_SOURCE.read_bytes()).hexdigest()[:16]
+    except OSError:
+        return None
+
+
+def _build(target: Path) -> "str | None":
+    """Compile the tracked source into ``target``; returns None when it
+    is built, else the state that says why not."""
+    target.parent.mkdir(parents=True, exist_ok=True)
+    tmp = target.with_suffix(f".{os.getpid()}.tmp")
     try:
         subprocess.run(
             # -ffp-contract=off: several kernels promise bit-parity with
             # an XLA or numpy float twin (tm_site_stats most strictly);
             # a fused multiply-add would round differently than the twin
             ["g++", "-O3", "-ffp-contract=off", "-fPIC", "-std=c++17",
-             "-shared", "-o", str(_SO_PATH), str(src)],
-            check=True, capture_output=True, timeout=120,
+             "-shared", "-o", str(tmp), str(_SOURCE)],
+            check=True, capture_output=True, timeout=300,
         )
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError) as e:
-        logger.info("native build unavailable: %s", e)
-        return False
+        os.replace(tmp, target)  # concurrent processes see whole files
+        return None
+    except FileNotFoundError:
+        logger.warning("native: no g++ on this machine — the Python "
+                       "fallbacks of every native kernel are in use")
+        return "no_compiler"
+    except subprocess.SubprocessError as e:
+        logger.warning("native: building %s failed (%s) — the Python "
+                       "fallbacks are in use", _SOURCE, e)
+        return "build_failed"
+    finally:
+        tmp.unlink(missing_ok=True)
 
 
 def _load():
-    global _lib, _load_attempted, _SO_PATH
+    global _lib, _load_attempted
     # fast-path ONLY on a published library: checking _load_attempted here
     # would let callers slip past the lock mid-build and wrongly conclude
     # the library is unavailable while another thread is still compiling it
@@ -67,18 +90,35 @@ def _load():
 
 
 def _load_locked():
-    global _lib, _load_attempted, _SO_PATH
+    global _lib, _load_attempted
     _load_attempted = True
-    found = next((p for p in _SO_CANDIDATES if p.exists()), None)
-    if found is not None:
-        _SO_PATH = found
-    elif not _build():  # _build writes the source-tree candidate
+    from tmlibrary_tpu.utils import checkout_cache_dir
+
+    digest = _source_digest()
+    _status.update(source_digest=digest, path=None)
+    if digest is not None:
+        so_path = Path(checkout_cache_dir("native")) / \
+            f"libtmnative-{digest}.so"
+        if not so_path.exists():
+            failure = _build(so_path)
+            if failure is not None:
+                _status["state"] = failure
+                return None
+    elif _PACKAGED_SO.exists():
+        so_path = _PACKAGED_SO
+    else:
+        logger.warning("native: neither %s nor a packaged library exists "
+                       "— the Python fallbacks are in use", _SOURCE)
+        _status["state"] = "no_source"
         return None
+    _status["path"] = str(so_path)
     try:
-        lib = ctypes.CDLL(str(_SO_PATH))
+        lib = ctypes.CDLL(str(so_path))
     except OSError as e:
-        logger.info("native library failed to load: %s", e)
+        logger.warning("native library %s failed to load: %s", so_path, e)
+        _status["state"] = "load_failed"
         return None
+    _status["state"] = "loaded"
     lib.tm_cc_label.restype = ctypes.c_int32
     lib.tm_cc_label.argtypes = [
         ctypes.POINTER(ctypes.c_uint8), ctypes.c_int32, ctypes.c_int32,
@@ -245,6 +285,15 @@ def _load_locked():
 
 def available() -> bool:
     return _load() is not None
+
+
+def status() -> dict:
+    """What the loader ended up with: ``state`` (``loaded`` or the reason
+    the Python fallbacks are in use), the ``source_digest`` of
+    ``native/tmnative.cpp`` the library was built from, and its
+    ``path``."""
+    _load()
+    return dict(_status)
 
 
 # ----------------------------------------------------------------- wrappers

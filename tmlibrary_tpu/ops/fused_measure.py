@@ -20,30 +20,34 @@ Three kernels cover the three accumulation shapes of ``ops/measure.py``:
   sums AND the bounding box from its 7-channel call — one HBM read where
   the unfused path takes two full passes per family.
 - :func:`intensity_hist` — the per-(object, bucket) histogram feeding
-  ``intensity_quantiles``: per-pixel bounds lookup, the mahotas-parity
-  quantization expression and the dual one-hot contraction all inside
-  the kernel.
+  ``intensity_quantiles``: the dual one-hot contraction with both
+  one-hots built in VMEM from the label and bucket rows.
 - :func:`glcm_all` — the second fused pass: all 4 directions' GLCM
-  counts in one kernel (per-object quantization of the shifted and
-  unshifted pixels in VMEM, bf16 one-hot operands contracted into an
-  f32 VMEM accumulator — the exact-integer-counts trick of
-  ``_glcm_matmul_all``).
+  counts in one kernel (bf16 one-hot operands built in VMEM and
+  contracted into an f32 VMEM accumulator — the exact-integer-counts
+  trick of ``_glcm_matmul_all``).
+
+The per-object gray-level stretch feeding the last two is
+``quantize_per_object`` itself, one elementwise XLA pass ahead of the
+kernel: the bounds lookup needs the whole table whatever segment tile a
+grid step works on, and the TPU lowering accepted the kernels once it
+left them (PERF.md, PR 21).
 
 Parity contract (pinned by ``tests/test_reduction.py`` and
 ``tests/test_fused_measure.py``, interpret mode on CPU): min/max,
 counts, histogram and GLCM cells are bit-identical to every reference
 strategy (order-free or exact-integer accumulations); fractional f32
 sums carry the same 1e-6 relative tolerance as sort/scatter vs the
-one-hot reference (different accumulation order).  The quantization
-expression trees are copied verbatim from ``quantize_per_object`` so
-bucket assignment cannot drift.
+one-hot reference (different accumulation order).  Bucket assignment
+is ``quantize_per_object``'s own, so it cannot drift.
 
 Capacity invariance: the pixel chunk is resolved independently of the
-object capacity (:func:`fused_chunk`), so rows ``0..n`` are
-bit-identical for any capacity ``>= n`` — the bucket router's contract
-(``ops/reduction.capacity_segments``).  Interpret-mode fallback keeps
-tier-1 hardware-independent: ``interpret=None`` resolves to ``True``
-off-TPU, exactly like ``pallas_kernels``.  The VMEM chunking knob
+object capacity (:func:`fused_chunk`) and the segment axis is tiled, so
+rows ``0..n`` are bit-identical for any capacity ``>= n`` — the bucket
+router's contract (``ops/reduction.capacity_segments``).  Interpret mode
+is the CPU test path only: ``interpret=None`` resolves to ``True``
+off-TPU, and on a ``tpu`` backend a kernel the compiler refuses raises —
+nothing catches it and reroutes.  The VMEM chunking knob
 follows ``_tuned_chunk`` conventions and shares its memoized
 TUNING.json reader (``TMX_FUSED_CHUNK`` env → committed ``fused_chunk``
 sweep result → the default).
@@ -98,8 +102,8 @@ def fused_chunk() -> int:
 
 
 def _interpret_default() -> bool:
-    """Interpret-mode fallback off-TPU, like ``pallas_enabled``'s
-    backend gate — tier-1 runs the same kernels on XLA-CPU."""
+    """Interpret mode off-TPU (the CPU test path); compiled on ``tpu``,
+    where a refusal by the compiler propagates."""
     return jax.default_backend() != "tpu"
 
 
@@ -119,7 +123,7 @@ def _pad_lane(n: int) -> int:
 def _chunked(flat: jax.Array, chunk: int, fill=0) -> jax.Array:
     """(P,) → (n_chunks, chunk); padded pixels carry ``fill`` (label 0
     pads land in the dropped background row, value pads are masked by
-    their label-0 one-hot column)."""
+    their label-0 one-hot row)."""
     p = flat.shape[0]
     pad = (-p) % chunk
     if pad:
@@ -129,39 +133,67 @@ def _chunked(flat: jax.Array, chunk: int, fill=0) -> jax.Array:
     return flat.reshape(-1, chunk)
 
 
+# Layout shared by the three kernels (what the TPU lowering accepts): a
+# pixel operand is ``(n_chunks, rows, chunk)`` and one grid step takes the
+# block ``(None, rows, chunk)`` — its last two dimensions are the array's
+# own, so ``rows`` may be 1.  Pixels run along the lanes, segments along
+# the sublanes: the one-hot is ``(segments, chunk)``, built by comparing a
+# sublane iota with the lane-major label row, and every contraction is an
+# ``A @ B.T`` over the pixel (lane) axis.  The grid is (segment tile,
+# pixel chunk): a tile's accumulator block stays resident while the chunk
+# axis walks, and the one-hot stays ``(_SEG_TILE, chunk)`` whatever the
+# capacity, so a capacity of thousands fits VMEM and each segment's
+# accumulation order is the chunk walk alone (capacity invariance).
+
+#: segments per tile: one lane-width, so it divides every lane-padded
+#: segment count
+_SEG_TILE = _LANE
+
+_CONTRACT_LANES = (((1,), (1,)), ((), ()))  # A (m, k) x B (n, k) -> (m, n)
+
+
+def _pixel_spec(rows: int, chunk: int) -> pl.BlockSpec:
+    return pl.BlockSpec((None, rows, chunk), lambda j, i: (i, 0, 0))
+
+
+def _tile_onehot(lab_row, tile_rows: int, chunk: int):
+    """(tile_rows, chunk) bool: segment ``j*tile_rows + r`` == label."""
+    ids = lax.broadcasted_iota(jnp.int32, (tile_rows, chunk), 0)
+    return (ids + pl.program_id(0) * tile_rows) == lab_row
+
+
 # ------------------------------------------------------------- stats kernel
 def _stats_kernel(lab_ref, val_ref, sums_ref, mins_ref, maxs_ref):
-    """One chunk's contribution to per-segment (sum, min, max) of every
-    channel.  The (chunk, segments) one-hot is materialized ONCE and
-    shared by the MXU sum contraction and the VPU masked min/max — the
-    fusion the separate grouped_sums/grouped_minmax passes cannot get."""
-    @pl.when(pl.program_id(0) == 0)
+    """One chunk's contribution to one segment tile's (sum, min, max) of
+    every channel.  The (segments, chunk) one-hot is materialized ONCE
+    and shared by the MXU sum contraction and the VPU masked min/max —
+    the fusion the separate grouped_sums/grouped_minmax passes cannot
+    get."""
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        sums_ref[:] = jnp.zeros_like(sums_ref)
-        mins_ref[:] = jnp.full_like(mins_ref, jnp.inf)
-        maxs_ref[:] = jnp.full_like(maxs_ref, -jnp.inf)
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+        mins_ref[...] = jnp.full_like(mins_ref, jnp.inf)
+        maxs_ref[...] = jnp.full_like(maxs_ref, -jnp.inf)
 
-    chunk = lab_ref.shape[1]
-    segs_p = sums_ref.shape[1]
-    n_ch = val_ref.shape[0]
-    lab = lab_ref[0, :]
-    ids = lax.broadcasted_iota(jnp.int32, (chunk, segs_p), 1)
-    sel = lab[:, None] == ids  # (chunk, segs_p)
-    vals = val_ref[:, 0, :]  # (n_ch, chunk)
+    n_ch, chunk = val_ref.shape
+    sel = _tile_onehot(lab_ref[...], sums_ref.shape[1], chunk)
+    vals = val_ref[...]  # (n_ch, chunk)
     # HIGHEST keeps f32 operand precision on the MXU — same contract as
     # grouped_sums' einsum, so integral sums stay exact / bit-identical
-    sums_ref[:] += lax.dot_general(
-        vals, sel.astype(jnp.float32), (((1,), (0,)), ((), ())),
+    sums_ref[...] += lax.dot_general(
+        vals, sel.astype(jnp.float32), _CONTRACT_LANES,
         precision=lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     for c in range(n_ch):  # static unroll: n_ch is a trace constant
-        v = vals[c, :][:, None]
-        mins_ref[c, :] = jnp.minimum(
-            mins_ref[c, :], jnp.min(jnp.where(sel, v, jnp.inf), axis=0)
+        v = vals[c:c + 1, :]
+        mins_ref[c] = jnp.minimum(
+            mins_ref[c],
+            jnp.min(jnp.where(sel, v, jnp.inf), axis=1, keepdims=True),
         )
-        maxs_ref[c, :] = jnp.maximum(
-            maxs_ref[c, :], jnp.max(jnp.where(sel, v, -jnp.inf), axis=0)
+        maxs_ref[c] = jnp.maximum(
+            maxs_ref[c],
+            jnp.max(jnp.where(sel, v, -jnp.inf), axis=1, keepdims=True),
         )
 
 
@@ -172,32 +204,32 @@ def _stats_call(flat, stacked, max_objects, interpret, chunk):
     segs = capacity_segments(max_objects)
     segs_p = _pad_lane(segs)
     n_ch = stacked.shape[0]
-    lab = _chunked(flat, chunk)
-    vals = jnp.stack([_chunked(v, chunk) for v in stacked])  # (C, n, chunk)
-    n_chunks = lab.shape[0]
+    lab = _chunked(flat, chunk)[:, None, :]  # (n, 1, chunk)
+    vals = jnp.stack(
+        [_chunked(v, chunk) for v in stacked], axis=1
+    )  # (n, C, chunk)
+    minmax_spec = pl.BlockSpec((n_ch, _SEG_TILE, 1), lambda j, i: (0, j, 0))
     sums, mins, maxs = pl.pallas_call(
         _stats_kernel,
-        grid=(n_chunks,),
-        in_specs=[
-            pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-            pl.BlockSpec((n_ch, 1, chunk), lambda i: (0, i, 0)),
-        ],
+        grid=(segs_p // _SEG_TILE, lab.shape[0]),
+        in_specs=[_pixel_spec(1, chunk), _pixel_spec(n_ch, chunk)],
         out_specs=[
-            pl.BlockSpec((n_ch, segs_p), lambda i: (0, 0)),
-            pl.BlockSpec((n_ch, segs_p), lambda i: (0, 0)),
-            pl.BlockSpec((n_ch, segs_p), lambda i: (0, 0)),
+            pl.BlockSpec((n_ch, _SEG_TILE), lambda j, i: (0, j)),
+            minmax_spec,
+            minmax_spec,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_ch, segs_p), jnp.float32)
-            for _ in range(3)
+            jax.ShapeDtypeStruct((n_ch, segs_p), jnp.float32),
+            jax.ShapeDtypeStruct((n_ch, segs_p, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n_ch, segs_p, 1), jnp.float32),
         ],
         interpret=interpret,
     )(lab, vals)
     # drop the background row and the lane padding; rows = objects
     return (
         sums[:, 1:segs].T,
-        mins[:, 1:segs].T,
-        maxs[:, 1:segs].T,
+        mins[:, 1:segs, 0].T,
+        maxs[:, 1:segs, 0].T,
     )
 
 
@@ -222,35 +254,22 @@ def grouped_stats(
 
 
 # --------------------------------------------------------- histogram kernel
-def _hist_kernel(lab_ref, img_ref, lo_ref, span_ref, counts_ref, *, bins):
-    """Per-(object, bucket) counts with the per-pixel bounds lookup and
-    quantization INSIDE the kernel.  The bounds lookup is a masked sum
-    over the label one-hot — exact (each pixel selects one finite table
-    entry), mirroring ``lookup_by_label``'s one-nonzero-term guarantee;
-    the quantization expression is ``quantize_per_object``'s verbatim,
-    so bucket assignment (and therefore every count) is bit-identical."""
-    @pl.when(pl.program_id(0) == 0)
+def _hist_kernel(lab_ref, q_ref, counts_ref):
+    """Per-(object, bucket) counts of one chunk for one segment tile: the
+    label one-hot against the bucket one-hot, both built in VMEM."""
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        counts_ref[:] = jnp.zeros_like(counts_ref)
+        counts_ref[...] = jnp.zeros_like(counts_ref)
 
     chunk = lab_ref.shape[1]
-    segs_p = lo_ref.shape[1]
-    bins_p = counts_ref.shape[1]
-    lab = lab_ref[0, :]
-    v = img_ref[0, :]
-    ids = lax.broadcasted_iota(jnp.int32, (chunk, segs_p), 1)
-    sel = lab[:, None] == ids
-    lo_pix = jnp.sum(jnp.where(sel, lo_ref[0, :][None, :], 0.0), axis=1)
-    span_pix = jnp.sum(jnp.where(sel, span_ref[0, :][None, :], 0.0), axis=1)
-    span_pix = jnp.maximum(span_pix, 1e-6)
-    q = jnp.floor((v - lo_pix) * (bins - 1) / span_pix)
-    q = jnp.clip(q, 0, bins - 1).astype(jnp.int32)
-    bin_ids = lax.broadcasted_iota(jnp.int32, (chunk, bins_p), 1)
-    oh_q = (q[:, None] == bin_ids).astype(jnp.bfloat16)
+    seg_t, bins_p = counts_ref.shape
+    sel = _tile_onehot(lab_ref[...], seg_t, chunk)
+    bin_ids = lax.broadcasted_iota(jnp.int32, (bins_p, chunk), 0)
+    oh_q = (bin_ids == q_ref[...]).astype(jnp.bfloat16)
     # bf16 one-hot operands are exact (0.0/1.0) and the MXU accumulates
     # f32 — integer counts < 2^24, the _glcm_matmul_all trick
-    counts_ref[:] += lax.dot_general(
-        sel.astype(jnp.bfloat16), oh_q, (((0,), (0,)), ((), ())),
+    counts_ref[...] += lax.dot_general(
+        sel.astype(jnp.bfloat16), oh_q, _CONTRACT_LANES,
         preferred_element_type=jnp.float32,
     )
 
@@ -258,47 +277,21 @@ def _hist_kernel(lab_ref, img_ref, lo_ref, span_ref, counts_ref, *, bins):
 @functools.partial(
     jax.jit, static_argnames=("max_objects", "bins", "interpret", "chunk")
 )
-def _hist_call(flat, img, lo_full, span_full, max_objects, bins,
-               interpret, chunk):
+def _hist_call(flat, q_flat, max_objects, bins, interpret, chunk):
     segs = capacity_segments(max_objects)
     segs_p = _pad_lane(segs)
     bins_p = _pad_lane(bins)
-    lab = _chunked(flat, chunk)
-    vals = _chunked(img, chunk)
-    # lane-pad the bounds tables; padded columns are never selected
-    # (labels <= max_objects), lo=0/span=1 keeps them inert regardless
-    lo_p = jnp.concatenate(
-        [lo_full, jnp.zeros((segs_p - segs,), jnp.float32)]
-    )[None, :]
-    span_p = jnp.concatenate(
-        [span_full, jnp.ones((segs_p - segs,), jnp.float32)]
-    )[None, :]
+    lab = _chunked(flat, chunk)[:, None, :]
+    q = _chunked(q_flat, chunk)[:, None, :]
     counts = pl.pallas_call(
-        functools.partial(_hist_kernel, bins=bins),
-        grid=(lab.shape[0],),
-        in_specs=[
-            pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-            pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-            pl.BlockSpec((1, segs_p), lambda i: (0, 0)),
-            pl.BlockSpec((1, segs_p), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((segs_p, bins_p), lambda i: (0, 0)),
+        _hist_kernel,
+        grid=(segs_p // _SEG_TILE, lab.shape[0]),
+        in_specs=[_pixel_spec(1, chunk), _pixel_spec(1, chunk)],
+        out_specs=pl.BlockSpec((_SEG_TILE, bins_p), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((segs_p, bins_p), jnp.float32),
         interpret=interpret,
-    )(lab, vals, lo_p, span_p)
+    )(lab, q)
     return counts[1:segs, :bins]
-
-
-def _masked_bounds(bounds):
-    """(raw_lo, raw_hi) → (lo_full, span_full) with the background row
-    prepended — the exact expression tree of ``quantize_per_object``."""
-    raw_lo, raw_hi = bounds
-    present = raw_hi >= raw_lo
-    lo = jnp.where(present, raw_lo, 0.0)
-    span = jnp.where(present, raw_hi - lo, 1.0)
-    lo_full = jnp.concatenate([jnp.zeros((1,), jnp.float32), lo])
-    span_full = jnp.concatenate([jnp.ones((1,), jnp.float32), span])
-    return lo_full, span_full
 
 
 def intensity_hist(
@@ -312,72 +305,58 @@ def intensity_hist(
     chunk: "int | None" = None,
 ) -> jax.Array:
     """Per-object intensity histogram ``(max_objects, bins)`` for
-    ``intensity_quantiles`` — quantization and accumulation fused in one
-    kernel pass.  ``bounds`` is the raw ``grouped_minmax`` output (±inf
-    for absent objects), normally the fused stats kernel's min/max so
-    the tile is read once for bounds and once for the histogram instead
-    of three-plus times."""
+    ``intensity_quantiles``.  ``bounds`` is the raw ``grouped_minmax``
+    output (±inf for absent objects), normally the fused stats kernel's
+    min/max.  The per-object stretch is ``quantize_per_object`` itself
+    (one elementwise XLA pass), so bucket assignment — and with it every
+    count — is bit-identical to the unfused strategies; the kernel
+    contracts the two one-hots without either leaving VMEM."""
+    from tmlibrary_tpu.ops.measure import quantize_per_object
+
     interpret, chunk = _resolve(interpret, chunk)
-    flat = jnp.asarray(labels, jnp.int32).reshape(-1)
-    img = jnp.asarray(intensity, jnp.float32).reshape(-1)
-    lo_full, span_full = _masked_bounds(bounds)
+    labels = jnp.asarray(labels, jnp.int32)
+    q = quantize_per_object(labels, intensity, max_objects, bins, bounds)
     return _hist_call(
-        flat, img, lo_full, span_full, max_objects, bins, interpret, chunk
+        labels.reshape(-1), q.reshape(-1), max_objects, bins,
+        interpret, chunk,
     )
 
 
 # -------------------------------------------------------------- GLCM kernel
-def _glcm_kernel(lab_ref, img_ref, lab2_ref, img2_ref, lo_ref, span_ref,
-                 counts_ref, *, levels, n_dirs):
-    """All directions' GLCM counts for one chunk: quantize the unshifted
-    and each direction's shifted pixels against the per-object bounds,
-    then contract the shared (label, q1) row one-hot against the
+#: GLCM accumulator rows (object x level cells) per tile: the
+#: (rows, chunk) row one-hot is the largest VMEM operand in the family
+_GLCM_ROW_TILE = 2048
+
+
+def _glcm_kernel(lab_ref, q_ref, lab2_ref, q2_ref, counts_ref,
+                 *, levels, n_dirs):
+    """All directions' GLCM counts of one chunk for one tile of (object,
+    level) rows: the (label, q1) row one-hot contracted against the
     concatenated per-direction column one-hots — ``_glcm_matmul_all``'s
-    factored contraction with the quantization pulled on chip."""
-    @pl.when(pl.program_id(0) == 0)
+    factored contraction with both one-hots built in VMEM."""
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        counts_ref[:] = jnp.zeros_like(counts_ref)
+        counts_ref[...] = jnp.zeros_like(counts_ref)
 
     chunk = lab_ref.shape[1]
-    segs_p = lo_ref.shape[1]
-    rows_p, cols_p = counts_ref.shape
-    lo_row = lo_ref[0, :][None, :]
-    span_row = span_ref[0, :][None, :]
-    seg_ids = lax.broadcasted_iota(jnp.int32, (chunk, segs_p), 1)
-
-    def quantize(lab, v):
-        sel = lab[:, None] == seg_ids
-        lo_pix = jnp.sum(jnp.where(sel, lo_row, 0.0), axis=1)
-        span_pix = jnp.maximum(
-            jnp.sum(jnp.where(sel, span_row, 0.0), axis=1), 1e-6
-        )
-        q = jnp.floor((v - lo_pix) * (levels - 1) / span_pix)
-        return jnp.clip(q, 0, levels - 1).astype(jnp.int32)
-
-    lab = lab_ref[0, :]
-    q = quantize(lab, img_ref[0, :])
-    row = jnp.where(lab > 0, lab * levels + q, 0)
-    row_ids = lax.broadcasted_iota(jnp.int32, (chunk, rows_p), 1)
-    oh_r = (row[:, None] == row_ids).astype(jnp.bfloat16)
-    lvl_ids = lax.broadcasted_iota(jnp.int32, (chunk, levels), 1)
+    rows_t, cols_p = counts_ref.shape
+    lab = lab_ref[...]  # (1, chunk)
+    row = jnp.where(lab > 0, lab * levels + q_ref[...], 0)
+    oh_r = _tile_onehot(row, rows_t, chunk).astype(jnp.bfloat16)
+    lvl_ids = lax.broadcasted_iota(jnp.int32, (levels, chunk), 0)
     cols = []
     for d in range(n_dirs):  # static unroll
-        lab2 = lab2_ref[d, 0, :]
-        q2 = quantize(lab2, img2_ref[d, 0, :])
-        valid = (lab > 0) & (lab2 == lab)
-        col = jnp.where(valid, q2, 0)
+        valid = (lab > 0) & (lab2_ref[d:d + 1, :] == lab)
         cols.append(
-            (col[:, None] == lvl_ids).astype(jnp.bfloat16)
-            * valid[:, None].astype(jnp.bfloat16)
+            ((lvl_ids == q2_ref[d:d + 1, :]) & valid).astype(jnp.bfloat16)
         )
     if cols_p > n_dirs * levels:
         cols.append(
-            jnp.zeros((chunk, cols_p - n_dirs * levels), jnp.bfloat16)
+            jnp.zeros((cols_p - n_dirs * levels, chunk), jnp.bfloat16)
         )
-    oh_c = jnp.concatenate(cols, axis=1)  # (chunk, cols_p)
-    counts_ref[:] += lax.dot_general(
-        oh_r, oh_c, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+    oh_c = jnp.concatenate(cols, axis=0)  # (cols_p, chunk)
+    counts_ref[...] += lax.dot_general(
+        oh_r, oh_c, _CONTRACT_LANES, preferred_element_type=jnp.float32,
     )
 
 
@@ -385,45 +364,35 @@ def _glcm_kernel(lab_ref, img_ref, lab2_ref, img2_ref, lo_ref, span_ref,
     jax.jit,
     static_argnames=("max_objects", "levels", "offsets", "interpret", "chunk"),
 )
-def _glcm_call(labels, img, lo_full, span_full, max_objects, levels,
-               offsets, interpret, chunk):
+def _glcm_call(labels, q, max_objects, levels, offsets, interpret, chunk):
     segs = capacity_segments(max_objects)
-    segs_p = _pad_lane(segs)
     k = len(offsets)
-    rows_p = _pad_lane(segs * levels)
+    rows_t = min(_GLCM_ROW_TILE, _pad_lane(segs * levels))
+    rows_p = -(-segs * levels // rows_t) * rows_t
     cols_p = _pad_lane(k * levels)
-    lab = _chunked(labels.reshape(-1), chunk)
-    vals = _chunked(img.reshape(-1), chunk)
+    lab = _chunked(labels.reshape(-1), chunk)[:, None, :]
+    q1 = _chunked(q.reshape(-1), chunk)[:, None, :]
+    # a shifted pixel was quantized against its OWN object's bounds, so
+    # shifting the quantized image equals quantizing the shifted one
     lab2 = jnp.stack([
         _chunked(shift_with_fill(labels, -dy, -dx, 0).reshape(-1), chunk)
         for dy, dx in offsets
-    ])
-    img2 = jnp.stack([
-        _chunked(shift_with_fill(img, -dy, -dx, 0.0).reshape(-1), chunk)
+    ], axis=1)  # (n, k, chunk)
+    q2 = jnp.stack([
+        _chunked(shift_with_fill(q, -dy, -dx, 0).reshape(-1), chunk)
         for dy, dx in offsets
-    ])
-    lo_p = jnp.concatenate(
-        [lo_full, jnp.zeros((segs_p - segs,), jnp.float32)]
-    )[None, :]
-    span_p = jnp.concatenate(
-        [span_full, jnp.ones((segs_p - segs,), jnp.float32)]
-    )[None, :]
-    n_chunks = lab.shape[0]
+    ], axis=1)
     counts = pl.pallas_call(
         functools.partial(_glcm_kernel, levels=levels, n_dirs=k),
-        grid=(n_chunks,),
+        grid=(rows_p // rows_t, lab.shape[0]),
         in_specs=[
-            pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-            pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-            pl.BlockSpec((k, 1, chunk), lambda i: (0, i, 0)),
-            pl.BlockSpec((k, 1, chunk), lambda i: (0, i, 0)),
-            pl.BlockSpec((1, segs_p), lambda i: (0, 0)),
-            pl.BlockSpec((1, segs_p), lambda i: (0, 0)),
+            _pixel_spec(1, chunk), _pixel_spec(1, chunk),
+            _pixel_spec(k, chunk), _pixel_spec(k, chunk),
         ],
-        out_specs=pl.BlockSpec((rows_p, cols_p), lambda i: (0, 0)),
+        out_specs=pl.BlockSpec((rows_t, cols_p), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_p, cols_p), jnp.float32),
         interpret=interpret,
-    )(lab, vals, lab2, img2, lo_p, span_p)
+    )(lab, q1, lab2, q2)
     out = []
     for d in range(k):
         glcm = counts[: segs * levels, d * levels : (d + 1) * levels]
@@ -444,18 +413,21 @@ def glcm_all(
     chunk: "int | None" = None,
 ) -> list[jax.Array]:
     """All directions' symmetrized per-object GLCMs
-    (``(max_objects, levels, levels)`` each) in one fused pass —
-    quantization included.  ``bounds`` is the raw per-object min/max of
-    ``intensity`` (the fused stats kernel supplies it).  The chunk is
-    clamped to :data:`GLCM_CHUNK_MAX`: the (chunk, segments×levels) row
-    one-hot dominates the kernel's VMEM budget (DESIGN.md §22)."""
+    (``(max_objects, levels, levels)`` each) in one fused pass.
+    ``bounds`` is the raw per-object min/max of ``intensity`` (the fused
+    stats kernel supplies it); the per-object stretch is
+    ``quantize_per_object`` itself.  The chunk is clamped to
+    :data:`GLCM_CHUNK_MAX` and the (object, level) rows are tiled by
+    :data:`_GLCM_ROW_TILE`: the row one-hot dominates the kernel's VMEM
+    budget (DESIGN.md §22)."""
+    from tmlibrary_tpu.ops.measure import quantize_per_object
+
     interpret, chunk = _resolve(interpret, chunk)
     chunk = min(chunk, GLCM_CHUNK_MAX)
     labels = jnp.asarray(labels, jnp.int32)
-    img = jnp.asarray(intensity, jnp.float32)
-    lo_full, span_full = _masked_bounds(bounds)
+    q = quantize_per_object(labels, intensity, max_objects, levels, bounds)
     return _glcm_call(
-        labels, img, lo_full, span_full, max_objects, levels,
+        labels, q, max_objects, levels,
         tuple(tuple(o) for o in offsets), interpret, chunk,
     )
 
@@ -474,33 +446,36 @@ def vmem_bytes_estimate(
     """Coarse on-chip working-set estimate (bytes) for one measure pass
     at ``capacity`` — the number bench sweep rows record so a rung's
     VMEM pressure is readable next to its throughput.  For ``"fused"``
-    it is the worst kernel's resident bytes (inputs + one-hots +
-    accumulator, per DESIGN.md §22's budget table); for the unfused
+    it is the worst kernel's resident bytes per grid step (inputs +
+    one-hots + accumulator tile); for the unfused
     strategies, the dominant chunked one-hot / accumulator operand of
     the XLA path (a bound on what XLA must keep live per chunk
     iteration, not a Pallas budget)."""
     segs = capacity_segments(capacity)
-    segs_p = _pad_lane(segs)
     if chunk is None:
         chunk = fused_chunk()
     if strategy == "fused":
+        # the segment axis is tiled, so stats and the histogram no longer
+        # grow with the capacity; the GLCM grows until its rows fill a tile
         gchunk = min(chunk, GLCM_CHUNK_MAX)
+        rows_t = min(_GLCM_ROW_TILE, _pad_lane(segs * levels))
+        cols_p = _pad_lane(n_directions * levels)
         stats = (
             chunk * (1 + n_channels) * 4      # label + channel blocks
-            + chunk * segs_p * 4              # shared one-hot / mask
-            + 3 * n_channels * segs_p * 4     # sum/min/max accumulators
+            + _SEG_TILE * chunk * 4           # shared one-hot / mask
+            + 3 * n_channels * _SEG_TILE * 4  # sum/min/max accumulators
         )
         hist = (
-            chunk * 2 * 4                     # label + value blocks
-            + chunk * segs_p * 4              # label one-hot
-            + chunk * _pad_lane(bins) * 2     # bucket one-hot (bf16)
-            + segs_p * _pad_lane(bins) * 4    # counts accumulator
+            chunk * 2 * 4                     # label + bucket blocks
+            + _SEG_TILE * chunk * 2           # label one-hot (bf16)
+            + _pad_lane(bins) * chunk * 2     # bucket one-hot (bf16)
+            + _SEG_TILE * _pad_lane(bins) * 4  # counts accumulator
         )
         glcm = (
-            gchunk * 2 * (1 + n_directions) * 4       # shifted pixel blocks
-            + gchunk * _pad_lane(segs * levels) * 2   # row one-hot (bf16)
-            + gchunk * _pad_lane(n_directions * levels) * 2
-            + _pad_lane(segs * levels) * _pad_lane(n_directions * levels) * 4
+            gchunk * 2 * (1 + n_directions) * 4   # label + level blocks
+            + rows_t * gchunk * 2                 # row one-hot (bf16)
+            + cols_p * gchunk * 2                 # column one-hots (bf16)
+            + rows_t * cols_p * 4                 # counts accumulator
         )
         return max(stats, hist, glcm)
     if strategy == "onehot":

@@ -66,6 +66,12 @@ def _run_min_scan(labels: jax.Array, mask: jax.Array, axis: int) -> jax.Array:
     """Propagate the min label across contiguous foreground runs along
     ``axis`` via a segmented associative scan (both directions) — O(log N)
     depth, no gathers (TPU gathers are the slow path)."""
+    if axis == 0:
+        # scan columns as the rows of the transpose: the TPU compiler
+        # needs ~35x longer for a scan along the second-minor axis (88 s
+        # against 2.5 s at 1024x1024 on a described v5e, and no end in
+        # sight at 2160x2160 — PERF.md, PR 21); min is min either way
+        return _run_min_scan(labels.T, mask.T, 1).T
     # run start: previous element along the axis is background
     is_start = mask & ~_shift_with_fill(
         mask, *((-1, 0) if axis == 0 else (0, -1)), False
@@ -88,6 +94,18 @@ def _run_min_scan(labels: jax.Array, mask: jax.Array, axis: int) -> jax.Array:
     resets_r = is_end | ~mask
     bwd, _ = lax.associative_scan(op, (fwd, resets_r), axis=axis, reverse=True)
     return jnp.where(mask, bwd, _BIG)
+
+
+def _row_major_ranks(flags: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Inclusive running count of ``flags`` in row-major order, flat, and
+    the total.  Computed as a cumsum along each row plus the exclusive
+    cumsum of the row totals: the same integers as one flat cumsum,
+    which costs the TPU compiler 24 s per megapixel (PERF.md, PR 21)."""
+    flags = flags.astype(jnp.int32)
+    in_row = jnp.cumsum(flags, axis=1)
+    row_totals = in_row[:, -1]
+    before = jnp.cumsum(row_totals) - row_totals
+    return (in_row + before[:, None]).reshape(-1), jnp.sum(row_totals)
 
 
 def connected_components(
@@ -129,7 +147,9 @@ def connected_components(
         if native.cpu_native_enabled():
             method = "native"
         else:
-            method = "pallas" if pallas_enabled("cc") else "xla"
+            method = (
+                "pallas" if pallas_enabled("cc", mask.shape) else "xla"
+            )
     if method == "native":
         import numpy as np
 
@@ -181,8 +201,7 @@ def connected_components(
 
     # compact to 1..N in row-major order of component roots (scipy order)
     is_root = mask & (labels == linear)
-    ranks = jnp.cumsum(is_root.reshape(-1).astype(jnp.int32))
-    count = ranks[-1]
+    ranks, count = _row_major_ranks(is_root)
     root_rank = ranks.reshape(-1)[jnp.clip(labels.reshape(-1), 0, h * w - 1)]
     out = jnp.where(mask, root_rank.reshape(h, w), 0).astype(jnp.int32)
     return out, count
@@ -240,7 +259,9 @@ def fill_holes(
         else:
             from tmlibrary_tpu.ops.pallas_kernels import pallas_enabled
 
-            method = "pallas" if pallas_enabled("fill") else "xla"
+            method = (
+                "pallas" if pallas_enabled("fill", mask.shape) else "xla"
+            )
     if method == "pallas":
         from tmlibrary_tpu.ops.pallas_kernels import fill_holes_flood
 
@@ -267,29 +288,28 @@ def fill_holes(
     if connectivity not in (4, 8):
         raise ValueError("connectivity must be 4 or 8")
 
-    def cond(state):
-        reach, changed = state
-        return changed
-
     # diagonal steps are only relevant at 8-connectivity; the run scans
     # below fully cover horizontal/vertical propagation
     diag = [] if connectivity == 4 else [(-1, -1), (-1, 1), (1, -1), (1, 1)]
 
+    # The flood is connected_components' own fixpoint on a two-valued
+    # label image (0 = reached from the border, 1 = not yet; run min 0
+    # means the whole background run is reached), carried as int32 with
+    # the same body.  That is also what the TPU compiler accepts at a
+    # full field: the former bool carry with its int32 round trip between
+    # the two scans took 94 s to compile at 1024x1024 against 9 s for
+    # this form, and did not finish at 2160x2160, where this one takes
+    # 11 s (described v5e; PERF.md, PR 21).
     def body(state):
-        reach, _ = state
-        grown = reach
-        for dy, dx in diag:
-            grown = grown | _shift_with_fill(reach, dy, dx, False)
-        grown = grown & bg
-        # flood entire background runs at once (reuse the min run-scan:
-        # 0 = reached, 1 = not; run min 0 means the whole run is reached)
-        for axis in (1, 0):
-            v = jnp.where(grown, 0, 1).astype(jnp.int32)
-            runmin = _run_min_scan(v, bg, axis)
-            grown = (runmin == 0) & bg
-        return grown, jnp.any(grown != reach)
+        v, _ = state
+        new = _propagate_min(v, bg, diag) if diag else v
+        new = _run_min_scan(new, bg, axis=1)
+        new = _run_min_scan(new, bg, axis=0)
+        return new, jnp.any(new != v)
 
-    reach, _ = lax.while_loop(cond, body, (seed, jnp.bool_(True)))
+    init = jnp.where(bg, jnp.where(seed, 0, 1), _BIG).astype(jnp.int32)
+    v, _ = lax.while_loop(lambda s: s[1], body, (init, jnp.bool_(True)))
+    reach = v == 0
     return mask | (bg & ~reach)
 
 

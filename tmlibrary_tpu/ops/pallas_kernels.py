@@ -55,6 +55,53 @@ BIG = 2**30
 CHUNK = 8
 
 
+#: A whole-site kernel keeps the site and every temporary of one
+#: propagation step resident in VMEM.  The compiler's own scoped limit
+#: (16 MiB) holds a 256x256 site but not a 16x128x128 volume, whose
+#: 26-neighbour step was refused at 17.9 MB (CC) and 21.1 MB (watershed)
+#: on a described v5e — about 20 int32 copies of the block.  So each
+#: kernel asks for that many copies, with headroom.
+_VMEM_COPIES = 24
+#: largest block (int32 bytes) ``method="auto"`` gives a whole-site
+#: kernel: 512x512 or 16x128x128.  Mosaic unrolls the step over every
+#: vreg of the block, so compile time grows faster than the block: CC
+#: compiled in 1.5 s at 256x256, 19 s at 512x512 and 226 s at 1024x1024
+#: on a described v5e, and a 2160x2160 plane (18.7 MB) neither fits nor
+#: finishes compiling (PERF.md, PR 21).  Larger sites need the tiled
+#: kernel ROADMAP A4/C6 describe; until then they take the XLA twin.
+_MAX_BLOCK_BYTES = 1 << 20
+
+
+def _block_bytes(shape) -> int:
+    n = 4
+    for d in shape:
+        n *= int(d)
+    return n
+
+
+def _vmem_limit(shape) -> int:
+    """Scoped-VMEM request (bytes) of a whole-site kernel on ``shape``."""
+    return max(16 * 1024 * 1024, _VMEM_COPIES * _block_bytes(shape))
+
+
+def fits_vmem(shape) -> bool:
+    """Whether ``method="auto"`` may route a ``shape`` site to a
+    whole-site kernel at all (see :data:`_MAX_BLOCK_BYTES`)."""
+    return _block_bytes(shape) <= _MAX_BLOCK_BYTES
+
+
+def _compiler_params(shape) -> "pltpu.CompilerParams":
+    return pltpu.CompilerParams(vmem_limit_bytes=_vmem_limit(shape))
+
+
+#: the 3-D twins' default interval.  A 26-neighbour step is 3.25x a 2-D
+#: one and the loop body unrolls ``chunk`` of them: on a described v5e
+#: the 16x128x128 kernels compiled in 79 s (CC) and 161 s (watershed) at
+#: chunk 8 against 8 s and 16 s at chunk 2 (PERF.md, PR 21); labels are
+#: the same for any chunk.
+CHUNK_3D = 2
+
+
 def _tuned_chunk() -> int:
     """Resolution: explicit arg (callers/tuner) → TMX_PALLAS_CHUNK env →
     committed ``pallas_chunk`` sweep result → the default."""
@@ -141,12 +188,13 @@ def _cc_kernel(mask_ref, out_ref, *, connectivity: int, chunk: int):
     out_ref[:] = labels
 
 
-def _resolve_chunk(chunk: "int | None") -> int:
-    """Explicit value (validated ≥ 1) or the tuned default — resolved
-    OUTSIDE jit so a changed TMX_PALLAS_CHUNK / re-written TUNING.json
-    is picked up per call instead of being baked into the first trace."""
+def _resolve_chunk(chunk: "int | None", default: "int | None" = None) -> int:
+    """Explicit value (validated ≥ 1), else ``default``, else the tuned
+    default — resolved OUTSIDE jit so a changed TMX_PALLAS_CHUNK /
+    re-written TUNING.json is picked up per call instead of being baked
+    into the first trace."""
     if chunk is None:
-        return _tuned_chunk()
+        return default if default is not None else _tuned_chunk()
     if not isinstance(chunk, int) or chunk < 1:
         raise ValueError(f"chunk must be an int >= 1, got {chunk!r}")
     return chunk
@@ -166,6 +214,7 @@ def _cc_min_propagate_jit(
         out_shape=jax.ShapeDtypeStruct((h, w), jnp.int32),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        compiler_params=_compiler_params((h, w)),
         interpret=interpret,
     )(jnp.asarray(mask, jnp.int32))
 
@@ -253,6 +302,7 @@ def _watershed_flood_jit(
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        compiler_params=_compiler_params((h, w)),
         interpret=interpret,
     )(
         jnp.asarray(intensity, jnp.float32),
@@ -327,6 +377,7 @@ def _fill_holes_jit(
         out_shape=jax.ShapeDtypeStruct((h, w), jnp.int32),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        compiler_params=_compiler_params((h, w)),
         interpret=interpret,
     )(jnp.asarray(mask, jnp.int32))
 
@@ -428,6 +479,7 @@ def _cc3d_min_propagate_jit(
         out_shape=jax.ShapeDtypeStruct((z, h, w), jnp.int32),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        compiler_params=_compiler_params((z, h, w)),
         interpret=interpret,
     )(jnp.asarray(mask, jnp.int32))
 
@@ -442,7 +494,7 @@ def cc3d_min_propagate(
     in ``ops.volume.connected_components_3d`` (which then compacts to
     scipy order)."""
     return _cc3d_min_propagate_jit(
-        mask, connectivity, interpret, _resolve_chunk(chunk)
+        mask, connectivity, interpret, _resolve_chunk(chunk, CHUNK_3D)
     )
 
 
@@ -514,6 +566,7 @@ def _watershed3d_flood_jit(
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        compiler_params=_compiler_params((z, h, w)),
         interpret=interpret,
     )(
         jnp.asarray(intensity, jnp.float32),
@@ -534,7 +587,8 @@ def watershed3d_flood(
     (Z, H, W) volume in VMEM — same schedule and tie-breaking as
     ``ops.volume.watershed_from_seeds_3d``'s XLA path."""
     return _watershed3d_flood_jit(
-        intensity, seeds, mask, n_levels, interpret, _resolve_chunk(chunk)
+        intensity, seeds, mask, n_levels, interpret,
+        _resolve_chunk(chunk, CHUNK_3D),
     )
 
 
@@ -583,6 +637,7 @@ def distance_transform(
         out_shape=jax.ShapeDtypeStruct((h, w), jnp.float32),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        compiler_params=_compiler_params((h, w)),
         interpret=interpret,
     )(jnp.asarray(mask, jnp.int32))
 
@@ -638,8 +693,12 @@ def _tuning_results() -> dict:
 _tuning_results.cache_clear = _TUNING_CACHE.clear
 
 
-def pallas_enabled(kernel: str | None = None) -> bool:
+def pallas_enabled(kernel: str | None = None, shape=None) -> bool:
     """Whether ``method="auto"`` dispatches to the pallas kernels.
+
+    ``shape`` is the site (or volume) the caller is about to dispatch: a
+    block larger than a whole-site kernel can hold (:func:`fits_vmem`)
+    takes the XLA twin whatever the verdicts below say.
 
     Resolution order on TPU-class backends: the ``TMX_PALLAS`` env var
     (explicit global override) → the committed per-kernel shootout
@@ -662,6 +721,8 @@ def pallas_enabled(kernel: str | None = None) -> bool:
     import os
 
     if jax.default_backend() in ("cpu", "gpu"):
+        return False
+    if shape is not None and not fits_vmem(shape):
         return False
     env = os.environ.get("TMX_PALLAS")
     if env is not None:

@@ -44,7 +44,9 @@ def distance_transform_approx(
         else:
             from tmlibrary_tpu.ops.pallas_kernels import pallas_enabled
 
-            method = "pallas" if pallas_enabled("distance") else "xla"
+            method = (
+                "pallas" if pallas_enabled("distance", mask.shape) else "xla"
+            )
     if method == "native":
         import numpy as np
 

@@ -102,7 +102,9 @@ def watershed_from_seeds(
         else:
             from tmlibrary_tpu.ops.pallas_kernels import pallas_enabled
 
-            method = "pallas" if pallas_enabled("watershed") else "xla"
+            method = "xla"
+            if pallas_enabled("watershed", jnp.shape(intensity)):
+                method = "pallas"
     if method == "pallas":
         from tmlibrary_tpu.ops.pallas_kernels import watershed_flood
 

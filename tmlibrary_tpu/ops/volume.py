@@ -96,7 +96,9 @@ def connected_components_3d(
         else:
             from tmlibrary_tpu.ops.pallas_kernels import pallas_enabled
 
-            method = "pallas" if pallas_enabled("cc3d") else "xla"
+            method = (
+                "pallas" if pallas_enabled("cc3d", mask.shape) else "xla"
+            )
     if method == "native":
         import numpy as np
 
@@ -210,7 +212,9 @@ def watershed_from_seeds_3d(
         else:
             from tmlibrary_tpu.ops.pallas_kernels import pallas_enabled
 
-            method = "pallas" if pallas_enabled("watershed3d") else "xla"
+            method = "xla"
+            if pallas_enabled("watershed3d", intensity.shape):
+                method = "pallas"
     if method == "pallas":
         from tmlibrary_tpu.ops.pallas_kernels import watershed3d_flood
 

@@ -397,12 +397,9 @@ class ServeDaemon:
         #: deferring to it.  The compilation cache rides along — serve
         #: is the long-lived process the cache exists for.
         aotstore.set_process_default_dir(str(aot_store_path(self.serve_root)))
-        try:
-            from tmlibrary_tpu.utils import enable_compilation_cache
+        from tmlibrary_tpu.utils import enable_compilation_cache
 
-            enable_compilation_cache(cfg.compile_cache_dir or None)
-        except Exception:
-            logger.debug("compilation cache setup failed", exc_info=True)
+        enable_compilation_cache()
         #: throttled store-stats cache for _publish_state/_should_defer —
         #: (monotonic_ts, stats dict); listing the store every poll-loop
         #: iteration would hammer the shared filesystem

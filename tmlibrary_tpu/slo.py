@@ -32,7 +32,7 @@ Burn-rate semantics (documented in DESIGN.md §21): over each window ``W``
 * a tenant's burn is the max of the two, over the worst window.
 
 Breaches are **warn-only**: the daemon appends an ``slo_burn`` ledger
-event (which ``scripts/tpu_watch.py`` surfaces and ``tmx top`` renders)
+event (which ``tmx top`` renders)
 and never aborts or sheds on its own — the same contract QC has.  Exit
 codes for ``tmx slo`` are pinned like the other sentinels: 0 ok,
 1 burn ≥ 1 for some tenant, 3 no job-completion data.

@@ -16,7 +16,7 @@ metrics and adds what the ledger alone cannot show:
   line up with device traces in XProf;
 * a :class:`ResourceSampler` daemon thread (RSS, open file handles, jax
   device memory when available) that also maintains a heartbeat timestamp
-  file consumed by ``tmx workflow status`` and ``scripts/tpu_watch.py``;
+  file consumed by ``tmx workflow status`` and ``tmx top``;
 * export surfaces: Prometheus textfile format and JSON, renderable from
   the live registry or derived post-hoc from any ledger
   (:func:`registry_from_ledger`), plus a span-tree builder with
@@ -805,7 +805,7 @@ def _device_memory_bytes() -> int | None:
 
 def heartbeat_path(workflow_dir: Path, host: str | None = None) -> Path:
     """Where this host's heartbeat lives: the legacy single-host name for
-    ``host0`` (so existing status/watcher consumers keep working), a
+    ``host0`` (so existing status consumers keep working), a
     per-host ``heartbeat.<host>.json`` for every other fleet member."""
     h = host or host_id()
     if h == "host0":
@@ -866,8 +866,8 @@ class ResourceSampler:
 
     Each tick sets gauges (``tmx_process_rss_bytes``,
     ``tmx_process_open_fds``, ``tmx_device_bytes_in_use``) and refreshes the
-    heartbeat file so ``tmx workflow status`` and ``scripts/tpu_watch.py``
-    can tell a hung run from a slow one.
+    heartbeat file so ``tmx workflow status`` and ``tmx top`` can tell a
+    hung run from a slow one.
     """
 
     def __init__(self, period: float, heartbeat_path: Path | None = None,
@@ -1378,8 +1378,6 @@ def registry_from_ledger(events: Iterable[dict]) -> MetricsRegistry:
             reg.counter("tmx_steps_failed_total", step=step, **hl).inc()
         elif kind == "depth_clamped":
             reg.counter("tmx_depth_clamps_total", step=step, **hl).inc()
-        elif kind == "backend_degraded":
-            reg.counter("tmx_backend_degradations_total", **hl).inc()
         elif kind == "span":
             name = str(ev.get("span", "")) or "unknown"
             if "elapsed" in ev:

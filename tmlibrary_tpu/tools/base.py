@@ -248,6 +248,21 @@ class Tool(abc.ABC):
         """Handle one tool request (reference ``Tool.process_request``)."""
 
 
+def _caller_holds_accelerator() -> bool:
+    """True when this process has initialised a JAX backend other than
+    the CPU's.  Asked without initialising one: a process that never
+    used a device must not take the chip just to find out."""
+    import sys
+
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    from jax._src import xla_bridge
+
+    return (xla_bridge.backends_are_initialized()
+            and jax.default_backend() != "cpu")
+
+
 class ToolRequestManager:
     """Submit tool requests with a persisted lifecycle
     (reference ``tmlib/tools/manager.py`` ``ToolRequestManager``: submits
@@ -300,22 +315,31 @@ class ToolRequestManager:
         """Detached submit (reference ``ToolJob`` fan-out): spawns
         ``tmx tool run-request`` as its own session with stdout/stderr
         captured to ``<request>/tool.log`` and returns the request id
-        immediately.  Poll with :meth:`status` / ``tmx tool list``."""
+        immediately.  Poll with :meth:`status` / ``tmx tool list``.
+
+        An accelerator belongs to one process at a time.  A caller that
+        holds it (the serve daemon, a running workflow) therefore starts
+        the child on the CPU platform; a caller that has not touched a
+        device (``tmx tool submit --background``) leaves the child the
+        default platform."""
+        import os
         import subprocess
         import sys
 
         request_id = self.create_request(tool_name, payload)
-        log = open(self._request_dir(request_id) / "tool.log", "w")
-        subprocess.Popen(
-            [
-                sys.executable, "-m", "tmlibrary_tpu.cli", "tool",
-                "run-request", "--root", str(self.store.root),
-                "--request", request_id,
-            ],
-            stdout=log, stderr=subprocess.STDOUT,
-            start_new_session=True,
-        )
-        log.close()
+        env = dict(os.environ)
+        if _caller_holds_accelerator():
+            env["JAX_PLATFORMS"] = "cpu"
+        with open(self._request_dir(request_id) / "tool.log", "w") as log:
+            subprocess.Popen(
+                [
+                    sys.executable, "-m", "tmlibrary_tpu.cli", "tool",
+                    "run-request", "--root", str(self.store.root),
+                    "--request", request_id,
+                ],
+                stdout=log, stderr=subprocess.STDOUT,
+                start_new_session=True, env=env,
+            )
         return request_id
 
     def run_request(self, request_id: str) -> ToolResult:

@@ -9,7 +9,7 @@ writes next to its ledger — per-host ``heartbeat*.json``, per-host
 Deliberately curses-free: a plain ANSI clear-and-repaint loop degrades to
 sensible output in CI logs and over ssh, and ``--once`` renders a single
 frame for tests.  Nothing here ever initializes a jax backend — the
-dashboard must be runnable from a watcher box that has no accelerator.
+dashboard must be runnable from a box that has no accelerator.
 """
 
 from __future__ import annotations
@@ -37,7 +37,7 @@ def collect_fleet(root: Path) -> dict[str, Any]:
     repaint frequency against a live run."""
     wf = _workflow_dir(root)
     view: dict[str, Any] = {"root": str(root), "hosts": [], "merged": None,
-                            "status": {}, "degraded": None, "qc": None,
+                            "status": {}, "qc": None,
                             "preempted": None}
     for hb_path in sorted(wf.glob("heartbeat*.json")):
         hb = telemetry.read_heartbeat(hb_path)
@@ -65,7 +65,6 @@ def collect_fleet(root: Path) -> dict[str, Any]:
 
         ledger = RunLedger(ledger_path)
         view["status"] = ledger.status()
-        view["degraded"] = ledger.degraded_backend()
         view["preempted"] = ledger.preempted()
     # qc.py is numpy + stdlib only — no jax backend touched (see module
     # docstring constraint)
@@ -418,15 +417,6 @@ def render_dashboard(view: dict, width: int = 80) -> str:
             total = sum(anom.values())
             per = " ".join(f"{m}:{n}" for m, n in sorted(anom.items()))
             lines.append(f"  ANOMALY x{total}  {per}")
-
-    # ---- breaker / degradation state
-    deg = view["degraded"]
-    if deg:
-        lines.append(
-            f"DEGRADED: backend fell back to {deg.get('backend')} at "
-            f"'{deg.get('where')}' after {deg.get('failures')} failed "
-            "device probes"
-        )
 
     # ---- preemption drain boundary (cleared by the next run_started)
     pre = view.get("preempted")

@@ -18,8 +18,8 @@ Format JSON (the ``chrome://tracing`` / Perfetto interchange format):
   ``telemetry.build_span_tree``'s fallback.
 
 For a serve root, :func:`collect_events` merges the serve ledger with
-every experiment ledger the spooled job specs reference (the same
-resolution ``tpu_watch`` uses), so the export covers the full
+every experiment ledger the spooled job specs reference, so the export
+covers the full
 enqueue→result path without the daemon's help.
 """
 

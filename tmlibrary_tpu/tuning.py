@@ -5,13 +5,13 @@ The hardware sweep (``scripts/tune_tpu.py``) writes its verdict —
 fetch-amortization depth — into ``tuning/TUNING.json``.  This module is the
 ONE runtime consumer shared by the production engine (the pipelined batch
 executor's default depth, jterator's auto batch size) and ``bench.py``
-(which re-exports these loaders so the watcher scripts keep one definition
-of the artifact path).
+(which re-exports these loaders so the scripts keep one definition of
+the artifact path).
 
 Provenance gate: only a file ``tune_tpu.py write_results`` itself produced
 counts.  Hand-seeded or dry-run (``SMOKE``) artifacts never set production
 defaults — a tuned default the hardware never measured is worse than a
-static one.  ``TMX_TUNING_JSON`` redirects the file (watcher rehearsal).
+static one.  ``TMX_TUNING_JSON`` redirects the file (rehearsals, tests).
 """
 
 from __future__ import annotations
@@ -39,30 +39,21 @@ def _tuning_dir() -> str:
     return os.path.dirname(os.path.abspath(tuning_json_path()))
 
 
-def bench_cache_path() -> str:
-    """The watcher-written cache of freshest on-hardware bench records
-    (``tuning/BENCH_TPU.json``); ``BENCH_TPU_CACHE`` redirects it — same
-    contract bench.py's CACHE_PATH has always had, now importable by the
-    perf layer without importing bench."""
-    return os.environ.get(
-        "BENCH_TPU_CACHE", os.path.join(_tuning_dir(), "BENCH_TPU.json")
-    )
-
-
 def bench_history_path() -> str:
     """Append-only bench history (``tuning/BENCH_HISTORY.jsonl``) — one
     JSON line per emitted bench/sweep record, the regression sentinel's
     input.  ``BENCH_HISTORY`` redirects it (tests, CI smoke); with no
-    redirect it follows ``TMX_TUNING_JSON``'s directory so watcher
-    rehearsal redirects the whole artifact family at once."""
+    redirect it follows ``TMX_TUNING_JSON``'s directory so one redirect
+    moves the whole artifact family at once."""
     return os.environ.get(
         "BENCH_HISTORY", os.path.join(_tuning_dir(), "BENCH_HISTORY.jsonl")
     )
 
 
 def recapture_path() -> str:
-    """Re-capture queue the regression sentinel writes and
-    ``scripts/tpu_watch.py`` drains (``tuning/RECAPTURE.json``)."""
+    """Re-capture queue the regression sentinel writes
+    (``tuning/RECAPTURE.json``): the labels to measure again on the
+    chip."""
     return os.environ.get(
         "WATCH_RECAPTURE", os.path.join(_tuning_dir(), "RECAPTURE.json")
     )
@@ -138,8 +129,14 @@ def tuned_pipeline_depth() -> int | None:
     return _positive_int(tuning.get("best_pipeline")) if tuning else None
 
 
+#: pixels of the site ``best_batch`` was swept at: ``scripts/tune_tpu.py``
+#: runs bench.py at its default 256x256 site (``BENCH_SITE_SIZE``)
+TUNED_SITE_PIXELS = 256 * 256
+
+
 def tuned_batch_size() -> int | None:
-    """The hardware-swept ``best_batch`` site batch, or None."""
+    """The hardware-swept ``best_batch`` site batch (sites of
+    :data:`TUNED_SITE_PIXELS` pixels), or None."""
     tuning = load_tuning()
     return _positive_int(tuning.get("best_batch")) if tuning else None
 

@@ -8,6 +8,7 @@ type-assertion helpers.
 from __future__ import annotations
 
 import itertools
+import os
 from typing import Any, Iterable, Sequence
 
 
@@ -54,29 +55,33 @@ def next_power_of_two(n: int) -> int:
     return 1 << (n - 1).bit_length()
 
 
-def enable_compilation_cache(directory=None) -> str | None:
-    """Turn on JAX's persistent compilation cache so repeated CLI/bench
-    invocations skip recompiling the fused pipeline (first compiles are
-    tens of seconds).  ``TMX_NO_COMPILE_CACHE=1`` disables; the default
-    directory is ``~/.cache/tmlibrary_tpu/xla``.  Returns the directory
-    used, or None when disabled/unsupported."""
+def checkout_cache_dir(name: str) -> str:
+    """``<checkout>/.cache/<name>``: the fixed in-tree home of everything
+    the program caches when the environment names no place for it.  The
+    path is part of the compile cache's key, so it never carries a temp
+    name, pid or timestamp, and it is never under ``$HOME``."""
     import os
 
-    if os.environ.get("TMX_NO_COMPILE_CACHE"):
-        return None
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(root, ".cache", name)
+
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache so repeated CLI/bench
+    invocations skip recompiling the fused pipeline, and return its
+    directory.  The directory is the environment's to choose: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX has already read it and no
+    directory is set here; otherwise the cache lives at
+    ``<checkout>/.cache/xla``.  Only the thresholds are set in code
+    (cache everything, not only long compiles)."""
     import jax
 
-    path = str(
-        directory
-        or os.environ.get("TMX_COMPILE_CACHE_DIR")
-        or os.path.expanduser("~/.cache/tmlibrary_tpu/xla")
-    )
-    try:
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = checkout_cache_dir("xla")
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        # cache everything, not only long compiles
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # older jax or read-only home: cache is best-effort
-        return None
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path

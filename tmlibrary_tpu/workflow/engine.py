@@ -509,14 +509,6 @@ class RunLedger:
                 entry["preempted"] = True
         return steps
 
-    def degraded_backend(self) -> dict | None:
-        """The most recent ``backend_degraded`` event, if any."""
-        last = None
-        for e in self.events():
-            if e.get("event") == "backend_degraded":
-                last = e
-        return last
-
     def preempted(self) -> dict | None:
         """The trailing ``run_preempted`` event when the most recent run
         ended in a graceful drain; a later ``run_started`` (the resume)
@@ -538,8 +530,9 @@ class Workflow:
     ``batch_failed`` ledger event) while the step continues, and the
     step only fails once quarantined batches exceed the configured
     budget.  ``resume`` re-attempts quarantined batches first.  A device
-    health guard probes the device path before every step and degrades
-    to the CPU backend when the relay is down."""
+    health guard probes the device path before every step; when the
+    device does not answer the run stops with a transient error (it is
+    never moved to another backend) and ``resume`` picks it up."""
 
     def __init__(self, store: ExperimentStore,
                  description: WorkflowDescription,
@@ -617,8 +610,6 @@ class Workflow:
         telemetry.get_registry().counter("tmx_runs_total").inc()
         sampler = self._start_sampler()
         guard = self.resilience.guard if self.resilience.enabled else None
-        if guard is not None:
-            guard.ensure_backend(self.ledger, where="run")
         # None when disabled: no monitor thread, no arming, no events
         self._watchdog = watchdog_from_config(
             on_fire=guard.note_watchdog_fire if guard is not None else None
@@ -626,6 +617,8 @@ class Workflow:
         done_steps = self.ledger.completed_steps() if resume else set()
         summary = {}
         try:
+            if guard is not None:
+                guard.ensure_backend(where="run")
             with telemetry.span("run", emit=self.ledger.append):
                 for stage in self.description.stages:
                     for sd in stage.steps:
@@ -646,7 +639,7 @@ class Workflow:
                                 step=sd.name, reason=self._stop_reason(),
                             ))
                         if guard is not None:
-                            guard.ensure_backend(self.ledger, where=sd.name)
+                            guard.ensure_backend(where=sd.name)
                         with telemetry.span(
                             "step",
                             emit=functools.partial(self.ledger.append,
@@ -804,8 +797,8 @@ class Workflow:
     def _start_sampler(self):
         """Start the resource sampler thread for this run when telemetry
         is on and a sample period is configured; the heartbeat file lands
-        next to the ledger so ``tmx workflow status`` and
-        ``scripts/tpu_watch.py`` can spot a hung run."""
+        next to the ledger so ``tmx workflow status`` and ``tmx top`` can
+        spot a hung run."""
         from tmlibrary_tpu.config import cfg
 
         period = float(getattr(cfg, "resource_sample_period", 0) or 0)

@@ -359,24 +359,31 @@ class ImageAnalysisRunner(Step):
             mode=mode, source=source,
         )
 
-    @staticmethod
-    def _auto_batch_size() -> int:
-        """``batch_size=0``: the hardware-swept ``best_batch`` on device
-        backends (the sweep measured the device, so a CPU run keeps the
-        static default)."""
+    def _auto_batch_size(self) -> int:
+        """``batch_size=0``.  On device backends the default is a pixel
+        budget, not a site count: the hardware-swept ``best_batch`` (else
+        the static 32) was measured on 256x256 sites, so it is scaled by
+        this experiment's site pixels — 128 sites of 256x256 become one
+        2160x2160 field per batch, which is what fits HBM next to the
+        in-flight window.  The sweep measured the device, so a CPU run
+        keeps the static default."""
         import jax
 
-        if jax.default_backend() != "cpu":
-            from tmlibrary_tpu.tuning import tuned_batch_size
+        if jax.default_backend() == "cpu":
+            return 32
+        from tmlibrary_tpu.tuning import TUNED_SITE_PIXELS, tuned_batch_size
 
-            tuned = tuned_batch_size()
-            if tuned:
-                logger.info(
-                    "batch_size auto: %d sites/batch (source: tuning "
-                    "best_batch)", tuned,
-                )
-                return tuned
-        return 32
+        tuned = tuned_batch_size()
+        exp = self.store.experiment
+        site_pixels = max(1, int(exp.site_height) * int(exp.site_width))
+        batch = max(1, (tuned or 32) * TUNED_SITE_PIXELS // site_pixels)
+        logger.info(
+            "batch_size auto: %d sites/batch (%s %d sites of 256x256, "
+            "scaled to %dx%d sites)", batch,
+            "tuning best_batch" if tuned else "default", tuned or 32,
+            exp.site_height, exp.site_width,
+        )
+        return batch
 
     # ---------------------------------------------------------------- compile
     def _description(self, args):
