@@ -14,8 +14,9 @@
 
 One process holds the chip at a time: this parent never imports JAX.
 The bench stages run ``bench.py`` (whose own child holds the chip) and
-the kernel stages run ``tune_tpu.py --stage <name>`` in a child, one
-after another; each child merges its numbers into ``TUNING.json``.
+the kernel stages run :func:`stage_child_main` of this module in a child
+interpreter, one after another; each child merges its numbers into
+``TUNING.json``.
 
 Usage: python scripts/tune_tpu.py
 """
@@ -287,7 +288,10 @@ def run_stage_child(name):
     its lifetime."""
     write_results()
     proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--stage", name],
+        [sys.executable, "-c",
+         "import sys; sys.path.insert(0, sys.argv[1]); import tune_tpu; "
+         "tune_tpu.stage_child_main(sys.argv[2])",
+         os.path.dirname(os.path.abspath(__file__)), name],
         text=True, timeout=2400,
     )
     with open(_results_path()) as f:
@@ -299,8 +303,8 @@ def run_stage_child(name):
 
 
 def stage_child_main(name):
-    """``--stage <name>``: the child body of one in-process stage.  It
-    takes the parent's flushed results, times the stage on the default
+    """The child body of one in-process stage (``kernels`` | ``glcm``).
+    It takes the parent's flushed results, times the stage on the default
     backend (or the CPU under ``BENCH_FORCE_CPU``) and writes them back."""
     import jax
 
@@ -482,7 +486,4 @@ def write_results():
 
 
 if __name__ == "__main__":
-    if len(sys.argv) == 3 and sys.argv[1] == "--stage":
-        stage_child_main(sys.argv[2])
-    else:
-        main()
+    main()

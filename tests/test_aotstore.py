@@ -22,7 +22,35 @@ WORKER = os.path.join(os.path.dirname(__file__), "warmstart_worker.py")
 
 
 @pytest.fixture
-def store(tmp_path, monkeypatch):
+def jax_cache():
+    """JAX's persistent compilation cache as these tests need it: off
+    (an earlier test of the same worker may have switched it on, and an
+    executable it serves is not one a compile produced), or on in a
+    directory of the test's own — ``jax_cache(path)``."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    before = {n: getattr(jax.config, n) for n in names}
+
+    def switch(directory=None):
+        jax.config.update("jax_enable_compilation_cache", bool(directory))
+        if directory:
+            jax.config.update("jax_compilation_cache_dir", str(directory))
+            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        compilation_cache.reset_cache()
+
+    switch()
+    yield switch
+    for name, value in before.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def store(tmp_path, monkeypatch, jax_cache):
     """Armed store in a fresh directory + fresh registry/profiles."""
     monkeypatch.setenv("TMX_AOT_STORE", "1")
     monkeypatch.setenv("TMX_AOT_STORE_DIR", str(tmp_path / "aot"))
@@ -125,6 +153,16 @@ def test_corrupt_artifact_falls_back_loudly_and_evicts(store, caplog):
     assert aotstore.list_entries(store) == []
 
 
+def test_an_edit_to_the_package_changes_the_fingerprint(monkeypatch):
+    """The program name digests the pipeline description, not ``ops/``:
+    without the package's own digest in the fingerprint, a store written
+    before an edit to an op hands the edited code its old executable."""
+    before = aotstore.backend_fingerprint()
+    assert aotstore.fingerprint_info()["package"] == aotstore.package_digest()
+    monkeypatch.setattr(aotstore, "_PACKAGE_DIGEST", "0" * 16)
+    assert aotstore.backend_fingerprint() != before
+
+
 def test_stale_fingerprint_never_loads():
     # the fingerprint is INSIDE the entry digest: a store written by a
     # different jax/backend resolves to different file names, so a
@@ -152,6 +190,101 @@ def test_prune_lru_cap_and_orphans(store):
     # LRU: the two most recent exports survive
     assert kept == set(digests[2:])
     assert not os.path.exists(os.path.join(store, "feedface" * 5 + ".bin"))
+
+
+@pytest.mark.parametrize("rungs_that_fit,imported", [
+    (4, 4),   # the cap holds the ladder: the second walk compiles nothing
+    (3, 0),   # one rung short: NOT 3 hits — each export evicts the least
+              # recently used rung, which is the one asked for next
+])
+def test_second_walk_of_a_ladder_hits_only_if_the_cap_holds_all_of_it(
+        store, monkeypatch, rungs_that_fit, imported):
+    """What happened on the chip in PR 21 at scaled-down sizes: 8 rungs of
+    166 MB against a 1 GiB cap, and a second process that imported
+    nothing.  ``DEFAULT_MAX_BYTES`` is sized from this."""
+    compiled, _ = _compiled_toy()
+    ladder = (8, 16, 32, 64)
+    probe = aotstore.export_entry(compiled, program="size", signature="s",
+                                  directory=store + "_probe")
+    per_entry = os.path.getsize(os.path.join(store + "_probe", f"{probe}.bin"))
+    monkeypatch.setenv("TMX_AOT_STORE_MAX_BYTES",
+                       str(rungs_that_fit * per_entry + 1))
+
+    def walk():
+        hits = 0
+        for cap in ladder:   # the engine's order: import, else compile + export
+            key = dict(program="ladder", capacity=cap, strategy="auto",
+                       signature="s")
+            if aotstore.import_entry(**key) is not None:
+                hits += 1
+            else:
+                aotstore.export_entry(compiled, **key)
+        return hits
+
+    assert walk() == 0
+    assert walk() == imported
+    total = sum(m["size_bytes"] for m in aotstore.list_entries(store))
+    assert total <= rungs_that_fit * per_entry + 1
+
+
+def test_default_cap_holds_a_real_fields_ladder():
+    # 8 rungs x 166 MB, the largest executable measured (PERF.md, PR 21)
+    assert aotstore.DEFAULT_MAX_BYTES >= 2 * 8 * 166_000_000
+
+
+def _toy_in_a_fresh_process(x):
+    """What a new process does with the toy program: nothing in memory,
+    the store and JAX's cache as the disk has them."""
+    perf.reset_profiles()
+    jax.clear_caches()
+
+    def stored_toy(v):
+        return v * 3.0 - 1.0
+
+    return np.asarray(perf.instrument_batch_fn(
+        jax.jit(stored_toy), program="stored_toy", capacity=8,
+        strategy="auto")(x))
+
+
+def _in_jax_cache(directory) -> int:
+    return sum("stored_toy" in name and name.endswith("-cache")
+               for name in os.listdir(directory))
+
+
+def test_an_executable_the_store_takes_is_not_written_to_jaxs_cache_too(
+        store, tmp_path, jax_cache, monkeypatch):
+    """A real field's executable is 91 MB: a second copy in a capped JAX
+    cache (192 MiB on the chip machine) evicted every small program a
+    second process would have hit (PERF.md, PR 21)."""
+    jax_cache(tmp_path / "jaxcache")
+    x = jnp.arange(8, dtype=jnp.float32)
+    want = _toy_in_a_fresh_process(x)
+    assert aotstore.counts_snapshot()["export"] == 1.0
+    assert _in_jax_cache(tmp_path / "jaxcache") == 0
+    # the next process gets it from the store
+    np.testing.assert_array_equal(_toy_in_a_fresh_process(x), want)
+    assert aotstore.counts_snapshot()["import_hit"] == 1.0
+    # with the store off the cache takes it, as for any program
+    monkeypatch.setenv("TMX_AOT_STORE", "0")
+    _toy_in_a_fresh_process(x)
+    assert _in_jax_cache(tmp_path / "jaxcache") == 1
+
+
+def test_an_executable_the_persistent_cache_served_stays_out_of_the_store(
+        store, tmp_path, jax_cache, monkeypatch):
+    """With jaxlib 0.9.0 an XLA:CPU executable that was itself loaded from
+    JAX's persistent cache serializes into a payload that loads and then
+    fails to run ("Function ... not found").  The store takes only what
+    a compile produced; the cache keeps serving the rest."""
+    jax_cache(tmp_path / "jaxcache")
+    x = jnp.arange(8, dtype=jnp.float32)
+    monkeypatch.setenv("TMX_AOT_STORE", "0")
+    want = _toy_in_a_fresh_process(x)      # store off: the cache takes it
+    assert _in_jax_cache(tmp_path / "jaxcache") == 1
+    monkeypatch.setenv("TMX_AOT_STORE", "1")
+    np.testing.assert_array_equal(_toy_in_a_fresh_process(x), want)
+    assert "export" not in aotstore.counts_snapshot()
+    assert not os.path.isdir(store) or os.listdir(store) == []
 
 
 # ----------------------------------------------- speculation unit tests

@@ -162,14 +162,50 @@ def test_whole_site_kernels_stay_out_of_auto_dispatch_at_a_full_field(
 def test_xla_twin_compiles_at_the_full_field(op, on_tpu):
     """The fixpoints the full field runs (the whole-site kernels are out
     of dispatch there).  Both used to take the TPU compiler longer than
-    25 minutes at 2160x2160 — column scans along the second-minor axis,
-    a flat 4.7-Mpixel cumsum, a bool carry between the scans."""
+    25 minutes at 2160x2160 — even/odd scan recursion along the minor
+    axis, a flat 4.7-Mpixel cumsum, a bool carry between the scans."""
     from tmlibrary_tpu.ops import label
 
     fn = {"cc": lambda m: label.connected_components(m, 8, method="xla"),
           "fill": lambda m: label.fill_holes(m, method="xla")}[op]
     _, seconds = _compile(fn, on_tpu((SMOKE_FIELD, SMOKE_FIELD), jnp.bool_))
     assert seconds < 300, f"{op} took {seconds:.0f}s to compile"
+
+
+# ------------------------------------- --layout spatial, one well's mosaic
+@pytest.mark.parametrize("n_devices", [4, 1])
+@pytest.mark.parametrize("program", ["smooth", "cc"])
+def test_spatial_layout_compiles_at_the_smokes_mosaic(program, n_devices,
+                                                      topo, on_tpu):
+    """The row-sharded programs of ``chip_smoke.py --chips 4``'s spatial
+    phase at its 4320x4320 mosaic (2x2 full fields), on four described
+    devices and on one.  The four-device CC program was still compiling
+    on four chips when the call was killed after 23 minutes: its 1080x4320
+    shard is not square, and the compile time of the former run scans
+    blew up with that (PERF.md, PR 21)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from tmlibrary_tpu.ops.smooth import gaussian_radius
+    from tmlibrary_tpu.parallel import halo
+    from tmlibrary_tpu.parallel import label as plabel
+
+    side = 2 * SMOKE_FIELD
+    mesh = Mesh(np.asarray(topo.devices[:n_devices]), ("rows",))
+    rows = NamedSharding(mesh, PartitionSpec("rows"))
+    if program == "smooth":
+        # the lru-cached builder's own function: a described mesh must
+        # not stay in the process's cache
+        fn = halo._cached_gaussian_halo.__wrapped__(
+            mesh, 1.5, gaussian_radius(1.5), "rows")
+        arg = jax.ShapeDtypeStruct((side, side), jnp.float32, sharding=rows)
+    else:
+        fn = plabel._cc_1d_program(
+            mesh, side // n_devices, side, 8, 4096, "rows")
+        arg = jax.ShapeDtypeStruct((side, side), jnp.bool_, sharding=rows)
+    _, seconds = _compile(fn, arg)
+    assert seconds < 300, (
+        f"{program} on {n_devices} devices took {seconds:.0f}s to compile")
 
 
 # ------------------------------------------------------- whole-site programs

@@ -65,10 +65,11 @@ def test_chips_4_runs_only_the_sharded_paths_on_four_virtual_devices():
     proc, records = _run_smoke("--chips", "4", n_devices=4)
     assert proc.returncode != 0, proc.stdout[-2000:]
     phases = {r["phase"]: r for r in records if "phase" in r}
-    assert list(phases) == ["start", "sharded", "spatial"]
+    assert list(phases) == ["start", "spatial", "sharded"]
     for name in ("sharded", "spatial"):
         assert phases[name]["passed"], (name, phases[name])
-    assert phases["sharded"]["checks"]["every_device_held_a_shard"]
+        assert all(phases[name]["checks"].values()), phases[name]["checks"]
+    assert phases["spatial"]["mosaic"] == [128, 128]  # 2x2 whole fields
     assert sorted(
         phases["spatial"]["spatial_shards"]["image_shards_per_device"]
     ) == ["0", "1", "2", "3"]
@@ -173,15 +174,19 @@ def test_the_retired_cache_knobs_are_gone(monkeypatch, tmp_path,
 
 
 # ------------------------------------------------- the engine's own resolver
-@pytest.mark.parametrize("side,tuned,expected", [
-    (256, 128, 128),    # the site the sweep measured: its own verdict
-    (2160, 128, 1),     # an acquisition-geometry field: one per batch
-    (1080, 128, 7),     # the 2x2-binned field
-    (2160, None, 1),    # no sweep: the static 32 is scaled the same way
-    (64, None, 512),
+@pytest.mark.parametrize("side,tuned,n_devices,expected", [
+    (256, 128, 1, 128),   # the site the sweep measured: its own verdict
+    (2160, 128, 1, 1),    # an acquisition-geometry field: one per batch
+    (1080, 128, 1, 7),    # the 2x2-binned field
+    (2160, None, 1, 1),   # no sweep: the static 32 is scaled the same way
+    (64, None, 1, 32),    # never above the batch that was swept
+    (2160, 128, 4, 4),    # a mesh: one field per device, no padded copies
+    (1080, 128, 4, 8),    # the budget of 7 rounded up to 2 per device
+    (256, 128, 4, 128),   # already a multiple of the mesh
+    (2160, 128, 0, 8),    # 0 = every device (conftest's 8 virtual ones)
 ])
-def test_auto_batch_size_is_a_pixel_budget_on_device(tmp_path, monkeypatch,
-                                                     side, tuned, expected):
+def test_auto_batch_size_is_a_pixel_budget_on_device(
+        tmp_path, monkeypatch, side, tuned, n_devices, expected):
     from tmlibrary_tpu import tuning
     from tmlibrary_tpu.models.experiment import grid_experiment
     from tmlibrary_tpu.models.store import ExperimentStore
@@ -191,6 +196,7 @@ def test_auto_batch_size_is_a_pixel_budget_on_device(tmp_path, monkeypatch,
                           channel_names=("DAPI",), site_shape=(side, side))
     step = get_step("jterator")(ExperimentStore.create(tmp_path / "e", exp))
     monkeypatch.setattr(tuning, "tuned_batch_size", lambda: tuned)
-    assert step._auto_batch_size() == 32  # the CPU keeps its static default
+    # the CPU keeps its static default
+    assert step._auto_batch_size(n_devices) == 32
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert step._auto_batch_size() == expected
+    assert step._auto_batch_size(n_devices) == expected

@@ -18,8 +18,9 @@ Keying contract (stale artifacts can never load):
   the reduction strategy, and the exact input signature (treedef +
   leaf shapes/dtypes) — plus the **backend fingerprint**;
 * the fingerprint is (jax version, jaxlib version, backend name,
-  device count): any toolchain or topology change produces a different
-  digest, so a stale artifact is simply never *found*.  The fingerprint
+  device count, digest of this package's sources): any toolchain,
+  topology or code change produces a different digest, so a stale
+  artifact is simply never *found*.  The fingerprint
   is additionally re-checked from the meta sidecar at import time
   (defense in depth) and a mismatch refuses LOUDLY.
 
@@ -45,6 +46,7 @@ gauge (the WARM row in ``tmx top`` / ``tmx serve status``).
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import logging
@@ -65,9 +67,17 @@ ENV_DIR = "TMX_AOT_STORE_DIR"
 
 _FALSE_VALUES = ("0", "false", "no", "off")
 
-#: default LRU cap on total payload bytes (1 GiB) — serialized jterator
-#: executables are single-digit MBs on CPU, tens on TPU
-DEFAULT_MAX_BYTES = 1 << 30
+#: default LRU cap on total payload bytes (4 GiB).  It has to hold at
+#: least one plate's whole bucket ladder: a process walks the rungs in
+#: the same order every time, and under least-recently-used eviction a
+#: store one rung too small gives the next walk NO hit at all (each
+#: export evicts the rung asked for next).  Serialized jterator
+#: executables are single-digit MBs on CPU; the config-3 program for one
+#: 2160x2160 field is 91 MB on a v5e at every rung (166 MB before the run
+#: scans were rewritten), so its 8-rung ladder is 0.73 GB — over the
+#: former 1 GiB cap at 166 MB, which is why the second chip run of PR 21
+#: imported nothing (PERF.md).
+DEFAULT_MAX_BYTES = 4 << 30
 
 _LOCK = threading.Lock()
 #: process-default directory (serve daemons point this at the shared
@@ -175,8 +185,31 @@ def max_store_bytes() -> int:
 
 # ------------------------------------------------------------- identity
 
+_PACKAGE_DIGEST: str | None = None
+
+
+def package_digest() -> str:
+    """Digest of this package's Python sources.  An executable is the
+    compiled form of THIS code: the program name digests the pipeline
+    description, not ``ops/``, so without this a store written before an
+    edit to an op would hand the edited code its old executable."""
+    global _PACKAGE_DIGEST
+    if _PACKAGE_DIGEST is None:
+        root = os.path.dirname(os.path.abspath(__file__))
+        h = hashlib.sha1()
+        for directory, subdirs, names in os.walk(root):
+            subdirs.sort()
+            for name in sorted(n for n in names if n.endswith(".py")):
+                path = os.path.join(directory, name)
+                h.update(os.path.relpath(path, root).encode())
+                with open(path, "rb") as f:
+                    h.update(f.read())
+        _PACKAGE_DIGEST = h.hexdigest()[:16]
+    return _PACKAGE_DIGEST
+
+
 def fingerprint_info() -> dict:
-    """The toolchain/topology facts the fingerprint digests.  Device
+    """The toolchain/topology/code facts the fingerprint digests.  Device
     count matters: an executable compiled for 8 virtual CPU devices is
     not the one a single-device process wants."""
     import jax
@@ -187,6 +220,7 @@ def fingerprint_info() -> dict:
         "jaxlib": getattr(jaxlib, "__version__", "unknown"),
         "backend": jax.default_backend(),
         "device_count": jax.device_count(),
+        "package": package_digest(),
     }
 
 
@@ -197,7 +231,7 @@ def backend_fingerprint(info: dict | None = None) -> str:
     info = info or fingerprint_info()
     blob = "|".join(
         f"{k}={info.get(k)}"
-        for k in ("jax", "jaxlib", "backend", "device_count")
+        for k in ("jax", "jaxlib", "backend", "device_count", "package")
     )
     return hashlib.sha1(blob.encode()).hexdigest()[:16]
 
@@ -294,6 +328,46 @@ def reset_counts() -> None:
 
 
 # ---------------------------------------------------------- export/import
+
+def compile_for_store():
+    """Context for the compile of an executable the store will take:
+    JAX's persistent cache may still serve it, but does not take a second
+    copy.  A real field's executable is 91 MB, and under a capped cache
+    (192 MiB on the chip machine) the copies evicted every small program
+    a second process would have hit (PERF.md, PR 21)."""
+    if not enabled():
+        return contextlib.nullcontext()
+    # the context-manager form of the option has no public name
+    from jax._src import config as jax_config
+
+    return jax_config.persistent_cache_min_compile_time_secs(float("inf"))
+
+
+_CACHE_HITS = threading.local()
+_LISTENING = False
+
+
+def cache_hits_seen() -> int:
+    """How many compiles JAX's persistent compilation cache has served on
+    this thread since the first call.  A caller reads it before and after
+    a ``compile()``: an executable the cache served stays out of the
+    store — the cache already holds it, and with jaxlib 0.9.0 an XLA:CPU
+    executable that was itself loaded from the cache serializes into a
+    payload that loads and then fails to run ("Function ... not
+    found")."""
+    global _LISTENING
+    with _LOCK:
+        if not _LISTENING:
+            import jax.monitoring
+
+            def on_event(event: str, **_) -> None:
+                if event == "/jax/compilation_cache/cache_hits":
+                    _CACHE_HITS.n = getattr(_CACHE_HITS, "n", 0) + 1
+
+            jax.monitoring.register_event_listener(on_event)
+            _LISTENING = True
+    return getattr(_CACHE_HITS, "n", 0)
+
 
 def export_entry(compiled: Any, *, program: str, step: str = "jterator",
                  capacity: int | None = None, strategy: str | None = None,

@@ -275,14 +275,16 @@ def build_parser() -> argparse.ArgumentParser:
         "list", help="store entries, most recently used first")
     p_clist.add_argument("--dir", default=None, dest="store_dir",
                          help="store directory (default TMX_AOT_STORE_DIR, "
-                              "config aot_store_dir, or ~/.cache)")
+                              "config aot_store_dir, or beside the compile "
+                            "cache)")
     p_clist.add_argument("--json", action="store_true", dest="as_json",
                          help="emit entries + stats as JSON (CI manifest)")
     p_cgc = cache_sub.add_parser(
         "gc", help="evict stale-fingerprint, over-age and over-cap entries")
     p_cgc.add_argument("--dir", default=None, dest="store_dir",
                        help="store directory (default TMX_AOT_STORE_DIR, "
-                            "config aot_store_dir, or ~/.cache)")
+                            "config aot_store_dir, or beside the compile "
+                            "cache)")
     p_cgc.add_argument("--max-bytes", type=int, default=None,
                        dest="max_bytes",
                        help="LRU size cap to enforce (default the "

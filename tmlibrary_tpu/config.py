@@ -157,7 +157,8 @@ class LibraryConfig:
     )
     #: store directory; "" = the resolution chain in aotstore.store_dir
     #: (TMX_AOT_STORE_DIR env > this > process default — serve daemons
-    #: point the default at the shared serve root > ~/.cache)
+    #: point the default at the shared serve root > beside the compile
+    #: cache, ``<checkout>/.cache/aot``)
     aot_store_dir: str = dataclasses.field(
         default_factory=lambda: _setting("aot_store_dir", "")
     )

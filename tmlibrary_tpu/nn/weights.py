@@ -26,7 +26,7 @@ Weight specs
     the full DL path without shipping a trained checkpoint.
 ``<name>``
     ``<name>.npz`` inside the weights directory (``TMX_WEIGHTS_DIR``
-    env, default ``~/.cache/tmlibrary_tpu/weights``).
+    env, default ``<checkout>/.cache/weights``).
 ``<path ending in .npz>``
     An explicit filesystem path.
 """
@@ -62,10 +62,10 @@ _RESOLVE_CACHE_MAX = 8
 def weights_dir() -> Path:
     """The named-checkpoint directory (created on access, like the
     experiment store's ``tools_dir``)."""
-    root = os.environ.get("TMX_WEIGHTS_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "tmlibrary_tpu", "weights"
-    )
-    path = Path(root)
+    from tmlibrary_tpu.utils import checkout_cache_dir
+
+    path = Path(os.environ.get("TMX_WEIGHTS_DIR")
+                or checkout_cache_dir("weights"))
     path.mkdir(parents=True, exist_ok=True)
     return path
 

@@ -7,7 +7,7 @@ Reference parity: ``jtmodules/label.py`` (mahotas/scipy connected components),
 TPU design (SURVEY.md §8 "hard parts" #1): labeling iterates {diagonal
 neighbor min-propagation, row run-scan, column run-scan} inside
 ``lax.while_loop`` — each pixel carries the minimum linear index seen in
-its component, and the segmented associative scans (``_run_min_scan``)
+its component, and the segmented run scans (``_run_min_scan``)
 move labels across entire straight runs per iteration with **no gathers**
 (TPU's slow path).  Convergence is ~O(turns of the most serpentine
 component): a handful of iterations for blob-like microscopy objects.
@@ -62,38 +62,48 @@ def _propagate_min(labels: jax.Array, mask: jax.Array, shifts) -> jax.Array:
     return jnp.where(mask, out, _BIG)
 
 
+def _shift_along(x: jax.Array, d: int, axis: int, fill) -> jax.Array:
+    """``out[i] = x[i - d]`` along ``axis`` (``d`` of either sign), ``fill``
+    where the shift exposes the edge."""
+    n = x.shape[axis]
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (max(d, 0), max(-d, 0))
+    return lax.slice_in_dim(
+        jnp.pad(x, pad, constant_values=fill), max(-d, 0), max(-d, 0) + n,
+        axis=axis,
+    )
+
+
 def _run_min_scan(labels: jax.Array, mask: jax.Array, axis: int) -> jax.Array:
-    """Propagate the min label across contiguous foreground runs along
-    ``axis`` via a segmented associative scan (both directions) — O(log N)
-    depth, no gathers (TPU gathers are the slow path)."""
-    if axis == 0:
-        # scan columns as the rows of the transpose: the TPU compiler
-        # needs ~35x longer for a scan along the second-minor axis (88 s
-        # against 2.5 s at 1024x1024 on a described v5e, and no end in
-        # sight at 2160x2160 — PERF.md, PR 21); min is min either way
-        return _run_min_scan(labels.T, mask.T, 1).T
-    # run start: previous element along the axis is background
-    is_start = mask & ~_shift_with_fill(
-        mask, *((-1, 0) if axis == 0 else (0, -1)), False
-    )
-    # background pixels are their own segment so nothing crosses them
-    resets = is_start | ~mask
+    """Give every foreground pixel the min label of its contiguous run
+    along ``axis``: a segmented min-scan in each direction by shift
+    doubling (log2 N whole-array steps of shift + min + select; no
+    gathers, no strided slices), then the min of the two.
 
-    def op(a, b):
-        av, ar = a
-        bv, br = b
-        return jnp.where(br, bv, jnp.minimum(av, bv)), ar | br
-
-    fwd, _ = lax.associative_scan(op, (labels, resets), axis=axis)
-    # reverse pass: a run's first element holds the run min after the
-    # forward pass only at its end; sweep back so every element gets it.
-    # run end: next element along the axis is background
-    is_end = mask & ~_shift_with_fill(
-        mask, *((1, 0) if axis == 0 else (0, 1)), False
-    )
-    resets_r = is_end | ~mask
-    bwd, _ = lax.associative_scan(op, (fwd, resets_r), axis=axis, reverse=True)
-    return jnp.where(mask, bwd, _BIG)
+    ``lax.associative_scan`` computes the same thing in less arithmetic,
+    but its even/odd recursion costs the TPU compiler minutes wherever
+    the scanned axis is the minor one, and a fixpoint that scans rows AND
+    columns always has one such: 15 s at 2160x2160, 112 s for a 540x2160
+    shard and no end after 900 s for a 1080x4320 one, against 7-8 s for
+    this form at every one of those shapes (described v5e; PERF.md,
+    PR 21)."""
+    n = labels.shape[axis]
+    bg = ~mask
+    v = jnp.where(mask, labels, _BIG)
+    # a pixel stops taking from behind it once its window holds a run
+    # boundary; background is its own segment, so nothing crosses it
+    fwd, fwd_stop = v, bg | _shift_along(bg, 1, axis, True)
+    bwd, bwd_stop = v, bg | _shift_along(bg, -1, axis, True)
+    d = 1
+    while d < n:
+        fwd = jnp.where(
+            fwd_stop, fwd, jnp.minimum(fwd, _shift_along(fwd, d, axis, _BIG)))
+        fwd_stop = fwd_stop | _shift_along(fwd_stop, d, axis, True)
+        bwd = jnp.where(
+            bwd_stop, bwd, jnp.minimum(bwd, _shift_along(bwd, -d, axis, _BIG)))
+        bwd_stop = bwd_stop | _shift_along(bwd_stop, -d, axis, True)
+        d *= 2
+    return jnp.where(mask, jnp.minimum(fwd, bwd), _BIG)
 
 
 def _row_major_ranks(flags: jax.Array) -> tuple[jax.Array, jax.Array]:

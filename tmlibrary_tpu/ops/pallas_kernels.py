@@ -8,7 +8,7 @@ watershed propagation (``jtmodules/segment_secondary.py``).
 Why Pallas (SURVEY.md §8 hard part #1): the XLA implementations in
 :mod:`tmlibrary_tpu.ops.label` / :mod:`~tmlibrary_tpu.ops.segment_secondary`
 run a ``lax.while_loop`` whose carried label image round-trips HBM every
-iteration (plus associative-scan passes).  A site image is tiny relative to
+iteration (plus the run-scan passes).  A site image is tiny relative to
 VMEM (256×256 int32 = 256 KB vs ~16 MB), so these kernels load the image
 ONCE, iterate the neighbor-propagation fixpoint entirely in VMEM on the
 VPU, and write the converged result — O(1) HBM traffic instead of
@@ -102,9 +102,10 @@ def _compiler_params(shape) -> "pltpu.CompilerParams":
 CHUNK_3D = 2
 
 
-def _tuned_chunk() -> int:
+def _tuned_chunk(default: int = 0) -> int:
     """Resolution: explicit arg (callers/tuner) → TMX_PALLAS_CHUNK env →
-    committed ``pallas_chunk`` sweep result → the default."""
+    committed ``pallas_chunk`` sweep result → ``default`` (the 2-D
+    :data:`CHUNK` unless the kernel names its own)."""
     import os
 
     env = os.environ.get("TMX_PALLAS_CHUNK")
@@ -116,7 +117,7 @@ def _tuned_chunk() -> int:
     tuned = _tuning_results().get("pallas_chunk")
     if isinstance(tuned, (int, float)) and tuned >= 1:
         return int(tuned)
-    return CHUNK
+    return default or CHUNK
 
 
 def _shift_fill(a: jax.Array, dy: int, dx: int, fill, h: int, w: int) -> jax.Array:
@@ -188,13 +189,13 @@ def _cc_kernel(mask_ref, out_ref, *, connectivity: int, chunk: int):
     out_ref[:] = labels
 
 
-def _resolve_chunk(chunk: "int | None", default: "int | None" = None) -> int:
-    """Explicit value (validated ≥ 1), else ``default``, else the tuned
-    default — resolved OUTSIDE jit so a changed TMX_PALLAS_CHUNK /
-    re-written TUNING.json is picked up per call instead of being baked
-    into the first trace."""
+def _resolve_chunk(chunk: "int | None", default: int = 0) -> int:
+    """Explicit value (validated ≥ 1), else the tuned one with the
+    kernel's ``default`` as the last resort — resolved OUTSIDE jit so a
+    changed TMX_PALLAS_CHUNK / re-written TUNING.json is picked up per
+    call instead of being baked into the first trace."""
     if chunk is None:
-        return default if default is not None else _tuned_chunk()
+        return _tuned_chunk(default)
     if not isinstance(chunk, int) or chunk < 1:
         raise ValueError(f"chunk must be an int >= 1, got {chunk!r}")
     return chunk

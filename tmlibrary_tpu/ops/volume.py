@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tmlibrary_tpu.ops.label import _run_min_scan
+
 _BIG = jnp.iinfo(jnp.int32).max
 
 
@@ -42,26 +44,6 @@ def _diag_shifts_3d(connectivity: int) -> list[tuple[int, int, int]]:
                     continue  # corner neighbors excluded at conn 18
                 out.append((dz, dy, dx))
     return out
-
-
-def _run_min_scan_3d(labels: jax.Array, mask: jax.Array, axis: int) -> jax.Array:
-    shift_prev = [0, 0, 0]
-    shift_prev[axis] = -1
-    shift_next = [0, 0, 0]
-    shift_next[axis] = 1
-    is_start = mask & ~shift3d(mask, *shift_prev, False)
-    resets = is_start | ~mask
-
-    def op(a, b):
-        av, ar = a
-        bv, br = b
-        return jnp.where(br, bv, jnp.minimum(av, bv)), ar | br
-
-    fwd, _ = lax.associative_scan(op, (labels, resets), axis=axis)
-    is_end = mask & ~shift3d(mask, *shift_next, False)
-    resets_r = is_end | ~mask
-    bwd, _ = lax.associative_scan(op, (fwd, resets_r), axis=axis, reverse=True)
-    return jnp.where(mask, bwd, _BIG)
 
 
 def _native_3d() -> bool:
@@ -144,9 +126,9 @@ def connected_components_3d(
                 for s in shifts:
                     new = jnp.minimum(new, shift3d(labels, *s, _BIG))
                 new = jnp.where(mask, new, _BIG)
-            new = _run_min_scan_3d(new, mask, axis=2)
-            new = _run_min_scan_3d(new, mask, axis=1)
-            new = _run_min_scan_3d(new, mask, axis=0)
+            new = _run_min_scan(new, mask, axis=2)
+            new = _run_min_scan(new, mask, axis=1)
+            new = _run_min_scan(new, mask, axis=0)
             return new, jnp.any(new != labels)
 
         labels, _ = lax.while_loop(cond, body, (init, jnp.bool_(True)))

@@ -97,7 +97,7 @@ def distributed_connected_components(
         raise ShardingError(f"mask rows {h} not divisible by mesh size {n}")
     if connectivity not in (4, 8):
         raise ValueError("connectivity must be 4 or 8")
-    # a 1-device CPU mesh has no seams to join: the associative-scan
+    # a 1-device CPU mesh has no seams to join: the while-loop
     # fixpoint is pathological on XLA-CPU (the same pathology the sites
     # layout's native fallback exists for), and the native union-find is
     # bit-identical (scipy scan order — exactly what the distributed
@@ -128,7 +128,7 @@ def _native_cc_available() -> bool:
 
 
 def _native_cc_shortcut(mask, mesh, connectivity, spec):
-    """1-device mesh: no seams to join, and the XLA associative-scan
+    """1-device mesh: no seams to join, and the XLA while-loop
     fixpoint is pathological on CPU — the native union-find is
     bit-identical (scipy scan order, exactly what the distributed paths
     are tested against)."""

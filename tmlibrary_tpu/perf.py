@@ -449,11 +449,13 @@ def _instrumented_call(fn, key, args, kwargs, sub_costs=None):
 
             compile_s = None
             compiled = None
+            cache_hits = aotstore.cache_hits_seen()
             if hasattr(fn, "lower"):
                 # a program the backend's compiler refuses raises here,
                 # exactly as the plain jit call would
                 t0 = time.perf_counter()
-                compiled = fn.lower(*args, **kwargs).compile()
+                with aotstore.compile_for_store():
+                    compiled = fn.lower(*args, **kwargs).compile()
                 compile_s = time.perf_counter() - t0
             cost = cost_from_compiled(compiled) if compiled is not None \
                 else ProgramCost()
@@ -470,11 +472,12 @@ def _instrumented_call(fn, key, args, kwargs, sub_costs=None):
                            recompile=recompile)
             if compiled is not None:
                 aotstore.note_cold(program)
-                aotstore.export_entry(
-                    compiled, program=program, step=step,
-                    capacity=capacity, strategy=strategy,
-                    signature=sig, compile_s=compile_s,
-                )
+                if aotstore.cache_hits_seen() == cache_hits:
+                    aotstore.export_entry(
+                        compiled, program=program, step=step,
+                        capacity=capacity, strategy=strategy,
+                        signature=sig, compile_s=compile_s,
+                    )
             if sub_costs is not None:
                 for sub_name, sub_cost in sub_costs(args, kwargs):
                     record_compile(
@@ -614,24 +617,27 @@ def speculate_compile(wrapped_fn, args, kwargs) -> str | None:
                           strategy=key[3], saved_s=meta.get("compile_s"))
             return "imported"
         return "known"
+    from tmlibrary_tpu import aotstore
+
     compile_s = None
     t0 = time.perf_counter()
+    cache_hits = aotstore.cache_hits_seen()
     try:
-        compiled = fn.lower(*args, **kwargs).compile()
+        with aotstore.compile_for_store():
+            compiled = fn.lower(*args, **kwargs).compile()
         compile_s = time.perf_counter() - t0
     except Exception:
         return None
     if not adopt_executable(key, sig, compiled):
         return "known"
-    try:
-        from tmlibrary_tpu import aotstore
-
-        aotstore.export_entry(
-            compiled, program=key[0], step=key[1], capacity=key[2],
-            strategy=key[3], signature=sig, compile_s=compile_s,
-        )
-    except Exception:
-        pass
+    if aotstore.cache_hits_seen() == cache_hits:
+        try:
+            aotstore.export_entry(
+                compiled, program=key[0], step=key[1], capacity=key[2],
+                strategy=key[3], signature=sig, compile_s=compile_s,
+            )
+        except Exception:
+            pass
     return "compiled"
 
 

@@ -248,7 +248,8 @@ class ImageAnalysisRunner(Step):
         if not args["pipe"]:
             raise ValueError("--pipe is required for --layout sites")
         sites = list(range(self.store.n_sites))
-        batch_size = args["batch_size"] or self._auto_batch_size()
+        batch_size = args["batch_size"] or self._auto_batch_size(
+            args["n_devices"])
         plan = self._schedule_plan(args, sites, batch_size)
         if plan is not None:
             from tmlibrary_tpu.workflow import schedule as schedule_mod
@@ -359,14 +360,19 @@ class ImageAnalysisRunner(Step):
             mode=mode, source=source,
         )
 
-    def _auto_batch_size(self) -> int:
+    def _auto_batch_size(self, n_devices: int = 0) -> int:
         """``batch_size=0``.  On device backends the default is a pixel
         budget, not a site count: the hardware-swept ``best_batch`` (else
         the static 32) was measured on 256x256 sites, so it is scaled by
         this experiment's site pixels — 128 sites of 256x256 become one
         2160x2160 field per batch, which is what fits HBM next to the
-        in-flight window.  The sweep measured the device, so a CPU run
-        keeps the static default."""
+        in-flight window.  The budget is then rounded up to a whole
+        number of sites per device of the mesh (``n_devices``, 0 = all):
+        a batch smaller than the mesh is padded with copies of its first
+        site, and those devices recompute it for nothing.  It never
+        exceeds the batch the sweep ran — smaller sites than the swept
+        one were not measured.  The sweep measured the device, so a CPU
+        run keeps the static default."""
         import jax
 
         if jax.default_backend() == "cpu":
@@ -374,14 +380,18 @@ class ImageAnalysisRunner(Step):
         from tmlibrary_tpu.tuning import TUNED_SITE_PIXELS, tuned_batch_size
 
         tuned = tuned_batch_size()
+        swept = tuned or 32
         exp = self.store.experiment
         site_pixels = max(1, int(exp.site_height) * int(exp.site_width))
-        batch = max(1, (tuned or 32) * TUNED_SITE_PIXELS // site_pixels)
+        n_dev = min(int(n_devices) or len(jax.devices()), len(jax.devices()))
+        budget = max(1, swept * TUNED_SITE_PIXELS // site_pixels)
+        per_device = min(-(-budget // n_dev), max(1, swept // n_dev))
+        batch = per_device * n_dev
         logger.info(
             "batch_size auto: %d sites/batch (%s %d sites of 256x256, "
-            "scaled to %dx%d sites)", batch,
-            "tuning best_batch" if tuned else "default", tuned or 32,
-            exp.site_height, exp.site_width,
+            "scaled to %dx%d sites, %d per device on %d)", batch,
+            "tuning best_batch" if tuned else "default", swept,
+            exp.site_height, exp.site_width, per_device, n_dev,
         )
         return batch
 
