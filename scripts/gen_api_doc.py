@@ -166,6 +166,24 @@ def telemetry_section() -> list[str]:
            "live dashboard `tmx top --root DIR [--once] "
            "[--interval SECS]`.",
            "",
+           "A `span` ledger event: `span` (its name), `parent` (the span "
+           "that enclosed it on its thread; absent at the top of a "
+           "thread), `t0` (wall clock), `elapsed`, `step`, `batch`, and "
+           "numeric attributes (`bytes`, `pixels`, `files`, `tiles`, "
+           "`capacity`). Names: `run`, `step`, `batch`; the executor's "
+           "phases `prefetch_wait`, `dispatch`, `device_block`, "
+           "`persist`; imextract `decode`, `write`; corilla `read_wait`, "
+           "`scan`, `finalize`, `write`; illuminati `stats_read`, `read`, "
+           "`prep`, `mosaic`, `percentile`, `pyramid`, `level_fetch`, "
+           "`encode`; jterator `load`, `upload`, `escalate` (children "
+           "`load`, `upload`, `device_wait`), `fetch`, `solidity`, "
+           "`write_labels`, `write_features`, `write_polygons`; JAX's "
+           "compile path `jit_trace`, `jit_lower`, `jit_compile`, "
+           "`cache_load`; `store_import`. `tmx ... --profile DIR` traces "
+           "the run for XProf: every span is a `TraceAnnotation` there, "
+           "and device operations carry `jax.named_scope` names (pipeline "
+           "module, then op stage).",
+           "",
            "| symbol | role |", "|---|---|"]
     for name in sorted(getattr(telemetry, "__all__", None) or
                        (n for n in dir(telemetry) if not n.startswith("_"))):

@@ -130,24 +130,28 @@ def test_oom_clamps_depth_and_retries():
         fail_exc=RuntimeError("RESOURCE_EXHAUSTED: out of memory (HBM)"),
         fail_times=1,
     )
+    from tmlibrary_tpu import telemetry
+
     events = []
     stats = PipelineStats(8, "cli")
     ex = PipelinedExecutor(
         step, depth=8, depth_source="cli",
         on_event=lambda **ev: events.append(ev), stats=stats,
     )
+    telemetry.drain_spans()
     out = list(ex.run(_batches(6)))
     assert [b["index"] for b, _ in out] == list(range(6))
     assert step.persisted == list(range(6))
-    # phase spans ride the same callback (telemetry); the control-flow
-    # events must still be exactly one depth clamp
-    assert [e for e in events if e["event"] != "span"] == [{
+    # the control-flow events are exactly one depth clamp; the phase
+    # spans do not ride that callback, they wait in the process buffer
+    assert events == [{
         "event": "depth_clamped", "from_depth": 8, "to_depth": 4,
         "batch": 3, "error": "RESOURCE_EXHAUSTED: out of memory (HBM)",
     }]
-    spans = [e for e in events if e["event"] == "span"]
+    spans = telemetry.drain_spans()
     assert {e["span"] for e in spans} >= {"dispatch", "persist"}
     assert {e["batch"] for e in spans} == set(range(6))
+    assert {e["step"] for e in spans} == {step.name}
     summary = stats.summary()
     assert summary["depth"] == 4
     assert summary["depth_clamps"] == [{"from": 8, "to": 4}]

@@ -126,16 +126,16 @@ def test_snapshot_shape_and_ordering():
 
 
 # ------------------------------------------------------------------- spans
-def test_span_emits_ledger_event_with_nesting_path():
+def test_span_emits_ledger_event_with_its_parent():
     events = []
     with telemetry.span("run", emit=lambda **kw: events.append(kw)):
         with telemetry.span("step", emit=lambda **kw: events.append(kw),
                             step="jterator"):
             pass
     assert [e["span"] for e in events] == ["step", "run"]  # inner exits first
-    assert events[0]["path"] == "run/step"
+    assert events[0]["parent"] == "run"
     assert events[0]["step"] == "jterator"
-    assert events[1]["path"] == "run"
+    assert "parent" not in events[1]
     for e in events:
         assert e["event"] == "span"
         assert e["elapsed"] >= 0.0
@@ -366,10 +366,28 @@ def test_device_trace_none_is_noop(monkeypatch):
     monkeypatch.setattr("jax.profiler.trace", explode)
     with profiling.device_trace(None):
         pass
-    assert not telemetry._trace_bridge.is_set()
 
 
-def test_device_trace_creates_dir_and_toggles_bridge(tmp_path, monkeypatch):
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records the names
+    entered, as a trace would hold them."""
+
+    entered: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.entered.append(self.name)
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_device_trace_creates_dir_and_spans_annotate_inside_it(
+        tmp_path, monkeypatch):
+    """No bridge to toggle: a span is a trace annotation in any trace,
+    the one ``--profile`` starts included."""
     from tmlibrary_tpu import profiling
 
     calls = []
@@ -379,39 +397,39 @@ def test_device_trace_creates_dir_and_toggles_bridge(tmp_path, monkeypatch):
             calls.append(("init", path))
 
         def __enter__(self):
-            calls.append(("enter", telemetry._trace_bridge.is_set()))
+            calls.append(("enter",))
 
         def __exit__(self, *exc):
             calls.append(("exit",))
             return False
 
     monkeypatch.setattr("jax.profiler.trace", FakeTrace)
+    monkeypatch.setattr("jax.profiler.TraceAnnotation", _FakeAnnotation)
+    _FakeAnnotation.entered = []
     log_dir = tmp_path / "trace" / "run1"
     with profiling.device_trace(log_dir):
         assert log_dir.is_dir()
-    # bridge was ACTIVE while the trace was open, cleared after
-    assert calls == [("init", str(log_dir)), ("enter", True), ("exit",)]
-    assert not telemetry._trace_bridge.is_set()
-
-
-def test_device_trace_clears_bridge_on_error(tmp_path, monkeypatch):
-    from tmlibrary_tpu import profiling
-
-    class FakeTrace:
-        def __init__(self, path):
+        with telemetry.span("outer"), telemetry.span_scope(step="corilla"), \
+                telemetry.span("step"), telemetry.span("scan"):
             pass
+    assert calls == [("init", str(log_dir)), ("enter",), ("exit",)]
+    # named as the benchmark names a ledger span: <step>/<span>
+    assert _FakeAnnotation.entered == ["outer", "corilla", "corilla/scan"]
+    telemetry.drain_spans()
 
-        def __enter__(self):
-            return self
 
-        def __exit__(self, *exc):
-            return False
-
-    monkeypatch.setattr("jax.profiler.trace", FakeTrace)
+def test_spans_annotate_a_trace_somebody_else_started(monkeypatch):
+    """Outside ``device_trace`` too (the benchmark starts its own trace),
+    and an error in the body still closes the annotation and the span."""
+    monkeypatch.setattr("jax.profiler.TraceAnnotation", _FakeAnnotation)
+    _FakeAnnotation.entered = []
+    telemetry.drain_spans()
     with pytest.raises(RuntimeError):
-        with profiling.device_trace(tmp_path / "t"):
+        with telemetry.span("work"):
             raise RuntimeError("body failed")
-    assert not telemetry._trace_bridge.is_set()
+    assert _FakeAnnotation.entered == ["work"]
+    assert [r["span"] for r in telemetry.drain_spans()] == ["work"]
+    assert telemetry._span_stack() == []
 
 
 # ----------------------------------------------------------- warn_once

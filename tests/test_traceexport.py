@@ -287,3 +287,40 @@ def test_export_raises_on_invalid_document(tmp_path, monkeypatch):
     with pytest.raises(ValueError, match="schema validation"):
         traceexport.export_chrome_trace(tmp_path, tmp_path / "out.json")
     assert not (tmp_path / "out.json").exists()
+
+
+def test_worker_thread_spans_get_their_own_lane_and_nest_by_time():
+    """Spans that closed off the engine thread carry ``thread``: each
+    such thread is a row of its own, where a child (``escalate`` inside
+    ``persist``) nests by time; the engine thread's spans stay on the
+    run's row, so a persist that overlaps the next dispatch is drawn
+    beside it and not inside it."""
+    def sp(span, t0, elapsed, ts, **kw):
+        return {"host": "h0", "event": "span", "span": span, "t0": t0,
+                "elapsed": elapsed, "ts": ts, "step": "jterator",
+                "batch": 0, **kw}
+
+    events = [
+        sp("dispatch", 10.0, 0.5, 10.5, parent="step",
+           thread="MainThread"),
+        sp("persist", 10.2, 3.0, 13.2, thread="tmx-persist_0"),
+        sp("escalate", 10.4, 2.0, 12.4, parent="persist", capacity=16,
+           thread="tmx-persist_0"),
+        sp("load", 10.1, 0.3, 10.4, thread="tmx-prefetch_1"),
+        sp("step", 9.0, 5.0, 14.0, parent="run"),
+    ]
+    doc = traceexport.chrome_trace(events)
+    assert traceexport.validate_chrome_trace(doc) == []
+    slices = {e["name"]: e for e in _slices(doc)}
+    lanes = {m["tid"]: m["args"]["name"] for m in doc["traceEvents"]
+             if m.get("name") == "thread_name"}
+    assert lanes[slices["persist"]["tid"]] == "tmx-persist_0"
+    assert slices["escalate"]["tid"] == slices["persist"]["tid"]
+    assert lanes[slices["load"]["tid"]] == "tmx-prefetch_1"
+    assert slices["dispatch"]["tid"] == slices["step"]["tid"]
+    assert lanes[slices["step"]["tid"]] == "run"
+    # the child lies inside its parent on their shared row
+    p, c = slices["persist"], slices["escalate"]
+    assert p["ts"] <= c["ts"] and c["ts"] + c["dur"] <= p["ts"] + p["dur"]
+    assert c["args"]["parent"] == "persist"
+    assert c["args"]["capacity"] == 16

@@ -486,19 +486,23 @@ def import_entry(*, program: str, capacity: int | None = None,
                        "cold", program, exc)
         return None
     try:
-        with open(bin_path, "rb") as f:
-            doc = pickle.loads(f.read())
-        from jax.experimental.serialize_executable import (
-            deserialize_and_load,
-        )
+        from tmlibrary_tpu import telemetry
 
-        import jax
+        with telemetry.span("store_import", program=program,
+                            bytes=os.path.getsize(bin_path)):
+            with open(bin_path, "rb") as f:
+                doc = pickle.loads(f.read())
+            from jax.experimental.serialize_executable import (
+                deserialize_and_load,
+            )
 
-        by_id = {d.id: d for d in jax.devices()}
-        compiled = deserialize_and_load(
-            doc["payload"], doc["in_tree"], doc["out_tree"],
-            execution_devices=[by_id[i] for i in doc["device_ids"]],
-        )
+            import jax
+
+            by_id = {d.id: d for d in jax.devices()}
+            compiled = deserialize_and_load(
+                doc["payload"], doc["in_tree"], doc["out_tree"],
+                execution_devices=[by_id[i] for i in doc["device_ids"]],
+            )
     except Exception as exc:
         logger.warning(
             "aotstore: corrupt artifact %s for %s (%s) — deleting entry "

@@ -1891,9 +1891,10 @@ def cmd_timeline(args) -> int:
 
 
 def cmd_trace(args) -> int:
-    """Dump the span tree (run > step > batch > phase) with the critical
-    path marked ``*`` at every level — the chain the run's wall time
-    actually went to.  Accepts serve roots too (the spooled job specs
+    """Dump the span tree (run > step > batch > phase > the spans inside
+    it, nested by ``parent``) with the critical path marked ``*`` at every
+    level — the chain the run's wall time actually went to — and a table
+    of every span by step, parent and name.  Accepts serve roots too (the spooled job specs
     point at their experiment ledgers), and ``--export chrome`` writes
     the whole thing as Trace Event Format JSON."""
     from tmlibrary_tpu import serve as serve_mod
@@ -1937,6 +1938,13 @@ def cmd_trace(args) -> int:
                            for k, v in sorted(totals.items(),
                                               key=lambda kv: -kv[1]))
         print(f"\nphase totals (critical resource): {phases}")
+    table = traceexport.span_table(events)
+    if table:
+        print("\nspans (step, parent, span, count, seconds):")
+        for row in table:
+            print(f"  {row['step']:<12} {row['parent']:<12} "
+                  f"{row['span']:<16} {row['count']:6d} "
+                  f"{row['total_s']:10.4f}")
     return 0
 
 

@@ -372,7 +372,10 @@ class ImageAnalysisPipeline:
                 ):
                     kwargs["max_objects"] = max_objects
                 try:
-                    outs = fn(**kwargs)
+                    # the module's name on every instruction it emits, so
+                    # a device trace can say whose the time was
+                    with jax.named_scope(mod.module):
+                        outs = fn(**kwargs)
                 except TypeError as e:
                     raise PipelineError(
                         f"module '{mod.module}' called with invalid arguments: {e}"
@@ -534,7 +537,8 @@ class ImageAnalysisPipeline:
 
         def one_site(raw, stats, shift):
             with reduction.strategy_scope(requested):
-                images = preprocess(raw, stats, shift)
+                with jax.named_scope("preprocess"):
+                    images = preprocess(raw, stats, shift)
                 # pass loaded objects (if any) through; label images loaded
                 # from the store live in the uncropped site frame, so they
                 # get the same intersection crop as the pixel channels

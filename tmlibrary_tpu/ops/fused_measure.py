@@ -223,6 +223,7 @@ def _stats_call(flat, stacked, max_objects, interpret, chunk):
             jax.ShapeDtypeStruct((n_ch, segs_p, 1), jnp.float32),
             jax.ShapeDtypeStruct((n_ch, segs_p, 1), jnp.float32),
         ],
+        name="grouped_stats",
         interpret=interpret,
     )(lab, vals)
     # drop the background row and the lane padding; rows = objects
@@ -289,6 +290,7 @@ def _hist_call(flat, q_flat, max_objects, bins, interpret, chunk):
         in_specs=[_pixel_spec(1, chunk), _pixel_spec(1, chunk)],
         out_specs=pl.BlockSpec((_SEG_TILE, bins_p), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((segs_p, bins_p), jnp.float32),
+        name="intensity_hist",
         interpret=interpret,
     )(lab, q)
     return counts[1:segs, :bins]
@@ -391,6 +393,7 @@ def _glcm_call(labels, q, max_objects, levels, offsets, interpret, chunk):
         ],
         out_specs=pl.BlockSpec((rows_t, cols_p), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_p, cols_p), jnp.float32),
+        name="glcm_all",
         interpret=interpret,
     )(lab, q1, lab2, q2)
     out = []

@@ -14,6 +14,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from tmlibrary_tpu.ops import named
+
 UINT16_MAX = 65535.0
 
 
@@ -142,6 +144,7 @@ def make_batch_prep(stats=None, apply_shift: bool = False,
     ops per channel inside its fused program)."""
     import jax
 
+    @named("prep")
     def prep(stack, shifts):
         def one(img, shift):
             out = jnp.asarray(img, jnp.float32)

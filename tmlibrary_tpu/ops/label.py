@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tmlibrary_tpu.ops import named
+
 _BIG = jnp.iinfo(jnp.int32).max
 
 
@@ -118,6 +120,7 @@ def _row_major_ranks(flags: jax.Array) -> tuple[jax.Array, jax.Array]:
     return (in_row + before[:, None]).reshape(-1), jnp.sum(row_totals)
 
 
+@named("label")
 def connected_components(
     mask: jax.Array, connectivity: int = 8, method: str = "auto",
     chunk: "int | None" = None,
@@ -245,6 +248,7 @@ def binary_erode(mask: jax.Array, connectivity: int = 8, iterations: int = 1) ->
     return mask
 
 
+@named("fill_holes")
 def fill_holes(
     mask: jax.Array, connectivity: int = 4, method: str = "auto"
 ) -> jax.Array:
@@ -430,6 +434,7 @@ def relabel_sequential(labels: jax.Array, keep: jax.Array) -> jax.Array:
     return remap_labels(labels, mapping)
 
 
+@named("filter_area")
 def filter_by_area(
     labels: jax.Array,
     max_objects: int,

@@ -32,6 +32,7 @@ from tmlibrary_tpu.ops.reduction import (
     segmented_min,
     segmented_sum,
 )
+from tmlibrary_tpu.ops import named
 
 
 def _seg_sum(values: jax.Array, labels: jax.Array, max_objects: int) -> jax.Array:
@@ -394,6 +395,7 @@ def _native_site_stats(
     )
 
 
+@named("measure_intensity")
 def intensity_features(
     labels: jax.Array, intensity: jax.Array, max_objects: int,
     method: str = "auto",
@@ -457,6 +459,7 @@ def intensity_features(
     }
 
 
+@named("measure_intensity")
 def intensity_quantiles(
     labels: jax.Array,
     intensity: jax.Array,
@@ -566,6 +569,7 @@ def _quantiles_from_counts(counts, lo, span, present, qs, bins):
 
 
 # ----------------------------------------------------------------- morphology
+@named("morphology")
 def morphology_features(labels: jax.Array, max_objects: int) -> dict[str, jax.Array]:
     """Reference feature set of ``jtlib/features/morphology.py``
     (CellProfiler-style): area, centroids, bounding box/extent, perimeter
@@ -836,6 +840,7 @@ def quantize_per_object(
     return jnp.clip(q, 0, levels - 1).astype(jnp.int32)
 
 
+@named("glcm")
 def haralick_features(
     labels: jax.Array,
     intensity: jax.Array,
@@ -1183,6 +1188,7 @@ def zernike_host_features(
     return out
 
 
+@named("zernike")
 def zernike_features(
     labels: jax.Array,
     max_objects: int,

@@ -216,6 +216,7 @@ def _cc_min_propagate_jit(
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         compiler_params=_compiler_params((h, w)),
+        name="cc_min_propagate",
         interpret=interpret,
     )(jnp.asarray(mask, jnp.int32))
 
@@ -304,6 +305,7 @@ def _watershed_flood_jit(
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         compiler_params=_compiler_params((h, w)),
+        name="watershed_flood",
         interpret=interpret,
     )(
         jnp.asarray(intensity, jnp.float32),
@@ -379,6 +381,7 @@ def _fill_holes_jit(
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         compiler_params=_compiler_params((h, w)),
+        name="fill_holes_flood",
         interpret=interpret,
     )(jnp.asarray(mask, jnp.int32))
 
@@ -481,6 +484,7 @@ def _cc3d_min_propagate_jit(
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         compiler_params=_compiler_params((z, h, w)),
+        name="cc3d_min_propagate",
         interpret=interpret,
     )(jnp.asarray(mask, jnp.int32))
 
@@ -568,6 +572,7 @@ def _watershed3d_flood_jit(
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         compiler_params=_compiler_params((z, h, w)),
+        name="watershed3d_flood",
         interpret=interpret,
     )(
         jnp.asarray(intensity, jnp.float32),
@@ -639,6 +644,7 @@ def distance_transform(
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         compiler_params=_compiler_params((h, w)),
+        name="distance_transform",
         interpret=interpret,
     )(jnp.asarray(mask, jnp.int32))
 

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from tmlibrary_tpu.ops import named
+
 TILE_SIZE = 256
 
 
@@ -39,6 +41,7 @@ def _display_dtype() -> jnp.dtype:
     return jnp.dtype(cfg.compute_dtype)
 
 
+@named("pyramid")
 def downsample_2x(img: jax.Array) -> jax.Array:
     """2x2 mean pooling (one pyramid level step).  Odd trailing row/col are
     edge-padded first so shape halving rounds up, matching zoomify."""
@@ -102,6 +105,7 @@ def cut_tiles(level: np.ndarray) -> dict[tuple[int, int], np.ndarray]:
     return tiles
 
 
+@named("pyramid")
 def to_uint8(level: jax.Array, lower: float, upper: float) -> jax.Array:
     """Percentile-stretch to display range (reference ``ChannelImage.scale``
     with corilla's clip percentiles)."""

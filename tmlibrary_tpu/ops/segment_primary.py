@@ -16,8 +16,10 @@ from tmlibrary_tpu.ops import label as label_ops
 from tmlibrary_tpu.ops import threshold as threshold_ops
 from tmlibrary_tpu.ops.segment_secondary import watershed_from_seeds
 from tmlibrary_tpu.ops.smooth import gaussian_smooth
+from tmlibrary_tpu.ops import named
 
 
+@named("distance")
 def distance_transform_approx(
     mask: jax.Array, max_distance: int = 64, method: str = "auto"
 ) -> jax.Array:

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tmlibrary_tpu.ops import named
 from tmlibrary_tpu.ops.label import _neighbor_shifts, _shift_with_fill
 
 
@@ -65,6 +66,7 @@ def expand_labels(
     return lab
 
 
+@named("watershed")
 def watershed_from_seeds(
     intensity: jax.Array,
     seeds: jax.Array,

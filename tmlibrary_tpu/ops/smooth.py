@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tmlibrary_tpu.ops import named
+
 
 def _gaussian_kernel1d(sigma: float, radius: int) -> jnp.ndarray:
     x = jnp.arange(-radius, radius + 1, dtype=jnp.float32)
@@ -56,6 +58,7 @@ def gaussian_radius(sigma: float, truncate: float = 4.0) -> int:
     return int(truncate * float(sigma) + 0.5)
 
 
+@named("smooth")
 def gaussian_smooth(img: jax.Array, sigma: float, truncate: float = 4.0) -> jax.Array:
     """Separable Gaussian blur matching ``scipy.ndimage.gaussian_filter``.
 
@@ -75,6 +78,7 @@ def gaussian_smooth(img: jax.Array, sigma: float, truncate: float = 4.0) -> jax.
     return _conv1d(out, k, axis=1)
 
 
+@named("smooth")
 def uniform_smooth(img: jax.Array, size: int) -> jax.Array:
     """Separable box (mean) filter matching ``scipy.ndimage.uniform_filter``."""
     if size < 1:
@@ -138,6 +142,7 @@ def _window_stack(img: jax.Array, size: int) -> jax.Array:
     return jnp.stack(views)
 
 
+@named("smooth")
 def median_smooth(img: jax.Array, size: int) -> jax.Array:
     """Median filter (odd ``size``) matching ``scipy.ndimage.median_filter``.
 
@@ -151,6 +156,7 @@ def median_smooth(img: jax.Array, size: int) -> jax.Array:
     return jnp.median(stack, axis=0)
 
 
+@named("smooth")
 def bilateral_smooth(
     img: jax.Array, size: int = 5, sigma_space: float = 2.0, sigma_range: float = 50.0
 ) -> jax.Array:

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tmlibrary_tpu.ops import named
+
 HIST_BINS = 65536  # exact for uint16 pixel data
 
 
@@ -85,6 +87,7 @@ def welford_update(state: WelfordState, raw: jax.Array) -> WelfordState:
     return WelfordState(n=n, mean=mean, m2=m2, offset=offset, hist=hist)
 
 
+@named("welford")
 def welford_scan(stack: jax.Array, init: WelfordState | None = None) -> WelfordState:
     """``lax.scan`` the update over a (B, H, W) site stack."""
     stack = jnp.asarray(stack)
@@ -98,6 +101,7 @@ def welford_scan(stack: jax.Array, init: WelfordState | None = None) -> WelfordS
     return out
 
 
+@named("welford")
 def welford_merge(a: WelfordState, b: WelfordState) -> WelfordState:
     """Chan et al. parallel combination of two disjoint-sample states.
 
@@ -119,6 +123,7 @@ def welford_merge(a: WelfordState, b: WelfordState) -> WelfordState:
     )
 
 
+@named("welford")
 def welford_finalize(
     state: WelfordState, percentile_qs: tuple[float, ...] = (0.1, 1.0, 50.0, 99.0, 99.9)
 ) -> dict[str, jax.Array]:

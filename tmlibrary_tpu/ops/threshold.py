@@ -13,6 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from tmlibrary_tpu.ops import named
 from tmlibrary_tpu.ops.smooth import gaussian_smooth, uniform_smooth
 
 
@@ -21,6 +22,7 @@ def threshold_manual(img: jax.Array, value) -> jax.Array:
     return jnp.asarray(img) > value
 
 
+@named("otsu")
 def otsu_value(img: jax.Array, bins: int = 256, method: str = "auto") -> jax.Array:
     """Otsu threshold value over a fixed-bin histogram.
 
@@ -130,6 +132,7 @@ def _otsu_argmax(hist: jax.Array, centers: jax.Array) -> jax.Array:
     return centers[k]
 
 
+@named("otsu")
 def threshold_otsu(img: jax.Array, bins: int = 256, correction_factor: float = 1.0) -> jax.Array:
     """Otsu global threshold (reference ``jtmodules/threshold_otsu``).
 
@@ -140,6 +143,7 @@ def threshold_otsu(img: jax.Array, bins: int = 256, correction_factor: float = 1
     return jnp.asarray(img, jnp.float32) > t
 
 
+@named("threshold_adaptive")
 def threshold_adaptive(
     img: jax.Array,
     method: str = "gaussian",

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tmlibrary_tpu.ops import named
 from tmlibrary_tpu.ops.label import _run_min_scan
 
 _BIG = jnp.iinfo(jnp.int32).max
@@ -52,6 +53,7 @@ def _native_3d() -> bool:
     return native.cpu_native_enabled() and native.has_3d_kernels()
 
 
+@named("label")
 def connected_components_3d(
     mask: jax.Array, connectivity: int = 26, method: str = "auto",
     chunk: "int | None" = None,
@@ -168,6 +170,7 @@ def propagate_labels_3d(labels: jax.Array, allowed: jax.Array) -> jax.Array:
     return out
 
 
+@named("watershed")
 def watershed_from_seeds_3d(
     intensity: jax.Array,
     seeds: jax.Array,

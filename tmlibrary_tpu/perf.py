@@ -224,29 +224,11 @@ _RUNTIME: dict[tuple, dict] = {}
 _MAX_SIGNATURES = 8
 
 
-#: compile spans buffered for the engine thread — record_compile can run
-#: on persist-worker threads (jterator bucket escalation), and only the
-#: engine thread may append to the run ledger, so spans queue here until
-#: WorkflowEngine._drain_compile_spans pops them
-_COMPILE_SPANS: list[dict] = []
-
-
-def pop_compile_spans() -> list[dict]:
-    """Drain buffered compile spans (engine thread).  Each dict carries
-    step/program/t0/elapsed/recompile, ready to append as a ledger
-    ``span`` event with ``span="compile"``."""
-    with _LOCK:
-        spans = list(_COMPILE_SPANS)
-        _COMPILE_SPANS.clear()
-    return spans
-
-
 def reset_profiles() -> None:
     """Drop all recorded program profiles (tests, fresh runs)."""
     with _LOCK:
         _PROFILES.clear()
         _RUNTIME.clear()
-        _COMPILE_SPANS.clear()
 
 
 def perf_profiles() -> list[dict]:
@@ -326,14 +308,6 @@ def record_compile(*, program: str, step: str = "jterator",
                 reg.histogram(
                     "tmx_perf_compile_seconds", capacity=labels["capacity"],
                 ).observe(compile_s)
-                with _LOCK:
-                    _COMPILE_SPANS.append({
-                        "step": str(step),
-                        "program": str(program),
-                        "t0": round(time.time() - compile_s, 6),
-                        "elapsed": round(compile_s, 6),
-                        "recompile": bool(recompile),
-                    })
             if cost.flops:
                 reg.gauge("tmx_perf_program_flops", **labels).set(cost.flops)
             if cost.bytes:

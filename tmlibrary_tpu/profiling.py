@@ -19,11 +19,12 @@ from tmlibrary_tpu import telemetry
 #: pipeline phases in execution order; keys of ``PipelineStats.summary()``
 PIPELINE_PHASES = ("prefetch_wait", "dispatch", "device_block", "persist")
 
-#: which resource each phase spends — the basis of the device/host time
-#: split in ``tmx perf`` and the ``tmx_perf_{device,host}_seconds_total``
-#: gauges.  ``dispatch`` is async launch work attributable to keeping the
-#: device fed; ``device_block`` is literal device wait; prefetch/persist
-#: are pure host IO.
+#: which resource each phase waits on BY THE HOST'S CLOCK — the basis of
+#: ``tmx perf``'s device/host split and the ``tmx_perf_*`` gauges: async
+#: launch and literal device wait are device, prefetch/persist host IO —
+#: except the ``device_wait`` spans inside ``persist`` (an escalation's
+#: re-launch), see :meth:`PipelineStats.record_device_wait`.  Not the
+#: device's own busy time: that is in a trace.
 PHASE_RESOURCE = {
     "prefetch_wait": "host",
     "dispatch": "device",
@@ -49,8 +50,7 @@ class PipelineStats:
     the original ``total_s``/``max_s`` keys (ledger shape stays
     backward-compatible).  When the telemetry registry is enabled the
     same observations are mirrored into ``tmx_pipeline_phase_seconds``
-    registry histograms, and per-batch (phase, seconds, t0) records are
-    buffered for the executor to flush as ``span`` ledger events.
+    registry histograms.
 
     Thread-safe: dispatch timings come from the main thread while
     device-block/persist timings come from persist workers.
@@ -74,24 +74,17 @@ class PipelineStats:
         }
         self._batches = 0
         self._clamps: list[dict] = []
-        #: batch index → [(phase, seconds, wall t0)], drained by the
-        #: executor on the calling thread to emit ``span`` ledger events
-        self._batch_spans: dict[int, list[tuple[str, float, float]]] = {}
+        #: seconds of ``persist`` spent waiting for a re-launched program
+        self._persist_device_wait = 0.0
 
-    def record(self, phase: str, seconds: float,
-               batch: int | None = None, t0: float | None = None) -> None:
+    def record(self, phase: str, seconds: float) -> None:
         self._hist[phase].observe(seconds)
         self._reg_hist[phase].observe(seconds)
-        if batch is not None and telemetry.enabled():
-            with self._lock:
-                self._batch_spans.setdefault(batch, []).append(
-                    (phase, seconds, t0 if t0 is not None else 0.0)
-                )
 
-    def pop_batch_spans(self, batch: int) -> list[tuple[str, float, float]]:
-        """Drain the buffered phase records for ``batch`` (span emission)."""
+    def record_device_wait(self, seconds: float) -> None:
+        """Device wait inside ``persist``: device time in the summary."""
         with self._lock:
-            return self._batch_spans.pop(batch, [])
+            self._persist_device_wait += float(seconds)
 
     def batch_done(self) -> None:
         with self._lock:
@@ -112,6 +105,7 @@ class PipelineStats:
         with self._lock:
             batches = self._batches
             clamps = list(self._clamps)
+            wait = self._persist_device_wait
         phases = {}
         for phase in PIPELINE_PHASES:
             hist = self._hist[phase]
@@ -130,11 +124,11 @@ class PipelineStats:
             "n_batches": batches,
             "phases": phases,
         }
-        device_s = sum(
+        device_s = wait + sum(
             p["total_s"] for ph, p in phases.items()
             if PHASE_RESOURCE.get(ph) == "device"
         )
-        host_s = sum(
+        host_s = -wait + sum(
             p["total_s"] for ph, p in phases.items()
             if PHASE_RESOURCE.get(ph) == "host"
         )
@@ -167,10 +161,9 @@ def device_trace(log_dir: str | Path | None):
 
     No-op when ``log_dir`` is None so call sites can pass the CLI flag
     straight through.  The trace directory is TensorBoard-compatible
-    (``tensorboard --logdir <dir>`` → Profile tab / xprof).  While the
-    trace is active, telemetry spans double as
-    ``jax.profiler.TraceAnnotation`` scopes so host spans line up with
-    device timelines in XProf.
+    (``tensorboard --logdir <dir>`` → Profile tab / xprof): telemetry
+    spans are ``TraceAnnotation``s in any trace and the programs carry
+    ``jax.named_scope`` stage names.
     """
     if log_dir is None:
         yield
@@ -179,9 +172,5 @@ def device_trace(log_dir: str | Path | None):
 
     path = Path(log_dir)
     path.mkdir(parents=True, exist_ok=True)
-    telemetry.set_trace_bridge(True)
-    try:
-        with jax.profiler.trace(str(path)):
-            yield
-    finally:
-        telemetry.set_trace_bridge(False)
+    with jax.profiler.trace(str(path)):
+        yield

@@ -10,10 +10,10 @@ metrics and adds what the ledger alone cannot show:
   bounded-reservoir histograms (p50/p95/max) — fed by the workflow engine,
   the pipelined executor, ``resilience.py`` and the throughput-critical
   steps (corilla/illuminati/jterator);
-* lightweight nested **spans** (run → step → batch → phase) recorded as
-  ``span`` events in the run ledger and, while ``profiling.device_trace``
-  is active, bridged into ``jax.profiler.TraceAnnotation`` so host spans
-  line up with device traces in XProf;
+* nested **spans** (run → step → batch → phase → the work inside it, each
+  with its ``parent``) from any thread through one process buffer into
+  ``span`` ledger events; each is a ``jax.profiler.TraceAnnotation`` too,
+  and JAX's compile-path events become child spans of what caused them;
 * a :class:`ResourceSampler` daemon thread (RSS, open file handles, jax
   device memory when available) that also maintains a heartbeat timestamp
   file consumed by ``tmx workflow status`` and ``tmx top``;
@@ -31,6 +31,7 @@ telemetry-on run stays bit-identical to telemetry-off (pinned by
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import logging
@@ -553,21 +554,30 @@ def record_device_times(times: list[tuple[str, float]], step: str = "",
 
 # ---------------------------------------------------------------------------
 # span tracing
-
-_trace_bridge = threading.Event()
-
-
-def set_trace_bridge(active: bool) -> None:
-    """Toggled by ``profiling.device_trace`` so spans double as
-    ``jax.profiler.TraceAnnotation`` scopes only while a device trace is
-    being captured (TraceAnnotation outside a trace is wasted work)."""
-    if active:
-        _trace_bridge.set()
-    else:
-        _trace_bridge.clear()
-
+#
+# One buffer for the whole process.  A span may close on any thread, but
+# only the engine thread may append to the run ledger: a span given no
+# ``emit`` lands here and the engine drains it (:func:`drain_spans`).
+# Bounded: a process without an engine (tests, serve's queries) never does.
 
 _span_local = threading.local()
+_span_lock = threading.Lock()
+_span_buffer: collections.deque = collections.deque(maxlen=65536)
+
+#: jax.monitoring duration events -> the span each becomes (a child of
+#: whatever span the compiling thread is in; the persistent-cache read
+#: happens inside JAX's backend-compile bracket, hence its fixed parent)
+_COMPILE_EVENT_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit_lower",
+    "/jax/core/compile/backend_compile_duration": "jit_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+#: shorter compile-path events are dropped: a traced program fires one
+#: ``jaxpr_trace_duration`` per nested ``jit`` (hundreds, each inside its
+#: caller's), and what a span is for is finding seconds
+_COMPILE_SPAN_MIN_S = 1e-3
+_compile_listener_on = False
 
 
 def _span_stack() -> list[str]:
@@ -578,50 +588,117 @@ def _span_stack() -> list[str]:
 
 
 @contextlib.contextmanager
-def span(name: str, emit: Callable[..., Any] | None = None,
-         **attrs: Any) -> Iterator[None]:
-    """Nested host span; records a ``span`` ledger event via ``emit``.
-
-    ``emit`` is typically ``RunLedger.append`` partial-applied with the
-    step/batch context.  Zero-cost when telemetry is disabled.
-    """
-    if not enabled():
-        yield
-        return
-    stack = _span_stack()
-    stack.append(name)
-    path = "/".join(stack)
-    annotation = None
-    if _trace_bridge.is_set():
-        try:
-            import jax
-
-            annotation = jax.profiler.TraceAnnotation(path)
-            annotation.__enter__()
-        except Exception:  # pragma: no cover - profiler unavailable
-            annotation = None
-    t0 = time.time()
-    p0 = time.perf_counter()
+def span_scope(step: str | None = None, batch: Any = None) -> Iterator[None]:
+    """Set the ambient ``step``/``batch`` of every span the calling thread
+    closes (the engine around a step, the executor on its workers)."""
+    prev = getattr(_span_local, "ctx", None)
+    new = {"step": step, "batch": batch}
+    _span_local.ctx = {**(prev or {}),
+                       **{k: v for k, v in new.items() if v is not None}}
     try:
         yield
     finally:
+        _span_local.ctx = prev
+
+
+def _span_record(name, parent, t0: float, elapsed: float, attrs) -> dict:
+    head = {"event": "span", "span": name, "t0": round(t0, 6),
+            "elapsed": round(elapsed, 6),
+            **({} if parent is None else {"parent": parent})}
+    return {**head, **(getattr(_span_local, "ctx", None) or {}), **attrs}
+
+
+def _buffer(record: dict) -> None:
+    # which thread: spans of one thread nest, those of others overlap
+    record["thread"] = threading.current_thread().name
+    with _span_lock:
+        _span_buffer.append(record)
+
+
+def _on_compile_event(event: str, duration: float, **kwargs: Any) -> None:
+    name = _COMPILE_EVENT_SPANS.get(event)
+    if name is None or duration < _COMPILE_SPAN_MIN_S or not enabled():
+        return
+    stack = _span_stack()
+    parent = "jit_compile" if name == "cache_load" else (
+        stack[-1] if stack else None)
+    attrs = {"program": str(kwargs["fun_name"])} if "fun_name" in kwargs \
+        else {}
+    _buffer(_span_record(name, parent, time.time() - duration, duration,
+                         attrs))
+
+
+def _ensure_compile_listener() -> None:
+    """Register the one ``jax.monitoring`` listener at the first span
+    opened with telemetry on (not at import: importing starts nothing)."""
+    global _compile_listener_on
+    if _compile_listener_on:
+        return
+    with _span_lock:
+        if _compile_listener_on:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_event)
+        _compile_listener_on = True
+
+
+@contextlib.contextmanager
+def span(name: str, emit: Callable[..., Any] | None = None,
+         **attrs: Any) -> Iterator[dict]:
+    """Host span, on any thread: ``span``, ``parent`` (the enclosing span
+    of the calling thread), wall ``t0``, ``elapsed``, the ambient
+    ``step``/``batch`` (:func:`span_scope`) and ``attrs``.
+
+    With ``emit`` (``RunLedger.append``, for callers on the appending
+    thread) the record goes to it on exit; without, into the buffer the
+    engine drains.  Yields ``attrs``, to be filled inside the block.  Also
+    a ``jax.profiler.TraceAnnotation`` (a no-op outside a trace).
+    Zero-cost when telemetry is disabled: no clock is read.
+    """
+    if not enabled():
+        yield attrs
+        return
+    _ensure_compile_listener()
+    import jax.profiler
+
+    stack = _span_stack()
+    parent = stack[-1] if stack else None
+    stack.append(name)
+    step = (getattr(_span_local, "ctx", None) or {}).get("step")
+    # in a trace: "<step>/<span>", the step span itself "<step>"
+    label = f"{step}/{name}".removesuffix("/step") if step else name
+    t0 = time.time()
+    p0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(label):
+            yield attrs
+    finally:
         elapsed = time.perf_counter() - p0
-        if annotation is not None:
-            with contextlib.suppress(Exception):
-                annotation.__exit__(None, None, None)
         stack.pop()
-        # a fatal injected fault simulates hard process death — a dead
-        # process writes nothing, so the span must not land either (the
-        # chaos suite pins that the torn ledger line stays trailing)
+        # a fatal injected fault simulates hard process death: a dead
+        # process writes nothing, so the span must not land either
         exc = sys.exc_info()[1]
-        if isinstance(exc, FaultInjected) and exc.fatal:
-            emit = None
-        if emit is not None:
-            try:
-                emit(event="span", span=name, path=path, t0=round(t0, 6),
-                     elapsed=round(elapsed, 6), **attrs)
-            except Exception:
-                logger.debug("span emit failed for %s", path, exc_info=True)
+        if not (isinstance(exc, FaultInjected) and exc.fatal):
+            record = _span_record(name, parent, t0, elapsed, attrs)
+            if emit is None:
+                _buffer(record)
+            else:
+                try:
+                    emit(**record)
+                except Exception:
+                    logger.debug("span emit failed for %s", name,
+                                 exc_info=True)
+
+
+def drain_spans() -> list[dict]:
+    """Empty the process buffer: its records in ``t0`` order, parents first."""
+    with _span_lock:
+        records = list(_span_buffer)
+        _span_buffer.clear()
+    records.sort(key=lambda r: (r["t0"], -r["elapsed"]))
+    return records
 
 
 # ---------------------------------------------------------------------------
@@ -1606,18 +1683,29 @@ def registry_from_ledger(events: Iterable[dict]) -> MetricsRegistry:
 # span tree + critical path (tmx trace)
 
 
-def build_span_tree(events: Iterable[dict]) -> dict:
-    """Assemble the run → step → batch → phase tree from ledger events.
+#: spans that are the tree's own levels, not work inside a batch
+_STRUCTURAL_SPANS = ("run", "step", "batch")
 
-    Structure comes from event fields (``step``/``batch``/``span``), not
-    from span nesting paths, so phase spans recorded on executor worker
-    threads land under the right batch.  Ledgers without ``span`` events
-    (seed-era) still produce a tree from ``batch_done``/``step_done``
-    timing.
+
+def _is_top_level(ev: dict) -> bool:
+    """A span that hangs off its batch directly (a phase, a worker's)."""
+    return ev.get("parent") in (None, *_STRUCTURAL_SPANS)
+
+
+def build_span_tree(events: Iterable[dict]) -> dict:
+    """Assemble the run → step → batch → phase → inner-span tree from
+    ledger events.
+
+    A batch's spans come from several threads, so the levels down to the
+    phase come from the ``step``/``batch`` fields; below that a span hangs
+    under the ``parent``-named span whose interval holds its start.
+    Seed-era ledgers (no ``span`` events) still give a tree from
+    ``batch_done``/``step_done`` timing.
     """
     root: dict[str, Any] = {"name": "run", "elapsed": 0.0, "children": []}
     steps: dict[str, dict] = {}
     batches: dict[tuple[str, Any], dict] = {}
+    inner: dict[tuple[str, Any], list[dict]] = {}
 
     def _step_node(step: str) -> dict:
         node = steps.get(step)
@@ -1649,21 +1737,12 @@ def build_span_tree(events: Iterable[dict]) -> dict:
             elif name == "batch":
                 node = _batch_node(step, ev.get("batch"))
                 node["elapsed"] = elapsed
-            else:  # phase span (prefetch_wait/dispatch/device_block/persist)
-                # batch-less phase spans (e.g. a compile attributed to
-                # the step, not to one batch) stay OUT of the tree:
-                # fabricating a "batch:None" node would miscount
-                # batches, and nesting under the step would outweigh
-                # every real batch on the critical path.  Compile cost
-                # keeps its own surfaces (perf profiles, `tmx trace`
-                # raw spans, the WARM row).
-                if ev.get("batch") is None:
-                    continue
-                parent = _batch_node(step, ev["batch"])
-                parent["children"].append(
-                    {"name": f"phase:{name}", "elapsed": elapsed,
-                     "children": []}
-                )
+            elif ev.get("batch") is not None:
+                # batch-less spans (a compile-ahead thread's) stay OUT of
+                # the tree: a "batch:None" node would miscount batches and
+                # outweigh the real ones; `tmx trace`'s table lists them
+                _batch_node(step, ev["batch"])
+                inner.setdefault((step, ev["batch"]), []).append(ev)
         elif kind == "batch_done":
             node = _batch_node(step, ev.get("batch"))
             if not node["elapsed"]:
@@ -1672,11 +1751,36 @@ def build_span_tree(events: Iterable[dict]) -> dict:
             node = _step_node(step)
             if not node["elapsed"]:
                 node["elapsed"] = float(ev.get("elapsed", 0.0) or 0.0)
+    for key, evs in inner.items():
+        _nest_spans(batches[key], evs)
     if not root["elapsed"]:
         root["elapsed"] = round(
             sum(c["elapsed"] for c in root["children"]), 6
         )
     return root
+
+
+def _nest_spans(batch_node: dict, evs: list[dict]) -> None:
+    """Hang one batch's spans under ``batch_node``, parents first, each
+    child under the latest-started span of its ``parent``'s name."""
+    placed: list[tuple[float, float, str, dict]] = []  # t0, t1, name, node
+    for ev in sorted(evs, key=lambda e: (float(e.get("t0", 0.0) or 0.0),
+                                         -float(e.get("elapsed", 0.0)))):
+        name = str(ev.get("span", ""))
+        t0 = float(ev.get("t0", 0.0) or 0.0)
+        elapsed = float(ev.get("elapsed", 0.0) or 0.0)
+        top = _is_top_level(ev)
+        node = {"name": f"phase:{name}" if top else name,
+                "elapsed": elapsed, "children": []}
+        home = batch_node
+        if not top:
+            for p0, p1, pname, pnode in reversed(placed):
+                # 1 ms of slack: t0 is a wall clock, elapsed a monotonic
+                if pname == ev["parent"] and p0 - 1e-3 <= t0 <= p1 + 1e-3:
+                    home = pnode
+                    break
+        home["children"].append(node)
+        placed.append((t0, t0 + elapsed, name, node))
 
 
 def annotate_critical_path(node: dict) -> dict:
@@ -1717,14 +1821,14 @@ def render_span_tree(node: dict, indent: int = 0) -> str:
 
 
 def phase_totals(events: Iterable[dict]) -> dict[str, float]:
-    """Sum phase-span durations per phase name (critical-path accounting
-    cross-checkable against ``pipeline_stats`` totals)."""
+    """Sum the top-level spans' durations per name (cross-checkable
+    against ``pipeline_stats``); a span inside another counts once."""
     totals: dict[str, float] = {}
     for ev in events:
-        if ev.get("event") != "span":
+        if ev.get("event") != "span" or not _is_top_level(ev):
             continue
         name = str(ev.get("span", ""))
-        if name in ("run", "step", "batch"):
+        if name in _STRUCTURAL_SPANS:
             continue
         totals[name] = totals.get(name, 0.0) + float(ev.get("elapsed", 0.0))
     return totals

@@ -9,8 +9,10 @@ Format JSON (the ``chrome://tracing`` / Perfetto interchange format):
 * one **thread row per tenant/job** (``tid``), so a multi-tenant serve
   window reads as parallel lanes and a single run as one lane;
 * every span event (``queue_wait``/``sched_delay``/``job`` from the serve
-  ledger, ``run``/``step``/``batch``/phase/``compile`` from the engine)
-  becomes a complete ``"X"`` slice with micro-second ``ts``/``dur``;
+  ledger, ``run``/``step``/``batch``/phase/inner spans from the engine)
+  becomes a complete ``"X"`` slice with micro-second ``ts``/``dur``; a
+  span from a worker thread sits on that thread's row, so slices nest
+  inside their ``parent`` by time and a child's time is drawn once;
 * **flow arrows** link enqueue → admit → execute for each ``trace_id``,
   so one job's whole life reads as a connected chain across lanes;
 * seed-era ledgers (no ``span`` events) still export: slices are
@@ -120,7 +122,8 @@ def _flow_id(ev: dict) -> int | None:
 def _span_args(ev: dict) -> dict:
     return {k: ev[k] for k in
             ("step", "batch", "trace_id", "job", "tenant", "attempt",
-             "program", "recompile", "path")
+             "program", "parent", "bytes", "pixels", "files", "tiles",
+             "capacity")
             if ev.get(k) is not None}
 
 
@@ -156,11 +159,16 @@ class _Rows:
 
 def _lane(ev: dict) -> str:
     """Thread-row label: tenant/job for traced jobs, the step for plain
-    runs, ``serve`` for daemon housekeeping."""
+    runs, ``serve`` for daemon housekeeping.  A span that closed off the
+    engine thread carries its ``thread``: it gets that thread's own row,
+    where it nests by time inside its ``parent`` and beside nothing that
+    ran concurrently on another thread."""
     job = ev.get("job")
     if job:
         tenant = ev.get("tenant") or "default"
         return f"{tenant}/{job}"
+    if ev.get("thread") and ev["thread"] != "MainThread":
+        return str(ev["thread"])
     if ev.get("step"):
         return "run"
     return "run" if ev.get("event") == "span" else "serve"
@@ -275,6 +283,24 @@ def chrome_trace(events: Iterable[dict],
             "trace_id": trace_id,
         },
     }
+
+
+def span_table(events: Iterable[dict]) -> list[dict]:
+    """One row per (step, parent, span): how often it ran and its summed
+    seconds, longest first — every span of the ledger, the batch-less
+    ones and the ones inside others included (``tmx trace``)."""
+    rows: dict[tuple, dict] = {}
+    for ev in events:
+        if ev.get("event") != "span":
+            continue
+        key = (str(ev.get("step", "")), str(ev.get("parent") or ""),
+               str(ev.get("span", "")))
+        row = rows.setdefault(key, {"step": key[0], "parent": key[1],
+                                    "span": key[2], "count": 0,
+                                    "total_s": 0.0})
+        row["count"] += 1
+        row["total_s"] += float(ev.get("elapsed", 0.0) or 0.0)
+    return sorted(rows.values(), key=lambda r: -r["total_s"])
 
 
 # ------------------------------------------------------------- validation

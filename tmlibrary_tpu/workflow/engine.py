@@ -19,7 +19,6 @@ reference's contract.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import hashlib
 import inspect
 import json
@@ -619,6 +618,7 @@ class Workflow:
         try:
             if guard is not None:
                 guard.ensure_backend(where="run")
+            telemetry.drain_spans()  # whatever closed before this run
             with telemetry.span("run", emit=self.ledger.append):
                 for stage in self.description.stages:
                     for sd in stage.steps:
@@ -640,11 +640,9 @@ class Workflow:
                             ))
                         if guard is not None:
                             guard.ensure_backend(where=sd.name)
-                        with telemetry.span(
-                            "step",
-                            emit=functools.partial(self.ledger.append,
-                                                   step=sd.name),
-                        ):
+                        with telemetry.span_scope(step=sd.name), \
+                                telemetry.span("step",
+                                               emit=self.ledger.append):
                             summary[sd.name] = self._run_step(sd, resume)
         finally:
             if self._watchdog is not None:
@@ -653,7 +651,7 @@ class Workflow:
                 self._watchdog = None
             if sampler is not None:
                 sampler.stop()
-            self._drain_compile_spans()
+            self._drain_spans()
             exc = sys.exc_info()[1]
             if exc is not None and not isinstance(exc, PreemptedError) \
                     and not (isinstance(exc, FaultInjected) and exc.fatal):
@@ -690,18 +688,18 @@ class Workflow:
                 reason="watchdog", extra={"step": step_name},
             )
 
-    def _drain_compile_spans(self, step_name: str | None = None) -> None:
-        """Append buffered compile spans from perf.py — buffered because
-        ``record_compile`` can run on persist-worker threads (jterator
-        bucket escalation) and only the engine thread may touch the
-        ledger.  No-op (and empties nothing) when telemetry is off."""
-        if not telemetry.enabled():
-            return
-        from tmlibrary_tpu import perf
-        for sp in perf.pop_compile_spans():
+    def _drain_spans(self, step_name: str | None = None) -> None:
+        """Append the spans that closed on any thread since the last
+        drain — here, because only the engine thread may touch the
+        ledger.  A span closed outside any step scope (a compile-ahead
+        thread) is booked to the step that is running."""
+        exc = sys.exc_info()[1]
+        if isinstance(exc, FaultInjected) and exc.fatal:
+            return  # simulated hard crash: no further ledger writes
+        for record in telemetry.drain_spans():
             if step_name is not None:
-                sp.setdefault("step", step_name)
-            self.ledger.append(event="span", span="compile", **sp)
+                record.setdefault("step", step_name)
+            self.ledger.append(**record)
 
     def _note_preempted(self, exc: PreemptedError) -> None:
         """Record the drain boundary durably (``run_preempted`` event +
@@ -873,7 +871,8 @@ class Workflow:
     # ---------------------------------------------------------- batch level
     def _exec_batch(self, step, batch: dict) -> dict:
         faults.maybe_fire("batch_run", step=step.name, batch=batch["index"])
-        return step.run_batch(batch)
+        with telemetry.span_scope(step=step.name, batch=batch["index"]):
+            return step.run_batch(batch)
 
     def _retry_after(self, step, batch: dict, first_exc: Exception,
                      policy: RetryPolicy) -> RetryOutcome:
@@ -1071,7 +1070,7 @@ class Workflow:
                                                           policy, pstats):
                     current_batch = batch["index"]
                     self._drain_watchdog(sd.name)
-                    self._drain_compile_spans(sd.name)
+                    self._drain_spans(sd.name)
                     if outcome.ok:
                         b_elapsed = time.time() - bt0
                         if telemetry.enabled():
@@ -1172,7 +1171,7 @@ class Workflow:
                 # collect is part of the step execution the log file
                 # covers; it sees only the surviving results
                 collected = self._call_collect(step, results)
-            self._drain_compile_spans(sd.name)
+            self._drain_spans(sd.name)
             metrics.histogram("tmx_step_seconds", step=sd.name).observe(
                 time.time() - t0
             )

@@ -257,24 +257,6 @@ class PipelinedExecutor:
                     continue  # _run_window drained: pos is the failed batch
                 raise
 
-    # ---------------------------------------------------------------- spans
-    def _flush_spans(self, batch: dict) -> None:
-        """Emit the buffered phase timings for a completed batch as
-        ``span`` events — on the calling (engine) thread, right before the
-        batch's ``(batch, result)`` is yielded, so every ledger append
-        stays on one thread and span events precede ``batch_done``."""
-        if self.stats is None or self.on_event is None:
-            return
-        idx = batch.get("index")
-        if idx is None:
-            return
-        for phase, seconds, t0 in self.stats.pop_batch_spans(idx):
-            self.on_event(
-                event="span", span=phase, batch=idx,
-                t0=round(t0, 6), elapsed=round(seconds, 6),
-                resource=profiling.PHASE_RESOURCE.get(phase, "host"),
-            )
-
     # --------------------------------------------------------------- window
     def _run_window(self, batches: list[dict]) -> Iterator[tuple[dict, dict]]:
         step = self.step
@@ -286,6 +268,17 @@ class PipelinedExecutor:
             # shared null context when no watchdog: zero per-batch cost
             return (_NULL_CM if watchdog is None
                     else watchdog.arm(phase, step=step_name, batch=idx))
+
+        @contextlib.contextmanager
+        def _phase(phase: str, idx):
+            # a phase on the thread that works: span scope + stats record
+            t0 = time.perf_counter()
+            with telemetry.span_scope(step=step_name, batch=idx), \
+                    telemetry.span(
+                        phase, resource=profiling.PHASE_RESOURCE[phase]):
+                yield
+            if stats is not None:
+                stats.record(phase, time.perf_counter() - t0)
 
         has_prefetch = hasattr(step, "prefetch_batch")
         prefetcher = None
@@ -301,18 +294,15 @@ class PipelinedExecutor:
         window: collections.deque = collections.deque()
         prefetched: dict[int, concurrent.futures.Future] = {}
 
+        def prefetch_task(batch: dict, idx):
+            with telemetry.span_scope(step=step_name, batch=idx):
+                return step.prefetch_batch(batch)
+
         def persist_task(eff: dict, ctx, idx: int) -> dict:
             if hasattr(step, "block_batch"):
-                w0 = time.time()
-                t0 = time.perf_counter()
-                with _arm("block", idx):
+                with _phase("device_block", idx), _arm("block", idx):
                     step.block_batch(ctx)
-                if stats is not None:
-                    stats.record("device_block", time.perf_counter() - t0,
-                                 batch=idx, t0=w0)
-            w0 = time.time()
-            t0 = time.perf_counter()
-            with _arm("persist", idx):
+            with _phase("persist", idx), _arm("persist", idx):
                 # persist-site faults land here: after the device work,
                 # before the outputs are durable (kill-mid-persist,
                 # sigterm, hang) — inside the armed phase so an injected
@@ -320,8 +310,9 @@ class PipelinedExecutor:
                 faults.maybe_fire("persist", step=step_name, batch=idx)
                 result = step.persist_batch(eff, ctx)
             if stats is not None:
-                stats.record("persist", time.perf_counter() - t0,
-                             batch=idx, t0=w0)
+                # what the persist spent waiting for a program it re-launched
+                if isinstance(result, dict) and result.get("device_wait_s"):
+                    stats.record_device_wait(result["device_wait_s"])
                 stats.batch_done()
             return result
 
@@ -337,9 +328,7 @@ class PipelinedExecutor:
         def pop_one() -> tuple[dict, dict]:
             batch, fut = window.popleft()
             note_inflight()
-            result = fut.result()
-            self._flush_spans(batch)
-            return batch, result
+            return batch, fut.result()
 
         try:
             for i, batch in enumerate(batches):
@@ -366,27 +355,17 @@ class PipelinedExecutor:
                     for j in range(i, min(i + self.depth, len(batches))):
                         if j not in prefetched:
                             prefetched[j] = prefetcher.submit(
-                                step.prefetch_batch, batches[j]
+                                prefetch_task, batches[j],
+                                batches[j].get("index", j),
                             )
                 bidx = batch.get("index", i)
                 try:
                     pre = None
                     if i in prefetched:
-                        w0 = time.time()
-                        t0 = time.perf_counter()
-                        pre = prefetched.pop(i).result()
-                        if stats is not None:
-                            stats.record(
-                                "prefetch_wait", time.perf_counter() - t0,
-                                batch=bidx, t0=w0,
-                            )
-                    w0 = time.time()
-                    t0 = time.perf_counter()
-                    with _arm("launch", bidx):
+                        with _phase("prefetch_wait", bidx):
+                            pre = prefetched.pop(i).result()
+                    with _phase("dispatch", bidx), _arm("launch", bidx):
                         eff, ctx = step.launch_batch(batch, pre)
-                    if stats is not None:
-                        stats.record("dispatch", time.perf_counter() - t0,
-                                     batch=bidx, t0=w0)
                     if self.warm_hook is not None and not self._warmed:
                         self._warmed = True
                         try:

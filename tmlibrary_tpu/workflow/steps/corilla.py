@@ -115,36 +115,44 @@ class IlluminationStatisticsCalculator(Step):
                                                channel=channel),
             depth=max(args.get("prefetch_chunks", 2), 1),
         )
-        for stack in loaded:
-            if dev_state is None:
-                dev_state = scan_jit(jnp.asarray(stack))
-            else:
-                dev_state = merge_jit(dev_state, scan_jit(jnp.asarray(stack)))
-        if dev_state is not None:
-            state = (
-                jax.tree.map(np.asarray, dev_state)
-                if state is None
-                else jax.tree.map(
-                    np.asarray,
-                    merge_jit(
-                        jax.tree.map(jnp.asarray, state),
-                        jax.tree.map(jnp.asarray, dev_state),
-                    ),
+        while True:
+            with telemetry.span("read_wait"):
+                stack = next(loaded, None)
+            if stack is None:
+                break
+            # upload + dispatch: the device's work is waited for below
+            with telemetry.span("scan", bytes=stack.nbytes):
+                if dev_state is None:
+                    dev_state = scan_jit(jnp.asarray(stack))
+                else:
+                    dev_state = merge_jit(dev_state,
+                                          scan_jit(jnp.asarray(stack)))
+        with telemetry.span("finalize"):
+            if dev_state is not None:
+                state = (
+                    jax.tree.map(np.asarray, dev_state)
+                    if state is None
+                    else jax.tree.map(
+                        np.asarray,
+                        merge_jit(
+                            jax.tree.map(jnp.asarray, state),
+                            jax.tree.map(jnp.asarray, dev_state),
+                        ),
+                    )
                 )
-            )
-        if state is None:
-            state = jax.tree.map(np.asarray, welford_init((exp.site_height, exp.site_width)))
+            if state is None:
+                state = jax.tree.map(np.asarray, welford_init((exp.site_height, exp.site_width)))
 
-        out = jax.tree.map(np.asarray, welford_finalize(jax.tree.map(jnp.asarray, state)))
-        if args["smooth_sigma"] > 0:
-            from tmlibrary_tpu.ops.smooth import gaussian_smooth
+            out = jax.tree.map(np.asarray, welford_finalize(jax.tree.map(jnp.asarray, state)))
+            if args["smooth_sigma"] > 0:
+                from tmlibrary_tpu.ops.smooth import gaussian_smooth
 
-            out["mean_log"] = np.asarray(
-                gaussian_smooth(out["mean_log"], args["smooth_sigma"])
-            )
-            out["std_log"] = np.asarray(
-                gaussian_smooth(out["std_log"], args["smooth_sigma"])
-            )
+                out["mean_log"] = np.asarray(
+                    gaussian_smooth(out["mean_log"], args["smooth_sigma"])
+                )
+                out["std_log"] = np.asarray(
+                    gaussian_smooth(out["std_log"], args["smooth_sigma"])
+                )
         # the finalize already inverted exact raw-intensity percentiles
         # from the Welford histogram — hand them to the QC session (one
         # no-op call when QC is off) so the run profile records each
@@ -159,7 +167,8 @@ class IlluminationStatisticsCalculator(Step):
             ch_name, out["percentile_keys"], out["percentile_values"]
         )
         out.pop("hist", None)
-        self.store.write_illumstats(out, cycle=cycle, channel=channel)
+        with telemetry.span("write"):
+            self.store.write_illumstats(out, cycle=cycle, channel=channel)
         # one batch == one channel; same perf_counter wall-time math as
         # bench.py's channels/sec metric (BASELINE.json)
         telemetry.get_registry().throughput(

@@ -68,9 +68,10 @@ class PyramidBuilder(Step):
 
         stats = None
         if args["correct"] and self.store.has_illumstats(cycle=cycle, channel=channel):
-            stats = IllumstatsContainer.from_store(
-                self.store.read_illumstats(cycle=cycle, channel=channel)
-            )
+            with telemetry.span("stats_read"):
+                stats = IllumstatsContainer.from_store(
+                    self.store.read_illumstats(cycle=cycle, channel=channel)
+                )
 
         # display range from corilla percentiles (reference: scale step)
         if stats is not None and stats.percentiles:
@@ -101,32 +102,39 @@ class PyramidBuilder(Step):
         )
         for part in create_partitions(refs, args["batch_size"]):
             idx = [self.store.site_linear_index(r) for r, _, _ in part]
-            stack = self.store.read_sites(idx, cycle=cycle, channel=channel)
-            prepped = np.asarray(
-                prep(jnp.asarray(stack), jnp.asarray(shifts_table[idx]))
-            )
-            for (ref, w, s), img in zip(part, prepped):
-                y0 = (w.row * spw_y + s.y) * H
-                x0 = (w.column * spw_x + s.x) * W
-                mosaic[y0 : y0 + H, x0 : x0 + W] = img
+            with telemetry.span("read"):
+                stack = self.store.read_sites(idx, cycle=cycle, channel=channel)
+            # upload, the (re-traced) program, and the fetch of its result
+            with telemetry.span("prep", bytes=stack.nbytes):
+                prepped = np.asarray(
+                    prep(jnp.asarray(stack), jnp.asarray(shifts_table[idx]))
+                )
+            with telemetry.span("mosaic"):
+                for (ref, w, s), img in zip(part, prepped):
+                    y0 = (w.row * spw_y + s.y) * H
+                    x0 = (w.column * spw_x + s.x) * W
+                    mosaic[y0 : y0 + H, x0 : x0 + W] = img
 
         if upper is None:
             # one call partitions both quantiles in a single pass over the
             # plate mosaic (two separate np.percentile calls measured ~2x
             # the cost in the workflow bench profile)
-            lo_up = np.percentile(mosaic, [0.1, args["clip_percent"]])
+            with telemetry.span("percentile"):
+                lo_up = np.percentile(mosaic, [0.1, args["clip_percent"]])
             lower, upper = float(lo_up[0]), float(lo_up[1])
 
         n_dev = min(args["n_devices"], len(jax.devices()))
-        if n_dev > 1:
-            from jax.sharding import Mesh
+        # the mosaic's upload and the downsample chain's dispatch
+        with telemetry.span("pyramid", bytes=mosaic.nbytes):
+            if n_dev > 1:
+                from jax.sharding import Mesh
 
-            from tmlibrary_tpu.parallel.halo import sharded_pyramid_levels
+                from tmlibrary_tpu.parallel.halo import sharded_pyramid_levels
 
-            mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("rows",))
-            levels = sharded_pyramid_levels(jnp.asarray(mosaic), mesh)
-        else:
-            levels = pyramid_levels(jnp.asarray(mosaic))
+                mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("rows",))
+                levels = sharded_pyramid_levels(jnp.asarray(mosaic), mesh)
+            else:
+                levels = pyramid_levels(jnp.asarray(mosaic))
         out_dir = self.store.root / "pyramids" / f"channel{channel:02d}"
         # PNG encode is host-side and embarrassingly parallel; cv2 releases
         # the GIL during imencode, so a thread pool overlaps tile encodes
@@ -144,15 +152,19 @@ class PyramidBuilder(Step):
             # level's cut; futures are drained per level before the array
             # is dropped
             for li, level in enumerate(levels):
-                level8 = np.asarray(to_uint8(level, float(lower), float(upper)))
+                with telemetry.span("level_fetch"):
+                    level8 = np.asarray(
+                        to_uint8(level, float(lower), float(upper)))
                 ldir = out_dir / f"{len(levels) - 1 - li}"
                 ldir.mkdir(parents=True, exist_ok=True)
-                futures = {
-                    pool.submit(cv2.imwrite, str(ldir / f"{ty}_{tx}.png"), tile):
-                    f"{ty}_{tx}.png"
-                    for (ty, tx), tile in cut_tiles(level8).items()
-                }
-                bad = [name for fut, name in futures.items() if not fut.result()]
+                with telemetry.span("encode", bytes=level8.nbytes) as enc:
+                    futures = {
+                        pool.submit(cv2.imwrite, str(ldir / f"{ty}_{tx}.png"), tile):
+                        f"{ty}_{tx}.png"
+                        for (ty, tx), tile in cut_tiles(level8).items()
+                    }
+                    bad = [name for fut, name in futures.items() if not fut.result()]
+                    enc["tiles"] = len(futures)
                 if bad:
                     raise WorkflowError(
                         f"PNG tile encode failed for {len(bad)} tiles of "

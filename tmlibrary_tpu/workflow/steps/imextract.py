@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from tmlibrary_tpu import telemetry
 from tmlibrary_tpu.errors import MetadataError
 from tmlibrary_tpu.utils import create_partitions
 from tmlibrary_tpu.workflow.api import Step
@@ -140,18 +141,26 @@ class ImageExtractor(Step):
                 cycle, channel, tpoint, zplane = key
                 pixels = []
                 indices = []
-                for i, f in enumerate(files):
-                    img = futures[(key, i)].result()
-                    if img.shape != (exp.site_height, exp.site_width):
-                        raise MetadataError(
-                            f"{f['path']}: shape {img.shape} != site shape "
-                            f"({exp.site_height}, {exp.site_width})"
-                        )
-                    pixels.append(np.asarray(img, np.uint16))
-                    indices.append(f["site_index"])
-                self.store.write_sites(
-                    np.stack(pixels), indices,
-                    cycle=cycle, channel=channel, tpoint=tpoint, zplane=zplane,
-                )
+                # the main thread's wait for this plane group's decodes
+                # (the pool decodes every group at once)
+                with telemetry.span(
+                    "decode", files=len(files),
+                    pixels=len(files) * exp.site_height * exp.site_width,
+                ):
+                    for i, f in enumerate(files):
+                        img = futures[(key, i)].result()
+                        if img.shape != (exp.site_height, exp.site_width):
+                            raise MetadataError(
+                                f"{f['path']}: shape {img.shape} != site shape "
+                                f"({exp.site_height}, {exp.site_width})"
+                            )
+                        pixels.append(np.asarray(img, np.uint16))
+                        indices.append(f["site_index"])
+                with telemetry.span("write"):
+                    self.store.write_sites(
+                        np.stack(pixels), indices,
+                        cycle=cycle, channel=channel, tpoint=tpoint,
+                        zplane=zplane,
+                    )
                 n_written += len(files)
         return {"n_written": n_written}

@@ -621,8 +621,9 @@ class ImageAnalysisRunner(Step):
         if batch["args"].get("layout", "sites") == "spatial":
             return self._run_spatial(batch)
         cap = self._route_capacity(batch)
-        result = self._launch(batch, capacity=cap)
-        return self._persist(batch, result, capacity=cap)
+        tally: dict = {}
+        result = self._launch(batch, capacity=cap, tally=tally)
+        return self._persist(batch, result, capacity=cap, tally=tally)
 
     # -------------------------------------------------- throughput gauge
     # sites/sec over cumulative wall time since the first batch — the same
@@ -690,7 +691,8 @@ class ImageAnalysisRunner(Step):
         batch = self._effective_batch(batch)
         if batch["args"].get("layout", "sites") == "spatial":
             return self._prefetch_spatial(batch)
-        return self._load_inputs(batch)
+        with telemetry.span("load"):
+            return self._load_inputs(batch)
 
     def launch_batch(self, batch: dict, prefetched=None):
         """Async device dispatch; returns ``(effective_batch, ctx)`` with
@@ -711,9 +713,11 @@ class ImageAnalysisRunner(Step):
             meta["predicted_shard_work"] = [
                 float(w) for w in plan["shard_work"]
             ]
+        # meta doubles as the batch's tally of bytes sent to the device
         return batch, (
             "sites",
-            (self._launch(batch, prefetched, capacity=cap), cap, meta),
+            (self._launch(batch, prefetched, capacity=cap, tally=meta),
+             cap, meta),
         )
 
     def block_batch(self, ctx) -> None:
@@ -748,7 +752,7 @@ class ImageAnalysisRunner(Step):
             return self._persist_spatial(batch, payload)
         result, cap = payload[0], payload[1]
         meta = payload[2] if len(payload) > 2 else None
-        out = self._persist(batch, result, capacity=cap)
+        out = self._persist(batch, result, capacity=cap, tally=meta)
         if meta and meta.get("device_times"):
             # ride the batch summary so the ledger's batch_done record (and
             # registry_from_ledger) carry device provenance; the ledger
@@ -1280,10 +1284,12 @@ class ImageAnalysisRunner(Step):
 
     def _launch(
         self, batch: dict, inputs: dict | None = None,
-        capacity: int | None = None,
+        capacity: int | None = None, tally: dict | None = None,
     ):
         """Transfer the (possibly prefetched) inputs and dispatch the
-        device computation; returns without waiting for completion."""
+        device computation; returns without waiting for completion.
+        ``tally["h2d_bytes"]`` grows by the host arrays handed to the
+        device (planes, shifts, illumination statistics)."""
         import jax
         import jax.numpy as jnp
 
@@ -1291,7 +1297,8 @@ class ImageAnalysisRunner(Step):
 
         _, fn = self._pipeline(batch["args"], capacity)
         if inputs is None:
-            inputs = self._load_inputs(batch)
+            with telemetry.span("load"):
+                inputs = self._load_inputs(batch)
         padded_sites = inputs["padded_sites"]
         n_dev = inputs["n_dev"]
 
@@ -1299,17 +1306,25 @@ class ImageAnalysisRunner(Step):
         if n_dev > 1:
             sharding = batch_sharding(site_mesh(n_dev))
 
-        raw = {}
-        for name, stack in inputs["raw"].items():
-            arr = jnp.asarray(stack)
-            raw[name] = jax.device_put(arr, sharding) if sharding else arr
-
+        sent = [*inputs["raw"].values(),
+                *(a for pair in inputs["stats"].values() for a in pair)]
         if inputs["shifts_np"] is not None:
-            shifts = jnp.asarray(inputs["shifts_np"])
-        else:
-            shifts = jnp.zeros((len(padded_sites), 2), jnp.int32)
-        if sharding is not None:
-            shifts = jax.device_put(shifts, sharding)
+            sent.append(inputs["shifts_np"])
+        nbytes = sum(int(np.asarray(a).nbytes) for a in sent)
+        if tally is not None:
+            tally["h2d_bytes"] = tally.get("h2d_bytes", 0) + nbytes
+        with telemetry.span("upload", bytes=nbytes):
+            raw = {}
+            for name, stack in inputs["raw"].items():
+                arr = jnp.asarray(stack)
+                raw[name] = jax.device_put(arr, sharding) if sharding else arr
+
+            if inputs["shifts_np"] is not None:
+                shifts = jnp.asarray(inputs["shifts_np"])
+            else:
+                shifts = jnp.zeros((len(padded_sites), 2), jnp.int32)
+            if sharding is not None:
+                shifts = jax.device_put(shifts, sharding)
 
         self._note_speculation_ctx(
             batch["args"], capacity, (raw, inputs["stats"], shifts)
@@ -1439,8 +1454,14 @@ class ImageAnalysisRunner(Step):
         except Exception:
             logger.debug("compile-ahead speculation failed", exc_info=True)
 
-    def _persist(self, batch: dict, result, capacity: int | None = None) -> dict:
-        """Fetch one launched batch's device results and write them out."""
+    def _persist(self, batch: dict, result, capacity: int | None = None,
+                 tally: dict | None = None) -> dict:
+        """Fetch one launched batch's device results and write them out.
+        ``tally`` carries the bytes the launches sent to the device; the
+        re-launches of the escalation loop add theirs."""
+        import jax
+
+        tally = {} if tally is None else tally
         # QC-on programs return (SiteResult, fused per-site image stats);
         # split the pair here so the persist path below is shape-agnostic
         qc_dev = None
@@ -1484,15 +1505,30 @@ class ImageAnalysisRunner(Step):
                 )
                 escalations += 1
                 cap = new_cap
-                result = self._launch(batch, capacity=cap)
+                # a rung climbed: planes re-read, re-sent, the program
+                # re-launched and waited for, all on this persist worker
+                with telemetry.span("escalate", capacity=cap):
+                    result = self._launch(batch, capacity=cap, tally=tally)
+                    w0 = time.perf_counter()
+                    with telemetry.span("device_wait"):
+                        jax.block_until_ready(result)
+                    tally["device_wait_s"] = (
+                        tally.get("device_wait_s", 0.0)
+                        + time.perf_counter() - w0)
                 if isinstance(result, tuple):
                     result, qc_dev = result
-        counts = {k: np.asarray(v)[:n_valid] for k, v in result.counts.items()}
-        objects = {k: np.asarray(v)[:n_valid] for k, v in result.objects.items()}
-        measurements = {
-            obj: {f: np.asarray(v)[:n_valid] for f, v in feats.items()}
-            for obj, feats in result.measurements.items()
-        }
+        with telemetry.span("fetch") as fetched:
+            counts = {k: np.asarray(v)[:n_valid]
+                      for k, v in result.counts.items()}
+            objects = {k: np.asarray(v)[:n_valid]
+                       for k, v in result.objects.items()}
+            measurements = {
+                obj: {f: np.asarray(v)[:n_valid] for f, v in feats.items()}
+                for obj, feats in result.measurements.items()
+            }
+            fetched["bytes"] = sum(
+                int(a.nbytes) for a in jax.tree_util.tree_leaves(
+                    (counts, objects, measurements)))
 
         if self._window is not None:
             # cropped intersection frame → site frame: pad labels back with
@@ -1522,32 +1558,36 @@ class ImageAnalysisRunner(Step):
         for name, feats in measurements.items():
             if "Morphology_area" in feats and objects.get(name) is not None \
                     and objects[name].ndim == 3:
-                feats["Morphology_solidity"] = np.stack(
-                    [solidity_host(objects[name][b], max_obj)
-                     for b in range(n_valid)]
-                )
+                with telemetry.span("solidity"):
+                    feats["Morphology_solidity"] = np.stack(
+                        [solidity_host(objects[name][b], max_obj)
+                         for b in range(n_valid)]
+                    )
 
         # ------------------------------------------------------------ persist
-        for name, labels in objects.items():
-            if labels.ndim == 4:  # (B, Z, H, W) volume labels: one stack per z
-                for zp in range(labels.shape[1]):
-                    self.store.write_labels(labels[:, zp], sites, name,
-                                            tpoint=tpoint, zplane=zp)
-            else:
-                self.store.write_labels(labels, sites, name,
-                                        tpoint=tpoint, zplane=zplane)
+        with telemetry.span("write_labels"):
+            for name, labels in objects.items():
+                if labels.ndim == 4:  # (B, Z, H, W) volumes: one stack per z
+                    for zp in range(labels.shape[1]):
+                        self.store.write_labels(labels[:, zp], sites, name,
+                                                tpoint=tpoint, zplane=zp)
+                else:
+                    self.store.write_labels(labels, sites, name,
+                                            tpoint=tpoint, zplane=zplane)
 
         shard = f"batch_{batch['index']:03d}"
         site_meta = self._site_metadata(sites)
         for name in objects:
-            table = self._feature_table(
-                name, counts[name], measurements.get(name, {}), site_meta,
-                args["max_objects"],
-            )
-            self.store.append_features(name, table, shard=shard)
+            with telemetry.span("write_features"):
+                table = self._feature_table(
+                    name, counts[name], measurements.get(name, {}), site_meta,
+                    args["max_objects"],
+                )
+                self.store.append_features(name, table, shard=shard)
             # polygon tracing is 2-D only; volume objects skip it
             if args["as_polygons"] and objects[name].ndim == 3:
-                self._write_polygons(name, objects[name], sites, shard)
+                with telemetry.span("write_polygons"):
+                    self._write_polygons(name, objects[name], sites, shard)
 
         if args.get("figures"):
             # segmentation-overlay artifacts (reference module Figure
@@ -1621,6 +1661,9 @@ class ImageAnalysisRunner(Step):
         summary["slot_occupancy"] = round(slot_occupancy(total_objects, slots), 4)
         if escalations:
             summary["bucket_escalations"] = escalations
+            summary["device_wait_s"] = round(tally["device_wait_s"], 6)
+        # bytes handed to the device: the first launch and every re-launch
+        summary["h2d_bytes"] = int(tally.get("h2d_bytes", 0))
         self._note_bucket(cap, ceiling, total_objects, slots, escalations)
         # object-capacity saturation must be LOUD: clip_label_count silently
         # zeroes labels past max_objects, so a site whose count sits AT the
