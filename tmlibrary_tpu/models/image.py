@@ -160,6 +160,18 @@ class IllumstatsContainer:
             n=self.n,
         )
 
+    def closest_percentile(self, q: float) -> float | None:
+        """The stored percentile whose key is nearest to ``q`` (reference
+        ``IllumstatsContainer.get_closest_percentile``), accepted only
+        within 1e-4 — what float32 rounding needs: stores written before
+        the keys were float64 hold float32 keys (99.9 reads back as
+        99.90000152...), and 99.0 must never answer for 99.9.  ``None``
+        when corilla computed no such percentile."""
+        key = min(self.percentiles, key=lambda k: abs(k - q), default=None)
+        if key is None or abs(key - q) > 1e-4:
+            return None
+        return self.percentiles[key]
+
     @classmethod
     def from_store(cls, d: dict[str, Any]) -> "IllumstatsContainer":
         pct_keys = d.get("percentile_keys")

@@ -11,6 +11,8 @@ over the site axis.  Static shapes only: crops/windows take Python-int sizes.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -133,27 +135,38 @@ def join_grid(tiles: jax.Array, grid_rows: int, grid_cols: int) -> jax.Array:
     )
 
 
+@functools.partial(jax.jit, static_argnames=("apply_shift", "window"))
+@named("prep")
+def prep(stack, shifts, mean_log=None, std_log=None, *,
+         apply_shift: bool = False,
+         window: tuple[int, int, int, int] | None = None):
+    """The ONE jitted, vmapped site-preprocessing program: illumination
+    correction when the statistic planes are given, per-site shift and
+    intersection crop when asked for.  The planes are arguments, so every
+    channel, plate and submit of a process runs the same executable; only
+    what changes the program's shape is static (planes given or not,
+    ``apply_shift``, ``window``)."""
+
+    def one(img, shift):
+        out = jnp.asarray(img, jnp.float32)
+        if mean_log is not None:
+            out = correct_illumination(out, mean_log, std_log)
+        if apply_shift:
+            out = align(out, shift[0], shift[1], window)
+        return out
+
+    return jax.vmap(one)(stack, shifts)
+
+
 def make_batch_prep(stats=None, apply_shift: bool = False,
                     window: tuple[int, int, int, int] | None = None):
-    """One jitted, vmapped site-preprocessing function: optional
-    illumination correction (corilla ``stats`` container), optional
-    per-site shift, optional intersection crop.
-
-    The single implementation behind the illuminati mosaic prep and the
-    image exporter (jterator's multi-channel preprocess composes the same
-    ops per channel inside its fused program)."""
-    import jax
-
-    @named("prep")
-    def prep(stack, shifts):
-        def one(img, shift):
-            out = jnp.asarray(img, jnp.float32)
-            if stats is not None:
-                out = correct_illumination(out, stats.mean_log, stats.std_log)
-            if apply_shift:
-                out = align(out, shift[0], shift[1], window)
-            return out
-
-        return jax.vmap(one)(stack, shifts)
-
-    return jax.jit(prep)
+    """``prep`` with a channel's corilla ``stats`` container (or none) and
+    the alignment choices bound: ``fn(stack, shifts)``.  Binding arguments
+    creates no program — the illuminati mosaic prep and the image exporter
+    share ``prep``'s jit cache (jterator's multi-channel preprocess
+    composes the same ops per channel inside its fused program)."""
+    return functools.partial(
+        prep,
+        mean_log=None if stats is None else stats.mean_log,
+        std_log=None if stats is None else stats.std_log,
+        apply_shift=apply_shift, window=window)

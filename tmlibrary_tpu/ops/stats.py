@@ -19,6 +19,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from tmlibrary_tpu.ops import named
@@ -142,7 +143,9 @@ def welford_finalize(
         "std_log": jnp.sqrt(jnp.maximum(var, 0.0)),
         "var_log": var,
         "n": state.n,
-        "percentile_keys": jnp.asarray(percentile_qs, jnp.float32),
+        # float64 from the Python tuple: 99.9 is stored as 99.9 (a jnp
+        # array would round it to float32 and read back as 99.90000152)
+        "percentile_keys": np.asarray(percentile_qs, np.float64),
         "percentile_values": jnp.clip(values, 0, HIST_BINS - 1),
         "hist": state.hist,
     }

@@ -73,12 +73,13 @@ class PyramidBuilder(Step):
                     self.store.read_illumstats(cycle=cycle, channel=channel)
                 )
 
-        # display range from corilla percentiles (reference: scale step)
-        if stats is not None and stats.percentiles:
-            upper = stats.percentiles.get(args["clip_percent"])
-            lower = stats.percentiles.get(0.1, 0.0)
-        else:
-            upper = lower = None
+        # display range from corilla's exact raw-intensity percentiles
+        # (reference: scale step); both bounds or neither
+        lower = upper = None
+        if stats is not None:
+            lower = stats.closest_percentile(0.1)
+            upper = stats.closest_percentile(args["clip_percent"])
+        from_corilla = lower is not None and upper is not None
 
         prep = image_ops.make_batch_prep(stats, apply_shift=args["align"])
 
@@ -104,7 +105,7 @@ class PyramidBuilder(Step):
             idx = [self.store.site_linear_index(r) for r, _, _ in part]
             with telemetry.span("read"):
                 stack = self.store.read_sites(idx, cycle=cycle, channel=channel)
-            # upload, the (re-traced) program, and the fetch of its result
+            # upload, the one `prep` program, and the fetch of its result
             with telemetry.span("prep", bytes=stack.nbytes):
                 prepped = np.asarray(
                     prep(jnp.asarray(stack), jnp.asarray(shifts_table[idx]))
@@ -115,10 +116,11 @@ class PyramidBuilder(Step):
                     x0 = (w.column * spw_x + s.x) * W
                     mosaic[y0 : y0 + H, x0 : x0 + W] = img
 
-        if upper is None:
-            # one call partitions both quantiles in a single pass over the
-            # plate mosaic (two separate np.percentile calls measured ~2x
-            # the cost in the workflow bench profile)
+        if not from_corilla:
+            # corilla stored no such percentile (a `clip_percent` outside
+            # its five) or no statistics were read (`correct` false): select
+            # from this submit's mosaic.  One call partitions both quantiles
+            # in a single pass (two np.percentile calls measured ~2x the cost)
             with telemetry.span("percentile"):
                 lo_up = np.percentile(mosaic, [0.1, args["clip_percent"]])
             lower, upper = float(lo_up[0]), float(lo_up[1])
@@ -188,6 +190,9 @@ class PyramidBuilder(Step):
             "mosaic_shape": list(mosaic.shape),
             "n_levels": len(levels),
             "n_tiles": n_tiles,
+            "display_range": "corilla" if from_corilla else "mosaic",
+            "display_lower": float(lower),
+            "display_upper": float(upper),
         }
 
     def collect(self) -> dict:
