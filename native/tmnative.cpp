@@ -1536,6 +1536,12 @@ int32_t tm_site_glcm(const int32_t* labels, const float* img,
       const float b = a * static_cast<float>(levels - 1);
       const float c = b / span;
       float f = std::floor(c);
+      // quantize_per_object holds the bin to floor's definition,
+      // f*span <= b < (f+1)*span (the TPU's division can land one ulp
+      // under a whole quotient); the same two f32 products here
+      const float up = (f + 1.0f) * span;
+      const float down = f * span;
+      f = f + (up <= b ? 1.0f : 0.0f) - (down > b ? 1.0f : 0.0f);
       if (f < 0.0f) f = 0.0f;
       if (f > static_cast<float>(levels - 1))
         f = static_cast<float>(levels - 1);

@@ -4,7 +4,7 @@ chip: one 64x64 unit of the ``cp3-plate`` configuration (config 3, five
 channels on disk) through ``tmx create`` + ``tmx workflow submit`` under a
 profiler trace, after a warm-up unit.
 
-    chiprun -- python scripts/record_stage_trace.py chiprun_out/stages
+    chiprun -- python scripts/record_stage_trace.py chiprun_out/stages [config]
 
 writes ``tiny_stages_tpu_v5e.xplane.pb`` (the device plane's ``XLA Ops``
 and ``XLA Modules`` lines and the host's ``python`` lines, which hold the
@@ -158,12 +158,17 @@ def slim_trace(src: str, dst: str) -> None:
 
 
 def main(argv=None) -> int:
-    out_dir = os.path.abspath((argv or sys.argv[1:] or ["chiprun_out/stages"])[0])
+    argv = list(argv or sys.argv[1:] or ["chiprun_out/stages"])
+    out_dir = os.path.abspath(argv[0])
+    # a second argument names another plate configuration (``cp4-plate``,
+    # PR 27): its files carry the name, ``tiny_cp4-plate_stages_…``
+    name = argv[1] if len(argv) > 1 else "cp3-plate"
+    tag = "" if name == "cp3-plate" else name + "_"
     os.makedirs(out_dir, exist_ok=True)
     from benchmark import harness, ledger, plate
 
     harness.prepare_environment()
-    config = harness.load_json(harness.HERE, "configs", "cp3-plate.json")
+    config = harness.load_json(harness.HERE, "configs", name + ".json")
     device = harness.device_record()
     work = tempfile.mkdtemp(prefix="tmstages_")
     try:
@@ -178,11 +183,11 @@ def main(argv=None) -> int:
         unit = submit(work, 1, src, sites, config, CAPACITY)
         tracer.stop()
         slim_trace(tracer.file(), os.path.join(
-            out_dir, "tiny_stages_tpu_v5e.xplane.pb"))
+            out_dir, f"tiny_{tag}stages_tpu_v5e.xplane.pb"))
         shutil.copy(os.path.join(unit.root, "workflow", "ledger.jsonl"),
-                    os.path.join(out_dir, "stages_run_ledger.jsonl"))
+                    os.path.join(out_dir, f"{tag}stages_run_ledger.jsonl"))
         events = ledger.run_ledger(unit.root)
-        with open(os.path.join(out_dir, "stages_unit.json"), "w") as f:
+        with open(os.path.join(out_dir, f"{tag}stages_unit.json"), "w") as f:
             json.dump({"device": device, "sites": sites, "t0": unit.t0,
                        "t1": unit.t1, "anchor_wall": tracer.anchor_wall,
                        "field_size": SIZE, "capacity": CAPACITY,

@@ -216,6 +216,9 @@ def _program_case(config):
         return benchmarks.smooth_threshold_description(), ("DAPI",)
     if config == "3":
         return benchmarks.cell_painting_description(), ("DAPI", "Actin")
+    if config == "4x5":  # as the cp4-plate cell runs it: all five stains
+        return (benchmarks.full_feature_description(),
+                benchmarks.FULL_STACK_CHANNELS)
     channels = benchmarks.FULL_STACK_CHANNELS[:3]
     return benchmarks.full_feature_description(channels=channels), channels
 
@@ -227,6 +230,9 @@ def _program_case(config):
     # the smoke's workflow phase: one acquisition-geometry field per batch
     # (what the engine's batch resolver gives a 2160x2160 site on device)
     ("3", SMOKE_FIELD, 1, SMOKE_CAPACITY),
+    # the cp4-plate cell's top rung (PR 27): 34 s, 172 MB of code and
+    # 1.2 GB of temporaries here; a rung over 5 minutes is a fault
+    ("4x5", SMOKE_FIELD, 1, SMOKE_CAPACITY),
 ])
 def test_whole_site_program_compiles_for_v5e(config, size, batch, capacity,
                                              on_tpu):
@@ -236,13 +242,22 @@ def test_whole_site_program_compiles_for_v5e(config, size, batch, capacity,
     desc, channels = _program_case(config)
     fn = ImageAnalysisPipeline(desc, max_objects=capacity).build_batch_fn()
     raw = {c: S((batch, size, size), jnp.float32) for c in channels}
+    t0 = time.perf_counter()
     compiled = fn.lower(raw, {}, S((batch, 2), jnp.int32)).compile()
+    seconds = time.perf_counter() - t0
     mem = compiled.memory_analysis()
     resident = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes)
     print(f"config {config} {size}x{size} batch {batch} capacity {capacity}: "
-          f"args {mem.argument_size_in_bytes / 1e6:.0f} MB, out "
+          f"compiled in {seconds:.1f} s, args "
+          f"{mem.argument_size_in_bytes / 1e6:.0f} MB, out "
           f"{mem.output_size_in_bytes / 1e6:.0f} MB, temp "
-          f"{mem.temp_size_in_bytes / 1e6:.0f} MB")
+          f"{mem.temp_size_in_bytes / 1e6:.0f} MB, code "
+          f"{mem.generated_code_size_in_bytes / 1e6:.0f} MB")
     # one program next to a pipelined window of its own inputs/outputs
     assert resident < 8e9, f"{resident / 1e9:.1f} GB of a 16 GB chip"
+    assert seconds < 300, f"{seconds:.0f} s to compile one rung"
+    # no hidden CPU: the measure families' host routes (zernike's "host",
+    # GLCM's and the reductions' "native") are pure_callbacks, and none
+    # may be in a program built for the chip
+    assert "callback" not in compiled.as_text()

@@ -8,6 +8,7 @@ from tmlibrary_tpu.ops.measure import (
     haralick_features,
     intensity_features,
     morphology_features,
+    quantize_per_object,
     zernike_features,
 )
 
@@ -616,3 +617,74 @@ def test_zernike_host_features_matches_fg_twin():
         got = zernike_host_features(labels, 3, degree=6, row_block=block)
         want = _zernike_host(labels, 3, 6)
         np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
+
+
+# ------------------------------------------- a full field's far corner (PR 27)
+def _far_corner_objects(size=2160, n=6):
+    """Ellipses of nucleus size at y, x ~ 2,000-2,140: where float32 field
+    coordinates are coarsest (half an ulp of 1.2e-4 px)."""
+    rng = np.random.default_rng(27)
+    lab = np.zeros((size, size), np.int32)
+    yy, xx = np.mgrid[0:size, 0:size]
+    for i in range(n):
+        y = 2000 + 24 * (i // 3) + rng.uniform(-2, 2)
+        x = 2000 + 40 * (i % 3) + rng.uniform(-2, 2)
+        a, b = rng.uniform(4.0, 6.5), rng.uniform(3.0, 4.5)
+        sel = ((yy[1980:2160, 1980:2160] - y) / a) ** 2 \
+            + ((xx[1980:2160, 1980:2160] - x) / b) ** 2 <= 1.0
+        lab[1980:2160, 1980:2160][sel] = i + 1
+    return lab, n
+
+
+def test_zernike_holds_at_the_far_corner_of_a_full_field():
+    """The projection is on offsets from the centroid: with a float32
+    centroid at ~2,000 they were good to 1.2e-4 px, and ``Zernike_6_0``
+    of a 5-px nucleus moved by 3e-4 on the chip (PERF.md, PR 27)."""
+    lab, n = _far_corner_objects()
+    got = zernike_features(jnp.asarray(lab), 8, degree=6, method="xla")
+    for obj in range(1, n + 1):
+        want = _zernike_reference_numpy(lab == obj, 6)
+        for key, value in want.items():
+            assert float(got[key][obj - 1]) == pytest.approx(
+                value, rel=1e-4, abs=2e-5), (obj, key)
+
+
+def test_second_moments_hold_at_the_far_corner_of_a_full_field():
+    """As ``E[y^2] - cy^2`` in field coordinates the central moments
+    cancel in float32 (both terms ~4e6, one ulp 0.5, a nucleus's
+    variance ~4): the axes were off by per cents at 2160x2160."""
+    lab, n = _far_corner_objects()
+    got = morphology_features(jnp.asarray(lab), 8)
+    for obj in range(1, n + 1):
+        ys, xs = np.nonzero(lab == obj)
+        dy, dx = ys - ys.mean(), xs - xs.mean()
+        myy, mxx = (dy * dy).mean() + 1 / 12, (dx * dx).mean() + 1 / 12
+        myx = (dy * dx).mean()
+        common = np.sqrt((myy - mxx) ** 2 + 4 * myx ** 2)
+        l1, l2 = (myy + mxx + common) / 2, (myy + mxx - common) / 2
+        assert float(got["Morphology_major_axis_length"][obj - 1]) \
+            == pytest.approx(4 * np.sqrt(l1), rel=1e-5)
+        assert float(got["Morphology_minor_axis_length"][obj - 1]) \
+            == pytest.approx(4 * np.sqrt(l2), rel=1e-5)
+        assert float(got["Morphology_eccentricity"][obj - 1]) ** 2 \
+            == pytest.approx(1 - l2 / l1, abs=1e-5)
+        assert float(got["Morphology_centroid_y"][obj - 1]) \
+            == pytest.approx(ys.mean(), abs=2e-4)
+        assert float(got["Morphology_centroid_x"][obj - 1]) \
+            == pytest.approx(xs.mean(), abs=2e-4)
+
+
+def test_stretch_is_floor_in_integer_arithmetic_on_integer_pixels(rng):
+    """``floor((v - min)(L - 1) / (max - min))`` held to its definition:
+    on uint16-valued pixels every bin equals the integer quotient, also
+    where a division lands one ulp under a whole number."""
+    lab = rng.integers(0, 9, (64, 64)).astype(np.int32)
+    img = rng.integers(200, 5000, (64, 64)).astype(np.float32)
+    got = np.asarray(quantize_per_object(jnp.asarray(lab), jnp.asarray(img),
+                                         8, 16))
+    vi = img.astype(np.int64)
+    for obj in range(1, 9):
+        sel = lab == obj
+        lo, hi = vi[sel].min(), vi[sel].max()
+        np.testing.assert_array_equal(
+            got[sel], (vi[sel] - lo) * 15 // (hi - lo))

@@ -569,6 +569,60 @@ def _quantiles_from_counts(counts, lo, span, present, qs, bins):
 
 
 # ----------------------------------------------------------------- morphology
+def _floor_div(num: jax.Array, den: jax.Array) -> jax.Array:
+    """``floor(num / den)`` held to floor's definition, ``q * den <= num
+    < (q + 1) * den`` (``den`` > 0).  The TPU divides by an approximate
+    reciprocal, so a quotient that is a whole number can come out one ulp
+    under it and its floor one too low; both products are exact in f32
+    while they stay below 2^24, so on integers this is the integer
+    quotient.  Where the division is IEEE's it changes nothing."""
+    q = jnp.floor(num / den)
+    return q + ((q + 1.0) * den <= num) - (q * den > num)
+
+
+def _centred_coordinates(
+    labels: jax.Array,
+    yy: jax.Array,
+    xx: jax.Array,
+    area: jax.Array,
+    sum_y: jax.Array,
+    sum_x: jax.Array,
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """``(cy, cx, dy, dx)``: the per-object centroids and every pixel's
+    offset from its own object's centroid.
+
+    A float32 centroid at field coordinates (~2,000) is good to half an
+    ulp, 1.2e-4 px, and so is every ``y - cy``.  That is nothing to a
+    centroid but not to what is built on the offsets: a nucleus's unit
+    disk has a radius of ~5 px, so the radial coordinate is off by 2e-5,
+    and ``R_60`` (slope 24 at the rim) moved ``Zernike_6_0`` by 3e-4 on
+    the chip at 2160x2160.  ``sum_y`` and ``area`` are exact integers
+    (below 2^24), so the centroid is split into its whole part and a
+    fraction (:func:`_floor_div`) and a pixel's offset is the exact
+    small integer ``y - whole`` less the fraction: good to 1e-7 of
+    itself.  One label look-up carries all four columns."""
+    safe_a = jnp.maximum(area, 1.0)
+
+    def split(total):
+        whole = _floor_div(total, safe_a)
+        return whole, (total - whole * safe_a) / safe_a
+
+    with jax.named_scope("centred_coordinates"):
+        wy, fy = split(sum_y)
+        wx, fx = split(sum_x)
+        zero1 = jnp.zeros((1,), jnp.float32)
+        pix = lookup_by_label(
+            labels,
+            jnp.stack(
+                [jnp.concatenate([zero1, c]) for c in (wy, fy, wx, fx)],
+                axis=-1,
+            ),
+        )
+        dy = (yy - pix[..., 0]) - pix[..., 1]
+        dx = (xx - pix[..., 2]) - pix[..., 3]
+    return wy + fy, wx + fx, dy, dx
+
+
 @named("morphology")
 def morphology_features(labels: jax.Array, max_objects: int) -> dict[str, jax.Array]:
     """Reference feature set of ``jtlib/features/morphology.py``
@@ -591,11 +645,9 @@ def morphology_features(labels: jax.Array, max_objects: int) -> dict[str, jax.Ar
         boundary = boundary | (shift_with_fill(labels, dy, dx, 0) != labels)
     boundary = boundary & (labels > 0)
 
-    chans = [
-        ones, yy, xx, yy * yy, xx * xx, yy * xx, boundary.astype(jnp.float32)
-    ]
+    chans = [ones, yy, xx, boundary.astype(jnp.float32)]
     if resolve_reduction_strategy() == "fused":
-        # all 7 per-object sums AND the bounding box from ONE megakernel
+        # the per-object sums AND the bounding box from ONE megakernel
         # pass — the min/max of the yy/xx channels ride the same shared
         # one-hot as the sums (the unfused path below is two passes)
         from tmlibrary_tpu.ops.fused_measure import grouped_stats
@@ -609,9 +661,7 @@ def morphology_features(labels: jax.Array, max_objects: int) -> dict[str, jax.Ar
         mins, maxs = grouped_minmax_multi(labels, [yy, xx], max_objects)
     area = sums[:, 0]
     safe_a = jnp.maximum(area, 1.0)
-    cy = sums[:, 1] / safe_a
-    cx = sums[:, 2] / safe_a
-    perimeter = sums[:, 6]
+    perimeter = sums[:, 3]
 
     y_min, x_min = mins[:, 0], mins[:, 1]
     y_max, x_max = maxs[:, 0], maxs[:, 1]
@@ -620,10 +670,22 @@ def morphology_features(labels: jax.Array, max_objects: int) -> dict[str, jax.Ar
     bbox_w = jnp.where(present, x_max - x_min + 1.0, 0.0)
     extent = area / jnp.maximum(bbox_h * bbox_w, 1.0)
 
-    # central second moments -> ellipse fit (CellProfiler/regionprops math)
-    mu_yy = sums[:, 3] / safe_a - cy * cy
-    mu_xx = sums[:, 4] / safe_a - cx * cx
-    mu_yx = sums[:, 5] / safe_a - cy * cx
+    # central second moments -> ellipse fit (CellProfiler/regionprops
+    # math), summed about each pixel's own object's centroid.  As
+    # E[y^2] - cy^2 in field coordinates they cancel in float32: at
+    # y ~ 2000 both terms are ~4e6, one ulp is 0.5, and a nucleus's
+    # variance is ~4 (axes off by several per cent at the far corner of
+    # a 2160x2160 field; invisible at 96x96).
+    with jax.named_scope("central_moments"):
+        cy, cx, dy, dx = _centred_coordinates(
+            labels, yy, xx, area, sums[:, 1], sums[:, 2]
+        )
+        central = grouped_sums(
+            labels, [dy * dy, dx * dx, dy * dx], max_objects
+        )
+    mu_yy = central[:, 0] / safe_a
+    mu_xx = central[:, 1] / safe_a
+    mu_yx = central[:, 2] / safe_a
     # regionprops adds 1/12 (pixel as unit square) to the diagonal
     mu_yy = mu_yy + 1.0 / 12.0
     mu_xx = mu_xx + 1.0 / 12.0
@@ -836,7 +898,12 @@ def quantize_per_object(
     per_pix = lookup_by_label(labels, jnp.stack([lo_full, span_full], axis=-1))
     lo_pix = per_pix[..., 0]
     span_pix = jnp.maximum(per_pix[..., 1], 1e-6)
-    q = jnp.floor((img - lo_pix) * (levels - 1) / span_pix)
+    # a plain TPU division put 6 object pixels in 10,000 a bin too low at
+    # 2160x2160 (PERF.md, PR 27), and one such pixel moves an object's 13
+    # Haralick features by parts in a thousand; on integer pixels this is
+    # floor((v-min)(L-1)/(max-min)) in integer arithmetic
+    with jax.named_scope("stretch_exact"):
+        q = _floor_div((img - lo_pix) * (levels - 1), span_pix)
     return jnp.clip(q, 0, levels - 1).astype(jnp.int32)
 
 
@@ -940,6 +1007,13 @@ def haralick_features(
         else:
             raise ValueError(f"unknown glcm method '{method}'")
 
+    lv = np.arange(levels)
+    sum_sel = jnp.asarray(
+        (lv[:, None] + lv[None, :]).reshape(-1, 1)
+        == np.arange(2 * levels - 1), jnp.float32)  # (L*L, 2L-1)
+    diff_sel = jnp.asarray(
+        np.abs(lv[:, None] - lv[None, :]).reshape(-1, 1) == lv,
+        jnp.float32)  # (L*L, L)
     acc: dict[str, jax.Array] = {}
     for glcm in glcms:
         total = jnp.maximum(glcm.sum(axis=(1, 2), keepdims=True), eps)
@@ -961,16 +1035,15 @@ def haralick_features(
         entropy = -(p * jnp.log(p + eps)).sum(axis=(1, 2))
 
         # p_{x+y}(k), k = i+j in [0, 2L-2]; p_{x-y}(k), k = |i-j| in [0, L-1]
+        # as contractions with constant 0/1 matrices: a segment_sum under
+        # vmap is a scatter-add of capacity*L*L updates a direction, and
+        # scatter-adds serialise on a TPU
         k_sum = jnp.arange(2 * levels - 1, dtype=jnp.float32)
-        sum_idx = (jnp.arange(levels)[:, None] + jnp.arange(levels)[None, :]).reshape(-1)
         p_flat = p.reshape(max_objects, -1)
-        p_sum = jax.vmap(
-            lambda row: jax.ops.segment_sum(row, sum_idx, num_segments=2 * levels - 1)
-        )(p_flat)
-        diff_idx = jnp.abs(jnp.arange(levels)[:, None] - jnp.arange(levels)[None, :]).reshape(-1)
-        p_diff = jax.vmap(
-            lambda row: jax.ops.segment_sum(row, diff_idx, num_segments=levels)
-        )(p_flat)
+        p_sum = jnp.einsum("mc,ck->mk", p_flat, sum_sel,
+                           precision=jax.lax.Precision.HIGHEST)
+        p_diff = jnp.einsum("mc,ck->mk", p_flat, diff_sel,
+                            precision=jax.lax.Precision.HIGHEST)
 
         sum_avg = (p_sum * k_sum).sum(axis=1)
         sum_entropy = -(p_sum * jnp.log(p_sum + eps)).sum(axis=1)
@@ -1244,22 +1317,12 @@ def zernike_features(
     )
     ones = jnp.ones((h, w), jnp.float32)
     sums = grouped_sums(labels, [ones, yy, xx], max_objects)
-    area, sy, sx = sums[:, 0], sums[:, 1], sums[:, 2]
+    area = sums[:, 0]
     safe_a = jnp.maximum(area, 1.0)
-    cy = sy / safe_a
-    cx = sx / safe_a
-
-    # per-pixel centroid of the pixel's own object (label lookup)
-    zero1 = jnp.zeros((1,), jnp.float32)
-    cen_pix = lookup_by_label(
-        labels,
-        jnp.stack(
-            [jnp.concatenate([zero1, cy]), jnp.concatenate([zero1, cx])],
-            axis=-1,
-        ),
+    # per-pixel offset from the centroid of the pixel's own object
+    _, _, dy, dx = _centred_coordinates(
+        labels, yy, xx, area, sums[:, 1], sums[:, 2]
     )
-    dy = yy - cen_pix[..., 0]
-    dx = xx - cen_pix[..., 1]
     r2 = dy * dy + dx * dx
     _, r2_max = grouped_minmax(labels, r2, max_objects)
     r_obj = jnp.sqrt(jnp.maximum(jnp.where(area > 0, r2_max, 1.0), 1.0))

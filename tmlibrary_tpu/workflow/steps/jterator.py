@@ -1578,12 +1578,16 @@ class ImageAnalysisRunner(Step):
         shard = f"batch_{batch['index']:03d}"
         site_meta = self._site_metadata(sites)
         for name in objects:
-            with telemetry.span("write_features"):
+            with telemetry.span("write_features") as written:
                 table = self._feature_table(
                     name, counts[name], measurements.get(name, {}), site_meta,
                     args["max_objects"],
                 )
                 self.store.append_features(name, table, shard=shard)
+                # object rows and feature columns (the seven site and
+                # label keys left out): what this shard holds
+                written["rows"] = len(table)
+                written["columns"] = len(measurements.get(name, {}))
             # polygon tracing is 2-D only; volume objects skip it
             if args["as_polygons"] and objects[name].ndim == 3:
                 with telemetry.span("write_polygons"):
