@@ -205,6 +205,193 @@ def test_saturated_bucket_escalates_then_matches(source_dir, store):
     )
 
 
+# -------------------------------------- routing by demand (the jump)
+def _handle(name, kind, **rest):
+    return {"name": name, "type": kind, **rest}
+
+
+def _pipe(segmenter_modules, correct=True):
+    """``PIPE_YAML`` with another segmentation: the modules given, which
+    leave their labels under the key ``nuclei``, then intensity."""
+    from test_workflow import PIPE_YAML
+
+    return {
+        **PIPE_YAML,
+        "input": {"channels": [
+            {"name": "DAPI", "correct": correct, "align": False}]},
+        "pipeline": [{"handles": m} for m in segmenter_modules]
+        + [PIPE_YAML["pipeline"][-1]],
+    }
+
+
+#: smooth, Otsu, label, area filter: the filter clips at the capacity and
+#: reports no demand, so the router sees "at least cap" and nothing more
+FALLBACK_PIPE = _pipe([
+    {"module": "smooth",
+     "input": [_handle("intensity_image", "IntensityImage", key="DAPI"),
+               _handle("sigma", "Numeric", value=1.5)],
+     "output": [_handle("smoothed_image", "IntensityImage", key="sm")]},
+    {"module": "threshold_otsu",
+     "input": [_handle("intensity_image", "IntensityImage", key="sm")],
+     "output": [_handle("mask", "BinaryImage", key="mask")]},
+    {"module": "label",
+     "input": [_handle("mask", "BinaryImage", key="mask")],
+     "output": [_handle("label_image", "LabelImage", key="raw")]},
+    {"module": "filter",
+     "input": [_handle("label_image", "LabelImage", key="raw"),
+               _handle("feature", "Character", value="area"),
+               _handle("lower_threshold", "Numeric", value=10)],
+     "output": [_handle("filtered_label_image", "SegmentedObjects",
+                        key="nuclei", objects="nuclei")]},
+])
+
+
+def _jterator_runs(source_dir, store, pipe, spec, batch_size, depth=2):
+    """The jterator step with ``object_buckets=off`` and then with
+    ``spec``, on one store: ``(reference labels, reference rows, the
+    bucketed run's batch summaries)``; the store holds the bucketed
+    run's outputs on return."""
+    import yaml
+
+    desc = make_description(source_dir, store)
+    if pipe is not None:
+        (store.root / "nuclei.pipe.yaml").write_text(yaml.safe_dump(pipe))
+    _run_prep_steps(desc, store)
+    jd = next(s for stage in desc.stages for s in stage.steps
+              if s.name == "jterator")
+    args = {**jd.args, "batch_size": batch_size, "object_buckets": "off"}
+    jt = get_step("jterator")(store)
+    jt.init(args)
+    for j in jt.list_batches():
+        jt.run(j)
+    ref_labels = store.read_labels(None, "nuclei").copy()
+    ref_feats = _read_features_sorted(store, "nuclei")
+
+    jt2 = get_step("jterator")(store)
+    jt2.delete_previous_output()
+    jt2.init({**args, "object_buckets": spec})
+    batches = [jt2.load_batch(i) for i in jt2.list_batches()]
+    out = [r for _, r in PipelinedExecutor(jt2, depth=depth).run(batches)]
+    return ref_labels, ref_feats, out
+
+
+@pytest.mark.parametrize("pipe,relaunches,skipped", [
+    pytest.param(None, 1, 1, id="segmenter-reports-jump"),
+    pytest.param(FALLBACK_PIPE, 2, 0, id="nobody-reports-climb"),
+])
+def test_relaunch_goes_to_the_rung_the_demand_selects(
+        source_dir, store, pipe, relaunches, skipped):
+    """About 6 objects a site, first rung 2 of the ladder 2, 4, 8, 16, 64.
+    ``segment_primary`` reports the component count it found at rung 2,
+    so ONE re-launch goes to rung 8 and rung 4 is skipped; a pipeline
+    whose clipping module reports nothing reads "at least 2", "at least
+    4", and climbs 2 -> 4 -> 8 as it always has.  Either way what is
+    persisted equals the unbucketed run."""
+    import pandas.testing
+
+    ref_labels, ref_feats, out = _jterator_runs(
+        source_dir, store, pipe, "2,4,8,16", batch_size=8)
+    per_site = ref_feats.groupby("site_index").size()
+    assert len(out) == 2  # both inside the first launch window
+    for i, res in enumerate(out):
+        peak = int(per_site.iloc[8 * i:8 * (i + 1)].max())
+        assert 4 <= peak < 8, "the fixture's sites hold about 6 objects"
+        assert res["bucket_capacity"] == 8
+        assert res["bucket_escalations"] == relaunches
+        assert res.get("bucket_rungs_skipped", 0) == skipped
+        # no debris in these fields: demand is the persisted peak count
+        assert res["bucket_demand"] == peak
+    assert np.array_equal(store.read_labels(None, "nuclei"), ref_labels)
+    pandas.testing.assert_frame_equal(
+        _read_features_sorted(store, "nuclei"), ref_feats
+    )
+
+
+@pytest.fixture
+def debris_source_dir(tmp_path):
+    """One well, four 64x64 fields: three 2x2 specks in the top rows
+    (first in scan order, each under ``min_area``) and two 8x8 nuclei
+    below them.  Five components, two objects."""
+    import cv2
+
+    src = tmp_path / "microscope_debris"
+    src.mkdir()
+    for site in range(4):
+        img = np.full((64, 64), 300, np.uint16)
+        for k in range(3):
+            img[2:4, 6 + 12 * k + site:8 + 12 * k + site] = 5000
+        img[20:28, 10 + site:18 + site] = 5000
+        img[40:48, 30 + site:38 + site] = 5000
+        cv2.imwrite(str(src / f"A01_s{site}_DAPI.png"), img)
+    return src
+
+
+def test_debris_before_the_filter_cannot_pass_for_a_fit(
+        debris_source_dir, store):
+    """Raw count 5 over the first rung 4, filtered count 2 under it: at
+    rung 4 the clip drops the fifth component (a nucleus), the filter
+    drops the three specks, and ONE object survives — below the cap.
+    The count after the filter says the rung held; the demand (5, taken
+    before the clip) says it did not, and the batch is re-launched."""
+    import pandas.testing
+
+    pipe = _pipe([
+        {"module": "segment_primary",
+         "input": [_handle("intensity_image", "IntensityImage", key="DAPI"),
+                   _handle("threshold_method", "Character", value="manual"),
+                   _handle("threshold_value", "Numeric", value=1000),
+                   _handle("smooth_sigma", "Numeric", value=0.0),
+                   _handle("min_area", "Numeric", value=10)],
+         "output": [_handle("objects", "SegmentedObjects", key="nuclei",
+                            objects="nuclei")]},
+    ], correct=False)
+    ref_labels, ref_feats, out = _jterator_runs(
+        debris_source_dir, store, pipe, "4", batch_size=4)
+    assert [int(lab.max()) for lab in ref_labels] == [2, 2, 2, 2]
+    assert np.array_equal(store.read_labels(None, "nuclei"), ref_labels)
+    pandas.testing.assert_frame_equal(
+        _read_features_sorted(store, "nuclei"), ref_feats
+    )
+    (res,) = out
+    assert res["bucket_demand"] == 5
+    assert res["bucket_capacity"] == 64
+    assert res["bucket_escalations"] == 1
+
+
+@pytest.mark.parametrize("declump", [False, True],
+                         ids=["components", "declump-seeds"])
+def test_demand_is_a_function_of_the_field(rng, declump):
+    """The same site at capacities 2, 8 and 64 reports the same demand,
+    whatever the capacity did to its labels and counts."""
+    import copy
+
+    import jax.numpy as jnp
+    from test_workflow import PIPE_YAML
+
+    from tmlibrary_tpu.jterator.description import PipelineDescription
+    from tmlibrary_tpu.jterator.pipeline import ImageAnalysisPipeline
+
+    pipe = copy.deepcopy(PIPE_YAML)
+    pipe["input"]["channels"][0]["correct"] = False
+    pipe["pipeline"][1]["handles"]["input"].append(
+        _handle("declump", "Boolean", value=declump))
+    desc = PipelineDescription.from_dict(pipe)
+    sites = np.stack([synth_site_image(rng) for _ in range(2)])
+    seen = {}
+    for cap in (2, 8, 64):
+        fn = ImageAnalysisPipeline(desc, max_objects=cap).build_batch_fn()
+        res = fn({"DAPI": jnp.asarray(sites)}, {},
+                 jnp.zeros((2, 2), jnp.int32))
+        seen[cap] = (np.asarray(res.demand).tolist(),
+                     np.asarray(res.counts["nuclei"]).tolist())
+    demand, counts = seen[64]
+    assert all(2 < d < 8 for d in demand)
+    assert demand == seen[2][0] == seen[8][0]
+    assert seen[2][1] == [2, 2] and seen[8][1] == counts
+    # the area filter only takes away: demand never under the count
+    assert all(d >= c for d, c in zip(demand, counts))
+
+
 # ----------------------------------------- bit-identity: spatial layout
 def test_spatial_layout_bit_identical_with_buckets(spatial_store,
                                                    monkeypatch):

@@ -12,20 +12,32 @@ capacities (via :func:`tmlibrary_tpu.utils.next_power_of_two`) ending at
 the configured ``max_objects`` ceiling.  The jterator step compiles one
 batch program per bucket it actually needs (the process-level
 ``cached_batch_fn`` cache keys on the capacity) and routes each batch at
-launch time by the object counts observed so far; a batch whose counts
-reach its routed capacity is re-run one bucket up before anything is
-persisted, and only saturation at the *ceiling* falls through to the
-existing auto-resegmentation path.
+launch time by the object counts observed so far.
+
+Routing rule: every launch reports its batch's *demand* — the most
+objects any module of any site saw BEFORE the capacity clipped them
+(``SiteResult.demand``; ``segment_primary`` reports the component count
+of its mask, and the clipped counts join the maximum, so a pipeline
+whose modules report nothing reads "at least the cap" when it
+saturates).  A launch is persisted only if ``demand < capacity``;
+otherwise the batch is re-launched at ``select_capacity(demand,
+ladder)`` — the smallest rung that holds the demand, however many rungs
+that skips; a pipeline that reports nothing thereby climbs one rung at a
+time — before anything is persisted.  Only saturation at the *ceiling*
+falls through to the existing warn / auto-resegmentation path, which
+reads the persisted counts, not the demand.
 
 Bit-identity contract (pinned by ``tests/test_buckets.py``): for a site
-with ``count`` objects, every capacity ``c > count`` produces identical
+whose demand is ``d``, every capacity ``c > d`` produces identical
 labels, counts and measurement rows ``1..count`` — the segmented
 reductions compute each object's row independently, and label ids are
 assigned in scan order regardless of the cap.  Routing is therefore a
-pure performance decision; persisting from a non-saturated run is what
-keeps the contract airtight (``clip_label_count`` only alters results
-once ``count`` hits the capacity, and the router never persists that
-state below the ceiling).
+pure performance decision; persisting only from a launch whose demand
+stayed under its capacity is what keeps the contract airtight
+(``clip_label_count`` only alters results once the count *before the
+clip* reaches the capacity — which a filter after the clip can hide
+from the surviving count, and cannot hide from the demand — and the
+router never persists that state below the ceiling).
 
 Resolution order for the bucket spec (highest first): the step's
 explicit ``object_buckets`` arg when not ``"auto"``, the
@@ -124,10 +136,11 @@ def select_capacity(observed: int, ladder: tuple[int, ...]) -> int:
 def likely_next_rungs(current: int, ladder: tuple[int, ...],
                       observed: "int | None" = None,
                       count: int = 1) -> tuple[int, ...]:
-    """The capacity rungs escalation would reach next from ``current`` —
+    """The capacity rungs a re-launch may reach next from ``current`` —
     the compile-ahead speculation targets (aotstore/perf): warming them
-    during prefetch idle means a saturated batch re-runs one bucket up
-    without paying compile on the critical path.
+    during prefetch idle means a saturated batch that has to climb (a
+    pipeline that reports no demand) re-runs one bucket up without
+    paying compile on the critical path.
 
     When the ``observed`` peak already demands a higher rung than
     ``current`` (routing history from a peer job, or a count recorded

@@ -236,7 +236,7 @@ def segment_primary(
     """Reference ``jtmodules/segment_primary.py`` (nuclei)."""
     from tmlibrary_tpu.ops.segment_primary import segment_primary as _sp
 
-    labels, _count = _sp(
+    labels, _count, demand = _sp(
         intensity_image,
         threshold_method=threshold_method,
         threshold_value=threshold_value,
@@ -250,8 +250,9 @@ def segment_primary(
         declump=declump,
         declump_min_distance=declump_min_distance,
         max_objects=max_objects,
+        return_demand=True,
     )
-    return {"objects": labels}
+    return {"objects": labels, MODULE_DEMAND_KEY: demand}
 
 
 @register_module("segment_secondary")
@@ -725,6 +726,17 @@ def detect_blobs(
 #: the batch program.  QC-off builds ignore the keys, so XLA dead-code
 #: eliminates the stats and the label outputs stay bit-identical.
 MODULE_QC_PREFIX = "__qc__"
+
+#: reserved output key for a module's *demand*: the number of objects it
+#: saw before ``max_objects`` clipped them (an int32 scalar, a function
+#: of the module's inputs and never of the capacity).  Like the QC keys
+#: it is no pipeline handle: ``build_site_fn`` folds the reporting
+#: modules' demands into ``SiteResult.demand``, which the capacity
+#: router reads to pick the rung of a re-launch (``capacity.py``).  A
+#: module that clips and does not report is still safe — its clipped
+#: count reaches the router through ``counts`` — it only makes the
+#: router climb one rung at a time.
+MODULE_DEMAND_KEY = "__demand__"
 
 
 def _qc_sample(values, k: int = 64):

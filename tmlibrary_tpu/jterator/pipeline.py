@@ -26,6 +26,7 @@ that fits.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import Any, Callable
 
@@ -304,10 +305,16 @@ class SiteResult:
     objects: dict[str, jax.Array]  # objects name -> (H, W) int32 labels
     counts: dict[str, jax.Array]  # objects name -> scalar int32
     measurements: dict[str, dict[str, jax.Array]]  # objects -> feature -> (M,)
+    #: scalar int32: the most objects any module saw BEFORE the capacity
+    #: clipped them (``modules.MODULE_DEMAND_KEY``), never below
+    #: ``max(counts)`` — what the capacity router sizes a re-launch by
+    demand: jax.Array
 
 
 jax.tree_util.register_dataclass(
-    SiteResult, data_fields=["objects", "counts", "measurements"], meta_fields=[]
+    SiteResult,
+    data_fields=["objects", "counts", "measurements", "demand"],
+    meta_fields=[],
 )
 
 
@@ -349,6 +356,7 @@ class ImageAnalysisPipeline:
             objects: dict[str, jax.Array] = {}
             measurements: dict[str, dict[str, jax.Array]] = {}
             diagnostics: dict[str, jax.Array] = {}
+            demands: list[jax.Array] = []
 
             for mod in desc.modules:
                 fn = module_registry.get_module(mod.module, mod.backend)
@@ -384,6 +392,10 @@ class ImageAnalysisPipeline:
                     raise PipelineError(
                         f"module '{mod.module}' must return a dict of outputs"
                     )
+                if module_registry.MODULE_DEMAND_KEY in outs:
+                    demands.append(jnp.asarray(
+                        outs[module_registry.MODULE_DEMAND_KEY], jnp.int32
+                    ))
                 if collect_diagnostics:
                     prefix = module_registry.MODULE_QC_PREFIX
                     for k, v in outs.items():
@@ -423,12 +435,19 @@ class ImageAnalysisPipeline:
                 name: jnp.max(lab).astype(jnp.int32) for name, lab in objects.items()
             }
             wanted = {o.name for o in desc.objects_out} or set(objects)
+            # the clipped counts join the maximum, so a pipeline none of
+            # whose modules reports still says "at least the cap" when it
+            # saturates, and the router climbs a rung as it always has
+            demand = functools.reduce(
+                jnp.maximum, demands + list(counts.values()), jnp.int32(0)
+            )
             result = SiteResult(
                 objects={k: v for k, v in objects.items() if k in wanted},
                 counts={k: v for k, v in counts.items() if k in wanted},
                 measurements={
                     k: v for k, v in measurements.items() if k in wanted
                 },
+                demand=demand,
             )
             if collect_diagnostics:
                 return result, diagnostics

@@ -132,8 +132,16 @@ def segment_primary(
     declump: bool = False,
     declump_min_distance: int = 5,
     max_objects: int = 256,
-) -> tuple[jax.Array, jax.Array]:
-    """Segment primary objects; returns (labels, count)."""
+    return_demand: bool = False,
+) -> tuple[jax.Array, ...]:
+    """Segment primary objects; returns (labels, count).
+
+    ``return_demand=True`` appends the *demand*: the number of objects
+    the field held before ``max_objects`` clipped them — the component
+    count of the mask, or with ``declump`` the seed count of the
+    watershed.  It is a function of the mask alone, so it reads the same
+    at every capacity, and it is taken before the area filter: a
+    capacity above it holds every object the filter gets to judge."""
     img = jnp.asarray(intensity_image, jnp.float32)
     if smooth_sigma > 0:
         img = gaussian_smooth(img, smooth_sigma)
@@ -149,7 +157,7 @@ def segment_primary(
         raise ValueError(f"unknown threshold method '{threshold_method}'")
     if fill:
         mask = label_ops.fill_holes(mask)
-    labels, _ = label_ops.connected_components(mask, connectivity=8)
+    labels, demand = label_ops.connected_components(mask, connectivity=8)
     if declump:
         # split touching objects: watershed on the distance transform from
         # its local maxima (CellProfiler shape-based declumping)
@@ -158,6 +166,7 @@ def segment_primary(
             dist, mask, min_distance=declump_min_distance,
             smooth_sigma=declump_min_distance / 2.0,
         )
+        demand = jnp.max(seeds)  # seeds are numbered 1..N
         labels = watershed_from_seeds(dist, seeds, mask)
         # watershed labels carry seed ids (peak scan order); re-rank by
         # each region's first pixel so declumped output keeps the
@@ -171,4 +180,7 @@ def segment_primary(
             labels, max_objects=max_objects, min_area=min_area, max_area=max_area
         )
     count = jnp.max(labels)
-    return labels.astype(jnp.int32), count
+    labels = labels.astype(jnp.int32)
+    if return_demand:
+        return labels, count, demand.astype(jnp.int32)
+    return labels, count

@@ -1386,6 +1386,11 @@ def registry_from_ledger(events: Iterable[dict]) -> MetricsRegistry:
                         reg.counter(
                             "tmx_jterator_bucket_saturated_total"
                         ).inc(esc)
+                    skipped = int(result.get("bucket_rungs_skipped", 0) or 0)
+                    if skipped:
+                        reg.counter(
+                            "tmx_jterator_bucket_rungs_skipped_total"
+                        ).inc(skipped)
                     occ = result.get("slot_occupancy")
                     if occ is not None:
                         occ_acc[0] += float(occ)

@@ -496,9 +496,9 @@ class ImageAnalysisRunner(Step):
         persist worker has recorded so far — the first batch has no
         history, so it starts from the hardware-swept capacity verdict
         (``TUNING.json``) when one is on the ladder, else the ladder's
-        smallest bucket.  A mis-route only costs a re-launch one bucket
-        up (:meth:`_persist` escalates before persisting), never a
-        wrong result."""
+        smallest bucket.  A mis-route only costs a re-launch at the rung
+        the launch's own demand selects (:meth:`_persist` re-launches
+        before persisting), never a wrong result."""
         args = batch["args"]
         ceiling = int(args["max_objects"])
         from tmlibrary_tpu.capacity import resolve_bucket_ladder, select_capacity
@@ -510,8 +510,8 @@ class ImageAnalysisRunner(Step):
             return ceiling
         # a packed batch routes to its PLANNED rung: the whole point of
         # rung-homogeneous packing is that a sparse batch stops paying
-        # for the global peak.  Under-prediction only costs the existing
-        # escalation re-launch (_persist), never a wrong result.
+        # for the global peak.  Under-prediction only costs the
+        # re-launch at the demanded rung (_persist), never a wrong result.
         planned = (batch.get("schedule") or {}).get("rung")
         if planned and int(planned) in ladder:
             return int(planned)
@@ -650,9 +650,9 @@ class ImageAnalysisRunner(Step):
 
     def _note_bucket(
         self, cap: int, ceiling: int, objects: int, slots: int,
-        escalations: int,
+        escalations: int, rungs_skipped: int,
     ) -> None:
-        """Bucket-router telemetry: routed/saturated counters plus the
+        """Bucket-router telemetry: routed/saturated/skipped counters plus the
         run-cumulative slot-occupancy and padded-FLOPs-avoided gauges
         (the per-object measure FLOPs scale with the capacity, so the
         slot ratio routed/ceiling IS the padded-work fraction saved)."""
@@ -664,6 +664,10 @@ class ImageAnalysisRunner(Step):
         ).inc()
         if escalations:
             reg.counter("tmx_jterator_bucket_saturated_total").inc(escalations)
+        if rungs_skipped:
+            reg.counter(
+                "tmx_jterator_bucket_rungs_skipped_total"
+            ).inc(rungs_skipped)
         from tmlibrary_tpu.capacity import ceiling_slots
 
         with self._bucket_lock:
@@ -1454,6 +1458,11 @@ class ImageAnalysisRunner(Step):
         except Exception:
             logger.debug("compile-ahead speculation failed", exc_info=True)
 
+    @staticmethod
+    def _batch_demand(result, n_valid: int) -> int:
+        """Peak :attr:`SiteResult.demand` over a batch's valid sites."""
+        return int(np.asarray(result.demand)[:n_valid].max(initial=0))
+
     def _persist(self, batch: dict, result, capacity: int | None = None,
                  tally: dict | None = None) -> dict:
         """Fetch one launched batch's device results and write them out.
@@ -1473,15 +1482,20 @@ class ImageAnalysisRunner(Step):
         n_valid = len(sites)
         ceiling = int(args["max_objects"])
         cap = int(capacity) if capacity is not None else ceiling
-        escalations = 0
+        escalations = rungs_skipped = 0
+        demand = self._batch_demand(result, n_valid)
         if cap < ceiling:
-            # Escalate until the routed capacity holds the batch.  A
-            # count AT the cap may have been clipped there, so nothing
-            # below the ceiling is ever persisted from a saturated run —
-            # this is the bit-identity contract (capacity.py): below the
-            # ceiling, routing can cost a re-launch one bucket up, never
-            # a different result.  Ceiling saturation keeps its existing
-            # warn/auto-resegment flow below.
+            # Re-launch until the routed capacity holds the batch's
+            # demand.  A demand AT the cap may have been clipped there
+            # (before or after a filter), so nothing below the ceiling is
+            # ever persisted from such a launch — this is the
+            # bit-identity contract (capacity.py): below the ceiling,
+            # routing can cost a re-launch, never a different result.
+            # The re-launch goes to the rung the demand selects, not the
+            # next one; a pipeline whose modules report no demand reads
+            # "at least cap" from its clipped counts and so climbs one
+            # rung at a time.  Ceiling saturation keeps its existing
+            # warn/auto-resegment flow below, which reads the counts.
             from tmlibrary_tpu.capacity import (
                 resolve_bucket_ladder, select_capacity,
             )
@@ -1489,25 +1503,20 @@ class ImageAnalysisRunner(Step):
             ladder = resolve_bucket_ladder(
                 ceiling, args.get("object_buckets", "auto")
             )
-            while cap < ceiling:
-                peak = max(
-                    (int(np.asarray(v)[:n_valid].max(initial=0))
-                     for v in result.counts.values()),
-                    default=0,
-                )
-                if peak < cap:
-                    break
-                new_cap = select_capacity(cap, ladder)
+            while demand >= cap and cap < ceiling:
+                new_cap = select_capacity(demand, ladder)
                 logger.info(
                     "batch %s saturated its routed object-capacity bucket "
-                    "(count hit %d) — re-running at capacity %d",
-                    batch.get("index"), cap, new_cap,
+                    "(demand %d at capacity %d) — re-running at capacity %d",
+                    batch.get("index"), demand, cap, new_cap,
                 )
                 escalations += 1
-                cap = new_cap
-                # a rung climbed: planes re-read, re-sent, the program
-                # re-launched and waited for, all on this persist worker
-                with telemetry.span("escalate", capacity=cap):
+                rungs_skipped += sum(1 for c in ladder if cap < c < new_cap)
+                from_cap, cap = cap, new_cap
+                # planes re-read, re-sent, the program re-launched and
+                # waited for, all on this persist worker
+                with telemetry.span("escalate", capacity=cap,
+                                    from_capacity=from_cap, demand=demand):
                     result = self._launch(batch, capacity=cap, tally=tally)
                     w0 = time.perf_counter()
                     with telemetry.span("device_wait"):
@@ -1517,6 +1526,7 @@ class ImageAnalysisRunner(Step):
                         + time.perf_counter() - w0)
                 if isinstance(result, tuple):
                     result, qc_dev = result
+                demand = self._batch_demand(result, n_valid)
         with telemetry.span("fetch") as fetched:
             counts = {k: np.asarray(v)[:n_valid]
                       for k, v in result.counts.items()}
@@ -1663,12 +1673,18 @@ class ImageAnalysisRunner(Step):
         # (telemetry.registry_from_ledger) — additive, PR-5 readers ignore it
         summary["bucket_ceiling"] = ceiling
         summary["slot_occupancy"] = round(slot_occupancy(total_objects, slots), 4)
+        # peak demand of the batch's sites: over bucket_capacity never
+        # below the ceiling; over the ceiling where the raw count clipped
+        summary["bucket_demand"] = demand
         if escalations:
             summary["bucket_escalations"] = escalations
             summary["device_wait_s"] = round(tally["device_wait_s"], 6)
+        if rungs_skipped:
+            summary["bucket_rungs_skipped"] = rungs_skipped
         # bytes handed to the device: the first launch and every re-launch
         summary["h2d_bytes"] = int(tally.get("h2d_bytes", 0))
-        self._note_bucket(cap, ceiling, total_objects, slots, escalations)
+        self._note_bucket(cap, ceiling, total_objects, slots, escalations,
+                          rungs_skipped)
         # object-capacity saturation must be LOUD: clip_label_count silently
         # zeroes labels past max_objects, so a site whose count sits AT the
         # cap may have lost objects — surface it per batch in the ledger,
