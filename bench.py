@@ -146,28 +146,12 @@ def _ledger_fields(pdepth: "int | None", max_objects: "int | None" = None) -> di
     }
     if max_objects is not None:
         out["max_objects"] = max_objects
-    # records self-describe the resolved reduction strategy; a fused run
-    # additionally suffixes the methodology so the regression sentinel's
-    # methodology-class keying never compares fused against unfused
-    # history silently (historic records carry neither field nor suffix
-    # and keep matching the unsuffixed classes)
-    try:
-        from tmlibrary_tpu.ops.reduction import resolve_reduction_strategy
-
-        strat = resolve_reduction_strategy()
-    except Exception:
-        strat = None
-    if strat:
-        out["reduction_strategy"] = strat
-        if strat == "fused":
-            out["timing_methodology"] += "+strategy=fused"
-    # records self-describe the resolved work-aware scheduling mode the
-    # same way: a packed capture dispatches a different batch plan than a
+    # records self-describe the resolved work-aware scheduling mode: a
+    # packed capture dispatches a different batch plan than a
     # directory-order one.  The methodology only grows a +schedule=
     # suffix when the mode was EXPLICITLY requested (env/cli/config/
-    # tuning — the sweep grid sets TMX_SCHEDULE per mode), so default
-    # runs keep matching their historic unsuffixed families while
-    # sweep-grid rows split into per-mode classes
+    # tuning), so default runs keep matching their historic unsuffixed
+    # families
     try:
         from tmlibrary_tpu.workflow.schedule import resolve_schedule
 
@@ -203,319 +187,6 @@ def _aotstore_provenance() -> dict:
         return {"enabled": False}
 
 
-def _mirror_gauge(name: str, value: float, **labels) -> None:
-    """Best-effort telemetry mirror for per-cell sweep timings — same
-    never-break-stdout contract as :func:`emit_record`."""
-    try:
-        from tmlibrary_tpu import telemetry
-
-        if telemetry.enabled():
-            telemetry.get_registry().gauge(name, **labels).set(float(value))
-    except Exception:
-        pass
-
-
-class _SweepStep:
-    """Adapter exposing one sweep cell's launch/fetch closures as the
-    launch/persist split :class:`PipelinedExecutor` drives — the
-    production executor IS the timing harness, so a swept depth's number
-    reflects the exact overlap the engine delivers at that depth."""
-
-    def __init__(self, workload):
-        self._wl = workload
-
-    def launch_batch(self, batch, prefetched=None):
-        return batch, self._wl.launch()
-
-    def persist_batch(self, batch, ctx):
-        self._wl.fetch(ctx)
-        return {}
-
-
-def measure_sweep() -> None:
-    """``--sweep`` / ``BENCH_SWEEP=1``: the per-config pipelined sweep.
-
-    Grid: reduction strategies x in-flight depths, every cell timed by
-    running ``n_exec = max(depths)`` batch executions through the SAME
-    ``PipelinedExecutor`` the production engine uses (best-of-
-    ``BENCH_REPS``, constant ``n_exec`` across cells so depths compare
-    fairly).  Configs whose chain has no grouped reductions
-    (``SWEEP_REDUCTION_CONFIGS``) collapse the strategy axis to the
-    ambient default — timing three identical programs would record noise
-    as a verdict — and host-synchronous chains
-    (``SWEEP_HOST_SYNC_CONFIGS``) hold depth at 1.
-
-    The verdict lands in ``tuning/TUNING.json`` via
-    ``tuning.record_config_sweep`` (``config_sweeps[config]`` plus the
-    per-backend ``reduction_strategy`` entry the "auto" resolver
-    consumes), every cell is mirrored as a ``tmx_bench_sweep_*`` gauge,
-    and ONE summary JSON line keeps the stdout contract."""
-    import jax
-
-    from tmlibrary_tpu import tuning as tuning_mod
-    from tmlibrary_tpu.benchmarks import (
-        SWEEP_HOST_SYNC_CONFIGS,
-        SWEEP_REDUCTION_CONFIGS,
-        sweep_workload,
-    )
-    from tmlibrary_tpu.ops.reduction import (
-        STRATEGIES,
-        resolve_reduction_strategy,
-    )
-    from tmlibrary_tpu.workflow.pipelined import PipelinedExecutor
-
-    backend = jax.default_backend()
-    config = os.environ.get("BENCH_CONFIG", "3")
-    allowed = ("2", "3", "4", "dl", "volume", "corilla", "pyramid", "spatial")
-    if config not in allowed:
-        raise SystemExit(
-            f"BENCH_SWEEP supports BENCH_CONFIG in {allowed}, got '{config}'"
-        )
-    size = int(
-        os.environ.get("BENCH_SITE_SIZE")
-        or (128 if config == "volume" else 256)
-    )
-    batch = int(os.environ.get("BENCH_BATCH") or _default_batch(config))
-    max_objects = int(os.environ.get("BENCH_MAX_OBJECTS", "64"))
-    reps = int(os.environ.get("BENCH_REPS", "2"))
-
-    env_depths = os.environ.get("BENCH_SWEEP_DEPTHS")
-    if env_depths:
-        depths = sorted({max(1, int(d)) for d in env_depths.split(",") if d.strip()})
-    else:
-        depths = [1, 2] if backend == "cpu" else [1, 2, 4, 8]
-    if config in SWEEP_HOST_SYNC_CONFIGS:
-        depths = [1]
-    env_strats = os.environ.get("BENCH_SWEEP_STRATEGIES")
-    strategies = (
-        [s.strip() for s in env_strats.split(",") if s.strip()]
-        if env_strats else list(STRATEGIES)
-    )
-    for s in strategies:
-        if s not in STRATEGIES:
-            raise SystemExit(
-                f"unknown reduction strategy '{s}' (choose from {STRATEGIES})"
-            )
-    strategy_invariant = config not in SWEEP_REDUCTION_CONFIGS
-    if strategy_invariant:
-        strategies = [None]  # one cell per depth, at the ambient resolution
-
-    # the object-capacity bucket axis: off by default so historic sweep
-    # grids (and their recorded cells) stay comparable — "auto" puts the
-    # whole capacity ladder on the grid, a comma list picks exact caps.
-    # Only meaningful for configs with per-object reductions; elsewhere
-    # capacity changes nothing but padding, so one cap per grid.
-    env_caps = os.environ.get("BENCH_SWEEP_CAPACITIES")
-    if env_caps and not strategy_invariant:
-        from tmlibrary_tpu.capacity import resolve_bucket_ladder
-
-        capacities = list(resolve_bucket_ladder(max_objects, env_caps))
-    else:
-        capacities = [max_objects]
-
-    # the work-aware scheduling axis: off by default so historic grids
-    # stay comparable — BENCH_SWEEP_SCHEDULE=1 puts packed-vs-unpacked
-    # dispatch on the grid (a comma list picks exact modes).  The mode
-    # rides TMX_SCHEDULE during each cell so every dispatch-plane
-    # consumer resolves it exactly like production, and the winning mode
-    # lands as the tuned best_schedule verdict.
-    env_sched = os.environ.get("BENCH_SWEEP_SCHEDULE")
-    if env_sched:
-        if env_sched.strip().lower() in ("1", "true", "auto", "on"):
-            schedule_modes: "list[str | None]" = ["off", "pack"]
-        else:
-            schedule_modes = [
-                m.strip() for m in env_sched.split(",") if m.strip()
-            ]
-        for m in schedule_modes:
-            if m not in ("off", "pack"):
-                raise SystemExit(
-                    f"unknown schedule mode '{m}' (choose from off, pack)"
-                )
-    else:
-        schedule_modes = [None]
-    prev_sched = os.environ.get("TMX_SCHEDULE")
-
-    knobs = dict(
-        size=size, batch=batch, max_objects=max_objects,
-        sites=int(os.environ.get("BENCH_SITES", "96")),
-        channels=int(os.environ.get("BENCH_CHANNELS", "8")),
-        zdepth=int(os.environ.get("BENCH_DEPTH", "16")),
-        grid_y=int(os.environ.get("BENCH_GRID_Y", "8")),
-        grid_x=int(os.environ.get("BENCH_GRID_X", "8")),
-    )
-
-    n_exec = max(depths)
-    rows = []
-    item_unit = None
-    for strat in strategies:
-        for cap in capacities:
-            wl = sweep_workload(
-                config, reduction_strategy=strat,
-                **{**knobs, "max_objects": cap},
-            )
-            label = strat or resolve_reduction_strategy()
-            item_unit = wl.item_unit
-            try:
-                wl.fetch(wl.launch())  # compile + warm outside the clock
-                for depth in depths:
-                    for mode in schedule_modes:
-                        if mode is not None:
-                            os.environ["TMX_SCHEDULE"] = mode
-                        best = float("inf")
-                        for _ in range(reps):
-                            ex = PipelinedExecutor(
-                                _SweepStep(wl), depth=depth,
-                                depth_source="sweep",
-                            )
-                            t0 = time.perf_counter()
-                            for _ in ex.run(
-                                [{"index": i} for i in range(n_exec)]
-                            ):
-                                pass
-                            best = min(best, time.perf_counter() - t0)
-                        value = n_exec * wl.n_items / best
-                        row = {
-                            "strategy": label,
-                            "pipeline_depth": depth,
-                            "capacity": cap,
-                            "items_per_sec": round(value, 3),
-                            "best_s": round(best, 4),
-                        }
-                        if mode is not None:
-                            row["schedule"] = mode
-                        if not strategy_invariant:
-                            # on-chip working-set estimate for this
-                            # (strategy, capacity) cell, so a rung's VMEM
-                            # pressure reads next to its throughput
-                            from tmlibrary_tpu.ops.fused_measure import (
-                                vmem_bytes_estimate,
-                            )
-
-                            row["vmem_bytes_estimate"] = vmem_bytes_estimate(
-                                cap, strategy=label
-                            )
-                        if strategy_invariant:
-                            row["strategy_invariant"] = True
-                        rows.append(row)
-                        _mirror_gauge(
-                            "tmx_bench_sweep_cell_items_per_sec", value,
-                            backend=backend, config=config, strategy=label,
-                            depth=str(depth), capacity=str(cap),
-                            **({"schedule": mode} if mode else {}),
-                        )
-            finally:
-                wl.close()
-    if env_sched:
-        # restore the ambient request: the grid's last cell must not
-        # leak its mode into this process's emitted-record provenance
-        if prev_sched is None:
-            os.environ.pop("TMX_SCHEDULE", None)
-        else:
-            os.environ["TMX_SCHEDULE"] = prev_sched
-
-    best_row = max(rows, key=lambda r: r["items_per_sec"])
-    base_row = min(
-        (r for r in rows
-         if r["strategy"] == rows[0]["strategy"]
-         and r["capacity"] == rows[0]["capacity"]),
-        key=lambda r: r["pipeline_depth"],
-    )
-    import datetime
-
-    swept_at = datetime.datetime.now(datetime.timezone.utc).isoformat(
-        timespec="seconds"
-    )
-    entry = {
-        "backend": backend,
-        "batch": batch,
-        "site_size": size,
-        "max_objects": max_objects,
-        "item_unit": item_unit,
-        "rows": rows,
-        "best_pipeline": best_row["pipeline_depth"],
-        # None for strategy-invariant configs: record_config_sweep then
-        # skips the per-backend verdict instead of recording noise
-        "best_strategy": None if strategy_invariant else best_row["strategy"],
-        # None when the capacity axis wasn't swept: a single-cap grid
-        # carries no evidence about bucket routing, so no verdict
-        "best_capacity": (
-            best_row["capacity"] if len(capacities) > 1 else None
-        ),
-        # None when the schedule axis wasn't swept — a one-mode grid is
-        # no evidence about packing, so no tuned verdict
-        "best_schedule": (
-            best_row.get("schedule") if len(schedule_modes) > 1 else None
-        ),
-        "capacities": capacities,
-        "best_items_per_sec": best_row["items_per_sec"],
-        "n_exec": n_exec,
-        # the strategy axis is part of the methodology identity: a sweep
-        # grid that includes "fused" is not comparable to a pre-fused
-        # 3-strategy grid, so the sentinel's methodology-class keying
-        # splits them automatically (strategy-invariant configs keep the
-        # unsuffixed string — their history never had a strategy axis)
-        "timing_methodology": (
-            f"pipelined-executor-sweep(n_exec={n_exec}, best-of-{reps})"
-            + (
-                "" if strategy_invariant
-                else f", strategies={'+'.join(strategies)}"
-            )
-            + (
-                f", schedule={'+'.join(schedule_modes)}"
-                if len(schedule_modes) > 1 else ""
-            )
-        ),
-        "swept_at": swept_at,
-    }
-    if config == "dl":
-        # a sweep grid is only evidence about the checkpoint it ran
-        # with: a retrained net changes object counts and therefore the
-        # measured work, so the digest joins both the stored entry (the
-        # tuned-default reader refuses a mismatched one) and the
-        # methodology class (the sentinel never compares across
-        # checkpoints)
-        from tmlibrary_tpu.nn import weights_digest
-
-        mdigest = weights_digest(os.environ.get("BENCH_DL_WEIGHTS", "seed:0"))
-        entry["model_digest"] = mdigest
-        entry["timing_methodology"] += f"+model={mdigest}"
-    tuning_mod.record_config_sweep(config, entry)
-
-    record = {
-        "metric": "sweep_best_items_per_sec",
-        "value": best_row["items_per_sec"],
-        "unit": f"{item_unit}/sec, best cell of a "
-                f"{len(strategies)}-strategy x {len(depths)}-depth"
-                + (
-                    f" x {len(capacities)}-capacity" if len(capacities) > 1
-                    else ""
-                )
-                + " grid",
-        # the gain the tuned (strategy, depth) cell buys over the
-        # depth-1 first-strategy cell of the same grid
-        "vs_baseline": round(
-            best_row["items_per_sec"] / base_row["items_per_sec"], 3
-        ),
-        "backend": backend,
-        "config": config,
-        "sweep": True,
-        "batch": batch,
-        "site_size": size,
-        "best_strategy": entry["best_strategy"],
-        "best_pipeline": entry["best_pipeline"],
-        "best_capacity": entry["best_capacity"],
-        "best_schedule": entry["best_schedule"],
-        "rows": rows,
-        "tuning_json": tuning_mod.tuning_json_path(),
-        **_ledger_fields(best_row["pipeline_depth"], max_objects),
-    }
-    record["timing_methodology"] = entry["timing_methodology"]
-    if "model_digest" in entry:
-        record["model_digest"] = entry["model_digest"]
-    emit_record(record)
-
-
 def measure(platform: str) -> None:
     """Child-process body: run the measurement on ``platform`` and print
     the result JSON line."""
@@ -532,9 +203,6 @@ def measure(platform: str) -> None:
             "bench: JAX found no accelerator (default platform is cpu); "
             "the CPU rehearsal is BENCH_FORCE_CPU=1 or --child cpu"
         )
-
-    if os.environ.get("BENCH_SWEEP"):
-        return measure_sweep()
 
     size = int(os.environ.get("BENCH_SITE_SIZE", "256"))
     config = os.environ.get("BENCH_CONFIG", "3")  # BASELINE.md milestone ladder
@@ -1287,8 +955,7 @@ def measure_analytics() -> None:
     the IVF index (``analytics/index.py``) — the methodology string
     then carries ``+index=ivf`` and ``+recall=...`` so
     ``perf._methodology_class`` separates indexed captures from brute
-    history the same way ``+strategy=fused`` separates reduction
-    strategies: the regression sentinel never compares an approximate
+    history: the regression sentinel never compares an approximate
     sublinear sweep against an exact O(N·N) one silently.  Every run
     additionally records ``index_vs_brute`` rows (built on CLUSTERED
     synthetic populations — the microscopy case; iid Gaussian data has
@@ -1434,8 +1101,7 @@ def measure_analytics() -> None:
     largest = str(max(sizes))
     # methodology provenance: the string IS the _methodology_class, so
     # an indexed capture carries +index=ivf (+recall at the headline
-    # size) and can never be judged against brute-force history — the
-    # same sentinel-separation discipline as "+strategy=fused"
+    # size) and can never be judged against brute-force history
     methodology = "analytics-tools-v1"
     if headline_index == "ivf":
         methodology += "+index=ivf"
@@ -1865,11 +1531,6 @@ if __name__ == "__main__":
         # visible device (8 virtual ones on the CPU backend)
         os.environ["BENCH_CONFIG"] = "mesh"
         sys.argv = [a for a in sys.argv if a != "--mesh"]
-    if "--sweep" in sys.argv:
-        # sugar for the per-config strategy x depth pipelined sweep
-        # (measure_sweep); env so the child process inherits the mode
-        os.environ["BENCH_SWEEP"] = "1"
-        sys.argv = [a for a in sys.argv if a != "--sweep"]
     if "--no-pipeline" in sys.argv:
         # legacy methodology: host-synchronous timing (fetch every rep),
         # no bucket routing — for apples-to-apples reruns against

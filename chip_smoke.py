@@ -21,11 +21,10 @@ Default run, on a ``tpu`` platform:
   reference chain on the same pixels, intensity features within the
   parity tests' tolerance, no ``backend_degraded`` / ``batch_failed`` /
   ``depth_clamped`` event, every array jterator returned on a tpu device.
-* **kernels** — each Pallas kernel of ``ops/pallas_kernels.py`` and
-  ``ops/fused_measure.py`` compiled NON-interpreted and run once,
-  bit-identical to its XLA twin.  A kernel the compiler refuses is listed
-  as refused and fails the phase unless ``method="auto"`` keeps it out of
-  dispatch.
+* **kernels** — each Pallas kernel of ``ops/pallas_kernels.py`` compiled
+  NON-interpreted and run once, bit-identical to its XLA twin.  A kernel
+  the compiler refuses is listed as refused and fails the phase unless
+  ``method="auto"`` keeps it out of dispatch.
 * **serve** — ``tmx enqueue`` a ``kind: workflow`` job on a second,
   one-well plate and a ``kind: query`` kNN over the features the workflow
   phase wrote, then ``tmx serve run --max-jobs 2``; both reach ``done/``
@@ -445,7 +444,7 @@ def phase_workflow(fields, work, shape) -> str:
     return root
 
 
-def kernel_cases(interpret: bool):
+def kernel_cases():
     """(name, kernel call, XLA twin call) for every Pallas kernel; 2-D at
     256x256, the 3-D twins at 16x128x128."""
     import jax.numpy as jnp
@@ -453,8 +452,7 @@ def kernel_cases(interpret: bool):
 
     from tmlibrary_tpu.benchmarks import (
         synthetic_cell_painting_batch, synthetic_volume_batch)
-    from tmlibrary_tpu.ops import fused_measure as fm
-    from tmlibrary_tpu.ops import label, measure, volume
+    from tmlibrary_tpu.ops import label, volume
     from tmlibrary_tpu.ops import pallas_kernels as pk
     from tmlibrary_tpu.ops import threshold as thr
     from tmlibrary_tpu.ops.segment_primary import distance_transform_approx
@@ -501,30 +499,6 @@ def kernel_cases(interpret: bool):
          lambda: volume.watershed_from_seeds_3d(vol, seeds3, vmask, 8,
                                                 method="xla")),
     ]
-    chans = [jnp.ones_like(dapi), dapi, dapi * dapi]
-    for cap in (64, 2048):
-        cases += [
-            # the three fused megakernels against the strategies they
-            # replace: stats at the kernel, histogram and GLCM through
-            # the feature family that is their only caller
-            (f"fused_stats@{cap}",
-             lambda c=cap: fm.grouped_stats(nuclei, chans, c,
-                                            interpret=interpret),
-             lambda c=cap: (
-                 measure.grouped_sums(nuclei, chans, c, "onehot"),
-                 *measure.grouped_minmax_multi(nuclei, chans, c,
-                                               method="onehot"))),
-            (f"fused_hist@{cap}",
-             lambda c=cap: measure.intensity_quantiles(
-                 nuclei, dapi, c, method="fused"),
-             lambda c=cap: measure.intensity_quantiles(
-                 nuclei, dapi, c, method="onehot")),
-            (f"fused_glcm@{cap}",
-             lambda c=cap: measure.haralick_features(
-                 nuclei, dapi, c, levels=32, glcm_method="fused"),
-             lambda c=cap: measure.haralick_features(
-                 nuclei, dapi, c, levels=32, glcm_method="matmul")),
-        ]
     return cases
 
 
@@ -540,7 +514,7 @@ def phase_kernels(fields, shape) -> None:
     refusals = (LoweringException, NotImplementedError, ValueError,
                 jax.errors.JaxRuntimeError)
     compiled, refused, mismatched = [], {}, []
-    for name, kernel, twin in kernel_cases(shape["interpret"]):
+    for name, kernel, twin in kernel_cases():
         want = jax.tree_util.tree_leaves(twin())
         try:
             got = jax.block_until_ready(kernel())
@@ -550,7 +524,7 @@ def phase_kernels(fields, shape) -> None:
         compiled.append(name)
         got = jax.tree_util.tree_leaves(got)
         same = len(got) == len(want) and all(
-            _kernel_equal(name, np.asarray(g), np.asarray(w))
+            np.array_equal(np.asarray(g), np.asarray(w))
             for g, w in zip(got, want))
         if not same:
             mismatched.append(name)
@@ -560,19 +534,6 @@ def phase_kernels(fields, shape) -> None:
     fields["interpret_mode"] = shape["interpret"]
     fields["checks"] = {"none_refused": not refused,
                         "all_match_their_twin": not mismatched}
-
-
-def _kernel_equal(name: str, got, want) -> bool:
-    """Bit-identical, except the fused stats kernel's fractional f32 sums,
-    which carry the 1e-6 relative tolerance tests/test_fused_measure.py
-    states (another accumulation order)."""
-    import numpy as np
-
-    if got.shape != want.shape:
-        return False
-    if name.startswith("fused_stats"):
-        return bool(np.allclose(got, want, rtol=1e-6, atol=0, equal_nan=True))
-    return bool(np.array_equal(got, want))
 
 
 def phase_serve(fields, work, plate_a: str, shape) -> None:

@@ -88,32 +88,6 @@ def ops_section() -> list[str]:
     return out
 
 
-def fused_section() -> list[str]:
-    from tmlibrary_tpu.ops import fused_measure
-
-    out = ["## Fused measure megakernels (`ops.fused_measure`)", "",
-           (inspect.getdoc(fused_measure) or "").split("\n")[0],
-           "",
-           "The `\"fused\"` reduction strategy (DESIGN.md §22): "
-           "selectable through the full `ops.reduction` precedence "
-           "chain (`--reduction-strategy fused`, `TMX_REDUCTION_"
-           "STRATEGY`, config, or a swept TUNING.json verdict), "
-           "interpret-mode fallback off-TPU, chunk knob via "
-           "`TMX_FUSED_CHUNK` / the tuned `fused_chunk` entry.",
-           "",
-           "| symbol | role |", "|---|---|"]
-    for name in sorted(n for n in dir(fused_measure) if not n.startswith("_")):
-        obj = getattr(fused_measure, name)
-        if not (inspect.isclass(obj) or inspect.isfunction(obj)):
-            continue
-        if getattr(obj, "__module__", "") != fused_measure.__name__:
-            continue
-        doc = (inspect.getdoc(obj) or "").split("\n")[0]
-        out.append(f"| `fused_measure.{name}` | {doc} |")
-    out.append("")
-    return out
-
-
 def nn_section() -> list[str]:
     import importlib
     import pkgutil
@@ -336,7 +310,7 @@ def aotstore_section() -> list[str]:
            "",
            "perf.py's AOT compile path exports every executable into a "
            "content-addressed on-disk store (digest = program identity "
-           "+ capacity rung + reduction strategy + input signature + "
+           "+ capacity rung + input signature + "
            "jax/jaxlib/backend fingerprint) and imports it back on the "
            "next process — or the next fleet host, via the shared "
            "serve-root store — instead of compiling.  Compile-ahead "
@@ -503,7 +477,6 @@ def main() -> None:
         *module_section(),
         *tool_section(),
         *ops_section(),
-        *fused_section(),
         *nn_section(),
         *telemetry_section(),
         *top_section(),

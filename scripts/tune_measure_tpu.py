@@ -1,10 +1,12 @@
 #!/usr/bin/env python3
 """Time the config-4 measure families on the attached chip at acquisition
-geometry (one 2160x2160 field, capacity 1024) under every grouped-reduction
-strategy and GLCM method, and check the per-object stretch against integer
-arithmetic.  One JSON line a case on stdout and all of them in
-``chiprun_out/tune_measure.json``; the verdicts go into
-``tuning/TUNING.json`` by hand, with this script named as their provenance.
+geometry (one 2160x2160 field, capacity 1024) under both grouped-reduction
+strategies and both GLCM methods, and check the per-object stretch against
+integer arithmetic.  One JSON line a case on stdout and all of them in
+``chiprun_out/tune_measure.json``; the times go into ``tuning/TUNING.json``
+by hand, as a record with this script named as their provenance (the
+program reads none of them: its choice is the backend's,
+``ops/reduction.py``).
 
     chiprun -- python scripts/tune_measure_tpu.py            # run on the chip
     python scripts/tune_measure_tpu.py --describe             # compile only,
@@ -72,7 +74,6 @@ def main():
         one = SingleDeviceSharding(topo.devices[0])
         jax.default_backend = lambda: "tpu"
     from tmlibrary_tpu.ops import measure
-    from tmlibrary_tpu.ops.reduction import strategy_scope
 
     size, cap = args.size, args.capacity
     results = []
@@ -120,9 +121,17 @@ def main():
         return out
 
     def scoped(strategy, fn):
+        """``fn`` traced with ``strategy`` wherever a measure function asks
+        the resolver: morphology and Zernike take no strategy argument."""
+        resolve = measure.resolve_reduction_strategy
+
         def run(*a):
-            with strategy_scope(strategy):
+            measure.resolve_reduction_strategy = lambda method="auto": (
+                resolve(strategy if method == "auto" else method))
+            try:
                 return fn(*a)
+            finally:
+                measure.resolve_reduction_strategy = resolve
         return run
 
     # ---- the stretch: floor of a TPU division against integer arithmetic
@@ -160,7 +169,7 @@ def main():
                       "bins_low": int((got[fg] < want[fg]).sum())})
 
     # ---- grouped reductions, by strategy
-    for strategy in ("onehot", "sort", "scatter", "fused"):
+    for strategy in ("onehot", "scatter"):
         timed(f"intensity.{strategy}", scoped(
             strategy, lambda l, v: measure.intensity_features(l, v, cap)),
             lab, img)
@@ -170,20 +179,18 @@ def main():
             strategy, lambda l: measure.zernike_features(
                 l, cap, degree=6, method="xla")), lab)
     # ---- Haralick, by GLCM method (reductions at the default strategy)
-    for method in ("matmul", "fused", "scatter", "sort"):
-        timed(f"texture.{method}", scoped(
-            None, lambda l, v, m=method: measure.haralick_features(
-                l, v, cap, levels=16, glcm_method=m)), lab, img)
+    for method in ("matmul", "scatter"):
+        timed(f"texture.{method}", lambda l, v, m=method:
+              measure.haralick_features(l, v, cap, levels=16, glcm_method=m),
+              lab, img)
     # the contraction alone, and the 13 features alone
     def glcm_only(l, v):
         q = measure.quantize_per_object(l, v, cap, 16)
         return measure._glcm_matmul_all(l, q, cap, 16,
                                         [(0, 1), (1, 0), (1, 1), (1, -1)])
-    timed("texture.parts.quantize", scoped(
-        None, lambda l, v: measure.quantize_per_object(l, v, cap, 16)),
-        lab, img)
-    timed("texture.parts.quantize+glcm_matmul", scoped(None, glcm_only),
-          lab, img)
+    timed("texture.parts.quantize",
+          lambda l, v: measure.quantize_per_object(l, v, cap, 16), lab, img)
+    timed("texture.parts.quantize+glcm_matmul", glcm_only, lab, img)
     # ---- where texture.matmul's time goes: its operations in a trace
     if not args.describe and (not args.only or "trace" in args.only):
         import glob
@@ -191,8 +198,8 @@ def main():
 
         from benchmark import stages
 
-        fn = jax.jit(scoped(None, lambda l, v: measure.haralick_features(
-            l, v, cap, levels=16, glcm_method="matmul")))
+        fn = jax.jit(lambda l, v: measure.haralick_features(
+            l, v, cap, levels=16, glcm_method="matmul"))
         jax.block_until_ready(fn(lab, img))
         tdir = os.path.join("chiprun_out", "tune_trace")
         shutil.rmtree(tdir, ignore_errors=True)

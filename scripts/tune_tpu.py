@@ -321,8 +321,9 @@ def stage_child_main(name):
         RESULTS["pallas_wins"] = bool(kernel_shootout())
         print(f"pallas wins: {RESULTS['pallas_wins']}")
     elif name == "glcm":
-        RESULTS["glcm_matmul_wins"] = bool(glcm_shootout())
-        print(f"glcm matmul wins: {RESULTS['glcm_matmul_wins']}")
+        # a record (``glcm_ms``), not a verdict: the program's choice is
+        # the backend's (ops/measure.py _resolve_glcm_method)
+        print(f"glcm matmul faster: {bool(glcm_shootout())}")
     else:
         raise SystemExit(f"unknown in-process stage '{name}'")
     write_results()

@@ -39,6 +39,26 @@ def devices():
 
 
 @pytest.fixture
+def pin_strategy(monkeypatch):
+    """``pin_strategy(name)``: what ``auto`` resolves to inside
+    ``ops/measure.py`` for the rest of the test.  Tier-1 runs on the CPU
+    backend, where ``auto`` is ``scatter``; ``onehot`` is what the chip
+    runs, and the measure functions that take no strategy argument
+    (morphology, Zernike, everything under Haralick but the GLCM) reach
+    it only through this pin."""
+    from tmlibrary_tpu.ops import measure, reduction
+
+    def pin(strategy):
+        monkeypatch.setattr(
+            measure, "resolve_reduction_strategy",
+            lambda method="auto": reduction.resolve_reduction_strategy(
+                strategy if method == "auto" else method),
+        )
+
+    return pin
+
+
+@pytest.fixture
 def rng():
     return np.random.default_rng(42)
 

@@ -79,16 +79,16 @@ def _counter(name: str) -> float:
 def test_export_import_roundtrip(store):
     compiled, x = _compiled_toy()
     digest = aotstore.export_entry(
-        compiled, program="toy", capacity=8, strategy="auto",
+        compiled, program="toy", capacity=8,
         signature="sig0", compile_s=0.5)
     assert digest is not None
     rows = aotstore.list_entries(store)
     assert len(rows) == 1 and rows[0]["digest"] == digest
-    assert rows[0]["capacity"] == 8 and rows[0]["strategy"] == "auto"
+    assert rows[0]["capacity"] == 8 and "strategy" not in rows[0]
     assert not rows[0]["stale"]
 
     hit = aotstore.import_entry(program="toy", capacity=8,
-                                strategy="auto", signature="sig0")
+                                signature="sig0")
     assert hit is not None
     compiled2, meta = hit
     np.testing.assert_array_equal(
@@ -101,11 +101,11 @@ def test_export_import_roundtrip(store):
 def test_import_misses_on_any_key_component(store):
     compiled, _ = _compiled_toy()
     aotstore.export_entry(compiled, program="toy", capacity=8,
-                          strategy="auto", signature="sig0")
+                          signature="sig0")
     for kw in ({"program": "other"}, {"capacity": 16},
-               {"strategy": "sort"}, {"signature": "sig1"}):
+               {"signature": "sig1"}):
         probe = {"program": "toy", "capacity": 8,
-                 "strategy": "auto", "signature": "sig0", **kw}
+                 "signature": "sig0", **kw}
         assert aotstore.import_entry(**probe) is None
 
 
@@ -115,7 +115,7 @@ def test_store_off_is_inert(store, monkeypatch):
     assert aotstore.export_entry(compiled, program="toy",
                                  signature="s") is None
     assert aotstore.import_entry(program="toy", capacity=None,
-                                 strategy=None, signature="s") is None
+                                 signature="s") is None
     assert aotstore.list_entries(store) == []
 
 
@@ -123,7 +123,7 @@ def test_store_off_is_inert(store, monkeypatch):
 def test_fingerprint_mismatch_refuses_loudly(store, caplog):
     compiled, _ = _compiled_toy()
     digest = aotstore.export_entry(compiled, program="toy", capacity=8,
-                                   strategy="auto", signature="sig0")
+                                   signature="sig0")
     meta_path = os.path.join(store, f"{digest}.json")
     meta = json.loads(open(meta_path).read())
     meta["fingerprint"] = "deadbeefdeadbeef"
@@ -131,7 +131,6 @@ def test_fingerprint_mismatch_refuses_loudly(store, caplog):
         json.dump(meta, f)
     with caplog.at_level("WARNING"):
         assert aotstore.import_entry(program="toy", capacity=8,
-                                     strategy="auto",
                                      signature="sig0") is None
     assert any("fingerprint" in r.message for r in caplog.records)
     assert aotstore.counts_snapshot().get("import_hit", 0) == 0
@@ -140,12 +139,11 @@ def test_fingerprint_mismatch_refuses_loudly(store, caplog):
 def test_corrupt_artifact_falls_back_loudly_and_evicts(store, caplog):
     compiled, _ = _compiled_toy()
     digest = aotstore.export_entry(compiled, program="toy", capacity=8,
-                                   strategy="auto", signature="sig0")
+                                   signature="sig0")
     with open(os.path.join(store, f"{digest}.bin"), "wb") as f:
         f.write(b"not a serialized executable")
     with caplog.at_level("WARNING"):
         assert aotstore.import_entry(program="toy", capacity=8,
-                                     strategy="auto",
                                      signature="sig0") is None
     assert any("corrupt" in r.message.lower() for r in caplog.records)
     # the bad entry is evicted so every later lookup is a clean miss,
@@ -167,8 +165,8 @@ def test_stale_fingerprint_never_loads():
     # the fingerprint is INSIDE the entry digest: a store written by a
     # different jax/backend resolves to different file names, so a
     # stale artifact can never even be found
-    a = aotstore.entry_digest("p", 8, "auto", "sig", fingerprint="aaaa")
-    b = aotstore.entry_digest("p", 8, "auto", "sig", fingerprint="bbbb")
+    a = aotstore.entry_digest("p", 8, "sig", fingerprint="aaaa")
+    b = aotstore.entry_digest("p", 8, "sig", fingerprint="bbbb")
     assert a != b
 
 
@@ -178,7 +176,7 @@ def test_prune_lru_cap_and_orphans(store):
     digests = []
     for i in range(4):
         digests.append(aotstore.export_entry(
-            compiled, program=f"p{i}", capacity=8, strategy="auto",
+            compiled, program=f"p{i}", capacity=8,
             signature="s"))
     # orphan payload with no meta sidecar
     with open(os.path.join(store, "feedface" * 5 + ".bin"), "wb") as f:
@@ -213,7 +211,7 @@ def test_second_walk_of_a_ladder_hits_only_if_the_cap_holds_all_of_it(
     def walk():
         hits = 0
         for cap in ladder:   # the engine's order: import, else compile + export
-            key = dict(program="ladder", capacity=cap, strategy="auto",
+            key = dict(program="ladder", capacity=cap,
                        signature="s")
             if aotstore.import_entry(**key) is not None:
                 hits += 1
@@ -242,8 +240,7 @@ def _toy_in_a_fresh_process(x):
         return v * 3.0 - 1.0
 
     return np.asarray(perf.instrument_batch_fn(
-        jax.jit(stored_toy), program="stored_toy", capacity=8,
-        strategy="auto")(x))
+        jax.jit(stored_toy), program="stored_toy", capacity=8)(x))
 
 
 def _in_jax_cache(directory) -> int:
@@ -307,7 +304,7 @@ def test_speculate_compile_then_warm_hit(store, monkeypatch):
         return x + 1.0
 
     wrapped = perf.instrument_batch_fn(
-        jax.jit(raw_fn), program="spec_toy", capacity=8, strategy="auto")
+        jax.jit(raw_fn), program="spec_toy", capacity=8)
     x = jnp.arange(4, dtype=jnp.float32)
     abs_args, abs_kwargs = perf.abstract_args((x,), {})
     # skeleton args produce the same signature as real arrays → the
@@ -331,8 +328,7 @@ def test_instrumented_call_imports_across_registry_reset(store):
     registry/profiles — the call imports instead of compiling."""
     x = jnp.arange(4, dtype=jnp.float32)
     wrapped = perf.instrument_batch_fn(
-        jax.jit(lambda v: v * 3.0), program="restart_toy", capacity=8,
-        strategy="auto")
+        jax.jit(lambda v: v * 3.0), program="restart_toy", capacity=8)
     first = np.asarray(wrapped(x))
     assert _counter("tmx_compile_cold_total") == 1
     assert _counter("tmx_compile_export_total") == 1
@@ -343,8 +339,7 @@ def test_instrumented_call_imports_across_registry_reset(store):
     perf.reset_profiles()
     aotstore.reset_counts()
     wrapped2 = perf.instrument_batch_fn(
-        jax.jit(lambda v: v * 3.0), program="restart_toy", capacity=8,
-        strategy="auto")
+        jax.jit(lambda v: v * 3.0), program="restart_toy", capacity=8)
     second = np.asarray(wrapped2(x))
     np.testing.assert_array_equal(first, second)
     assert _counter("tmx_compile_import_hit_total") == 1

@@ -21,7 +21,6 @@ from tmlibrary_tpu.jterator.pipeline import (
 @pytest.fixture(autouse=True)
 def _fresh_cache(monkeypatch):
     monkeypatch.setattr(jp, "_BATCH_FN_CACHE", {})
-    monkeypatch.delenv("TMX_REDUCTION_STRATEGY", raising=False)
     monkeypatch.delenv("TM_DONATE_BUFFERS", raising=False)
 
 
@@ -64,19 +63,15 @@ def test_donation_config_default_keys_cache(monkeypatch):
     assert b is cached_batch_fn(smooth_threshold_description(), 64, donate=False)
 
 
-def test_strategy_request_misses(monkeypatch):
+def test_strategy_environment_is_not_read(monkeypatch):
+    """The backend is in the key and decides the measure kernels; the
+    variable that used to request a strategy splits nothing."""
     a = cached_batch_fn(smooth_threshold_description(), 64)
-    b = cached_batch_fn(
-        smooth_threshold_description(), 64, reduction_strategy="sort"
-    )
-    assert a is not b
-    # env request and explicit parameter resolve to the SAME key
     monkeypatch.setenv("TMX_REDUCTION_STRATEGY", "sort")
-    assert b is cached_batch_fn(smooth_threshold_description(), 64)
-    # a different env request misses again
-    monkeypatch.setenv("TMX_REDUCTION_STRATEGY", "scatter")
-    c = cached_batch_fn(smooth_threshold_description(), 64)
-    assert c is not a and c is not b
+    assert a is cached_batch_fn(smooth_threshold_description(), 64)
+    monkeypatch.setenv("TMX_REDUCTION_STRATEGY", "onehot")
+    assert a is cached_batch_fn(smooth_threshold_description(), 64)
+    assert len(jp._BATCH_FN_CACHE) == 1
 
 
 def test_description_content_misses(monkeypatch):

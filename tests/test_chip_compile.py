@@ -1,7 +1,7 @@
 """Ask the chip's compiler, without the chip.
 
-Every kernel of ``ops/pallas_kernels.py`` and ``ops/fused_measure.py`` and
-the whole-site jterator batch programs are compiled NON-interpreted for a
+Every kernel of ``ops/pallas_kernels.py`` and the whole-site jterator
+batch programs are compiled NON-interpreted for a
 *described* TPU v5e (``jax.experimental.topologies``): what the chip's
 compiler refuses — a block shape off the (8, 128) tiling, a kernel over
 its VMEM budget, a program that does not fit HBM or does not finish
@@ -113,32 +113,6 @@ def test_whole_site_kernel_compiles_for_v5e(kernel, on_tpu):
     fn, args = _kernel_case(kernel, on_tpu)
     compiled, _ = _compile(fn, *args)
     assert "tpu_custom_call" in compiled.as_text()  # the kernel is in there
-
-
-# ------------------------------------------------------ fused megakernels
-@pytest.mark.parametrize("capacity", [64, 2048])
-@pytest.mark.parametrize("family", ["stats", "hist", "glcm"])
-def test_fused_measure_kernel_compiles_for_v5e(family, capacity, on_tpu):
-    from tmlibrary_tpu.ops import fused_measure as fm
-
-    S = on_tpu
-    lab, img = S((256, 256), jnp.int32), S((256, 256), jnp.float32)
-    bounds = [S((capacity,), jnp.float32), S((capacity,), jnp.float32)]
-    if family == "stats":
-        fn, args = (lambda l, a: fm.grouped_stats(
-            l, [jnp.ones_like(a), a, a * a], capacity, interpret=False),
-            [lab, img])
-    elif family == "hist":
-        fn, args = (lambda l, a, lo, hi: fm.intensity_hist(
-            l, a, capacity, 256, (lo, hi), interpret=False),
-            [lab, img, *bounds])
-    else:
-        fn, args = (lambda l, a, lo, hi: fm.glcm_all(
-            l, a, capacity, 32, [(0, 1), (1, 1), (1, 0), (1, -1)],
-            (lo, hi), interpret=False),
-            [lab, img, *bounds])
-    compiled, _ = _compile(fn, *args)
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_whole_site_kernels_stay_out_of_auto_dispatch_at_a_full_field(

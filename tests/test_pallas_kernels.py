@@ -278,8 +278,8 @@ def test_pallas_enabled_per_kernel(monkeypatch):
 
 
 def test_glcm_method_resolution(monkeypatch):
-    """GLCM accumulation: scatter on CPU, tuning verdict on TPU (matmul
-    when absent), matmul elsewhere."""
+    """GLCM accumulation: scatter on CPU, the contraction elsewhere — a
+    constant since the chip decided (PR 27), no tuning verdict."""
     import tmlibrary_tpu.ops.measure as measure
     from tmlibrary_tpu.ops import pallas_kernels as pk
 
@@ -289,11 +289,9 @@ def test_glcm_method_resolution(monkeypatch):
 
     monkeypatch.setattr(measure.jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(pk, "_tuning_results", lambda: {"glcm_matmul_wins": False})
-    assert measure._resolve_glcm_method("auto") == "scatter"
-    monkeypatch.setattr(pk, "_tuning_results", lambda: {"glcm_matmul_wins": True})
     assert measure._resolve_glcm_method("auto") == "matmul"
     monkeypatch.setattr(pk, "_tuning_results", lambda: {})
-    assert measure._resolve_glcm_method("auto") == "matmul"  # untuned default
+    assert measure._resolve_glcm_method("auto") == "matmul"
 
 
 # ------------------------------------------------------------- 3-D twins

@@ -191,7 +191,7 @@ def test_cached_batch_fn_keys_on_qc_gate():
 def test_perf_wrapper_never_reuses_executable_across_qc_gate():
     """Regression: perf's AOT executable cache keys on the program
     digest — a QC-off run compiling first (same description, window,
-    capacity, strategy, shapes) must NOT hand its executable to the
+    capacity, shapes) must NOT hand its executable to the
     QC-on wrapper, which expects a (SiteResult, qc_stats) pytree back.
     Order-dependent in the full suite (any engine run before a QC-on
     one), deterministic here."""

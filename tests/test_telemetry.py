@@ -716,7 +716,7 @@ def test_cli_perf_renders_roofline_table(tmp_path, capsys, monkeypatch):
     for cap in (8, 32):
         perf.record_compile(
             program="jterator_batch@abc123", capacity=cap,
-            strategy="onehot", backend="cpu", compile_s=0.5,
+            backend="cpu", compile_s=0.5,
             cost=perf.ProgramCost(2e9, 4e7),
         )
     (st.workflow_dir / "perf.json").write_text(

@@ -14,9 +14,9 @@ Keying contract (stale artifacts can never load):
 
 * the **entry digest** hashes the full program identity — the perf
   program name (which already folds in the description digest +
-  ``program_digest_extras`` incl. weight/QC keys), the capacity rung,
-  the reduction strategy, and the exact input signature (treedef +
-  leaf shapes/dtypes) — plus the **backend fingerprint**;
+  ``program_digest_extras`` incl. weight/QC keys), the capacity rung
+  and the exact input signature (treedef + leaf shapes/dtypes) — plus
+  the **backend fingerprint**;
 * the fingerprint is (jax version, jaxlib version, backend name,
   device count, digest of this package's sources): any toolchain,
   topology or code change produces a different digest, so a stale
@@ -29,8 +29,8 @@ process default (serve daemons point this at the shared serve root) >
 next to the compile cache, ``<checkout>/.cache/aot``)::
 
     <dir>/<digest>.bin    pickled {payload, in_tree, out_tree}
-    <dir>/<digest>.json   meta sidecar: program/capacity/strategy,
-                          fingerprint, size, compile_s, timestamps
+    <dir>/<digest>.json   meta sidecar: program/capacity, fingerprint,
+                          size, compile_s, timestamps
 
 Writes are tmp-file + ``os.replace`` (the atomicio discipline) so a
 concurrent reader never sees a torn entry; a corrupt/undeserializable
@@ -236,16 +236,14 @@ def backend_fingerprint(info: dict | None = None) -> str:
     return hashlib.sha1(blob.encode()).hexdigest()[:16]
 
 
-def entry_digest(program: str, capacity: int | None, strategy: str | None,
+def entry_digest(program: str, capacity: int | None,
                  signature: Any, fingerprint: str | None = None) -> str:
     """Content address of one executable: full program identity (the
     perf program name already folds in the description digest and
-    ``program_digest_extras``) + capacity rung + reduction strategy +
-    input signature + backend fingerprint."""
+    ``program_digest_extras``) + capacity rung + input signature +
+    backend fingerprint."""
     fp = fingerprint or backend_fingerprint()
-    blob = "|".join([
-        str(program), str(capacity), str(strategy), repr(signature), fp,
-    ])
+    blob = "|".join([str(program), str(capacity), repr(signature), fp])
     return hashlib.sha1(blob.encode()).hexdigest()
 
 
@@ -370,7 +368,7 @@ def cache_hits_seen() -> int:
 
 
 def export_entry(compiled: Any, *, program: str, step: str = "jterator",
-                 capacity: int | None = None, strategy: str | None = None,
+                 capacity: int | None = None,
                  signature: Any = None, compile_s: float | None = None,
                  directory: str | None = None) -> str | None:
     """Serialize ``compiled`` into the store.  Returns the entry digest,
@@ -413,7 +411,7 @@ def export_entry(compiled: Any, *, program: str, step: str = "jterator",
     try:
         info = fingerprint_info()
         fp = backend_fingerprint(info)
-        digest = entry_digest(program, capacity, strategy, signature, fp)
+        digest = entry_digest(program, capacity, signature, fp)
         bin_path, meta_path = _paths(digest, directory)
         if os.path.exists(meta_path):
             return digest  # already exported (peer or earlier run)
@@ -428,7 +426,6 @@ def export_entry(compiled: Any, *, program: str, step: str = "jterator",
             "program": str(program),
             "step": str(step),
             "capacity": capacity,
-            "strategy": strategy,
             "signature": repr(signature),
             "fingerprint": fp,
             "fingerprint_info": info,
@@ -457,7 +454,7 @@ def _drop_entry(digest: str, directory: str | None = None) -> None:
 
 
 def import_entry(*, program: str, capacity: int | None = None,
-                 strategy: str | None = None, signature: Any = None,
+                 signature: Any = None,
                  directory: str | None = None) -> tuple[Any, dict] | None:
     """Load a serialized executable back.  Returns ``(compiled, meta)``
     on a hit, None on miss/disabled.  A fingerprint mismatch or a
@@ -468,7 +465,7 @@ def import_entry(*, program: str, capacity: int | None = None,
         return None
     try:
         fp = backend_fingerprint()
-        digest = entry_digest(program, capacity, strategy, signature, fp)
+        digest = entry_digest(program, capacity, signature, fp)
         bin_path, meta_path = _paths(digest, directory)
         if not (os.path.exists(bin_path) and os.path.exists(meta_path)):
             return None

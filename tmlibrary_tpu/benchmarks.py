@@ -949,203 +949,3 @@ def cpu_reference_mosaic(mosaic: np.ndarray) -> int:
         ndi.maximum(img64, labels, ids)
         ndi.sum(img64, labels, ids)
     return n
-
-
-# ------------------------------------------------------ bench sweep workloads
-#: configs whose compiled chain contains grouped (per-object) reductions —
-#: the only ones where the reduction-strategy axis changes the program.
-#: Config 2 stops at label (exact counts, no measure modules), corilla is a
-#: Welford scan, the pyramid is a reduce_window chain, and the spatial
-#: layout's mosaic programs are cached without a strategy key — sweeping
-#: strategies there would record timing noise as a verdict.
-SWEEP_REDUCTION_CONFIGS = ("3", "4", "dl", "volume")
-
-#: configs whose chain is host-synchronous end to end (stitching on both
-#: ends): there is nothing for a deeper in-flight window to overlap, so
-#: the sweep holds them at depth 1 and the row says so.
-SWEEP_HOST_SYNC_CONFIGS = ("spatial",)
-
-
-class BenchWorkload:
-    """One device-side workload cell for the pipelined bench sweep
-    (``bench.py --sweep``): ``launch()`` dispatches one batch execution
-    asynchronously and returns the un-fetched device value(s);
-    ``fetch(ctx)`` forces the host round-trip that fences it.  The split
-    mirrors ``PipelinedExecutor``'s launch/persist contract so the sweep
-    times the exact overlap the production engine delivers."""
-
-    def __init__(self, launch, fetch, n_items, item_unit,
-                 host_synchronous=False, close=None):
-        self.launch = launch
-        self.fetch = fetch
-        #: items (sites / channels / Mpix) completed by ONE launch
-        self.n_items = n_items
-        self.item_unit = item_unit
-        self.host_synchronous = host_synchronous
-        self._close = close
-
-    def close(self):
-        if self._close is not None:
-            self._close()
-
-
-def _jterator_sweep_workload(desc, data, batch, max_objects, count_key,
-                             reduction_strategy):
-    import jax.numpy as jnp
-
-    from tmlibrary_tpu.jterator.pipeline import ImageAnalysisPipeline
-
-    pipe = ImageAnalysisPipeline(desc, max_objects=max_objects)
-    # donate=False: the sweep's timing loop re-launches the SAME device
-    # arrays over and over, which donation would invalidate
-    fn = pipe.build_batch_fn(donate=False,
-                             reduction_strategy=reduction_strategy)
-    raw = {k: jnp.asarray(v) for k, v in data.items()}
-    shifts = jnp.zeros((batch, 2), jnp.int32)
-
-    def launch():
-        return fn(raw, {}, shifts).counts[count_key]
-
-    def fetch(ctx):
-        np.asarray(ctx)
-
-    return BenchWorkload(launch, fetch, batch, "sites")
-
-
-def sweep_workload(config, *, reduction_strategy=None, size=256, batch=64,
-                   max_objects=64, sites=96, channels=8, zdepth=16,
-                   grid_y=8, grid_x=8):
-    """Build the ``BENCH_CONFIG`` workload one sweep cell times.
-
-    For the jterator configs the compiled program is built with
-    ``reduction_strategy`` pinned at trace time (``None`` keeps the
-    ambient resolution); the non-jterator configs ignore the pin — their
-    chains contain no grouped reductions (see
-    :data:`SWEEP_REDUCTION_CONFIGS`)."""
-    if config == "3":
-        return _jterator_sweep_workload(
-            cell_painting_description(),
-            synthetic_cell_painting_batch(batch, size=size),
-            batch, max_objects, "cells", reduction_strategy,
-        )
-    if config == "2":
-        return _jterator_sweep_workload(
-            smooth_threshold_description(),
-            synthetic_cell_painting_batch(batch, size=size, dapi_only=True),
-            batch, max_objects, "fg", reduction_strategy,
-        )
-    if config == "dl":
-        import os
-
-        return _jterator_sweep_workload(
-            dl_description(weights=os.environ.get("BENCH_DL_WEIGHTS",
-                                                  "seed:0")),
-            synthetic_cell_painting_batch(batch, size=size, dapi_only=True),
-            batch, max_objects, "cells", reduction_strategy,
-        )
-    if config == "4":
-        return _jterator_sweep_workload(
-            full_feature_description(),
-            synthetic_full_stack_batch(batch, size=size),
-            batch, max_objects, "cells", reduction_strategy,
-        )
-    if config == "volume":
-        return _jterator_sweep_workload(
-            volume_description(),
-            synthetic_volume_batch(batch, size=size, depth=zdepth),
-            batch, max_objects, "cells3d", reduction_strategy,
-        )
-    if config == "corilla":
-        import jax
-        import jax.numpy as jnp
-
-        from tmlibrary_tpu.ops.stats import welford_finalize, welford_scan
-
-        stack = synthetic_channel_stack(channels, sites, size)
-        fn = jax.jit(jax.vmap(lambda s: welford_finalize(welford_scan(s))))
-        dev = jnp.asarray(stack)
-
-        def launch():
-            return fn(dev)["n"]
-
-        def fetch(ctx):
-            np.asarray(ctx)
-
-        return BenchWorkload(launch, fetch, channels, "channels")
-    if config == "pyramid":
-        import jax
-        import jax.numpy as jnp
-
-        from tmlibrary_tpu.ops.pyramid import (
-            downsample_2x,
-            n_pyramid_levels,
-            to_uint8,
-        )
-
-        tiles = np.asarray(
-            synthetic_cell_painting_batch(
-                grid_y * grid_x, size=size, dapi_only=True
-            )["DAPI"], np.float32,
-        )
-        n_levels = n_pyramid_levels(grid_y * size, grid_x * size)
-        lower = float(np.percentile(tiles, 0.1))
-        upper = float(np.percentile(tiles, 99.9))
-
-        def chain(b):
-            mosaic = (
-                b.reshape(grid_y, grid_x, size, size)
-                .transpose(0, 2, 1, 3)
-                .reshape(grid_y * size, grid_x * size)
-            )
-            levels = [to_uint8(mosaic, lower, upper)]
-            cur = mosaic
-            for _ in range(n_levels - 1):
-                cur = downsample_2x(cur)
-                levels.append(to_uint8(cur, lower, upper))
-            return levels
-
-        fn = jax.jit(chain)
-        dev = jnp.asarray(tiles)
-
-        def launch():
-            return fn(dev)[-1]
-
-        def fetch(ctx):
-            np.asarray(ctx)
-
-        return BenchWorkload(
-            launch, fetch, grid_y * grid_x * size * size / 1e6, "Mpix"
-        )
-    if config == "spatial":
-        import os
-        import shutil
-        import tempfile
-
-        from tmlibrary_tpu.models.experiment import grid_experiment
-        from tmlibrary_tpu.models.store import ExperimentStore
-        from tmlibrary_tpu.workflow.registry import get_step
-
-        _, tiles = synthetic_mosaic_well(grid_y, grid_x, size=size)
-        tmpdir = tempfile.mkdtemp(prefix="bench_sweep_spatial_")
-        exp = grid_experiment(
-            "bench_sweep_spatial", well_rows=1, well_cols=1,
-            sites_per_well=(grid_y, grid_x), channel_names=("DAPI",),
-            site_shape=(size, size),
-        )
-        store = ExperimentStore.create(os.path.join(tmpdir, "exp"), exp)
-        store.write_sites(tiles, list(range(grid_y * grid_x)), channel=0)
-        jt = get_step("jterator")(store)
-        jt.init({"layout": "spatial", "spatial_zernike_degree": 0})
-
-        def launch():
-            return jt.run(0)
-
-        def fetch(ctx):
-            pass  # jt.run is host-synchronous: the launch already fenced
-
-        return BenchWorkload(
-            launch, fetch, grid_y * grid_x * size * size / 1e6, "Mpix",
-            host_synchronous=True,
-            close=lambda: shutil.rmtree(tmpdir, ignore_errors=True),
-        )
-    raise ValueError(f"no sweep workload for BENCH_CONFIG={config!r}")

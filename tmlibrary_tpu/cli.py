@@ -375,17 +375,6 @@ def build_parser() -> argparse.ArgumentParser:
              "per-backend default; 1 = minimal overlap)",
     )
     shared.add_argument(
-        "--reduction-strategy", default=None,
-        choices=("auto", "onehot", "sort", "scatter", "fused"),
-        help="grouped-reduction strategy for the measurement stack "
-             "(default: TMX_REDUCTION_STRATEGY / TM_REDUCTION_STRATEGY "
-             "config, else the bench sweep's tuned verdict in "
-             "tuning/TUNING.json, else scatter on CPU and one-hot "
-             "matmuls on accelerators; 'sort' is the exactly "
-             "deterministic path, 'fused' the single-pass Pallas "
-             "measure megakernels)",
-    )
-    shared.add_argument(
         "--object-buckets", default=None, metavar="SPEC",
         help="object-capacity bucket ladder for the jterator step "
              "(capacity.py): 'auto' compiles power-of-two capacity "
@@ -1022,24 +1011,13 @@ def cmd_workflow(args) -> int:
 
     if args.no_telemetry:
         telemetry.set_enabled(False)
-    if getattr(args, "reduction_strategy", None):
-        import os as _os
-
-        # the env (not a plumbed parameter) because compiled programs
-        # trace lazily at first call: the request must outlive this
-        # function and be visible to every build site (ops/reduction.py
-        # resolution order; "auto" clears a stale request)
-        if args.reduction_strategy == "auto":
-            _os.environ.pop("TMX_REDUCTION_STRATEGY", None)
-        else:
-            _os.environ["TMX_REDUCTION_STRATEGY"] = args.reduction_strategy
     if getattr(args, "object_buckets", None):
         import os as _os
 
-        # same env pattern as --reduction-strategy: the bucket router
-        # resolves the spec at every launch (capacity.py resolution
-        # order), so the request must outlive this function; "auto"
-        # clears any stale explicit request
+        # the env (not a plumbed parameter): the bucket router resolves
+        # the spec at every launch (capacity.py resolution order), so
+        # the request must outlive this function; "auto" clears any
+        # stale explicit request
         if args.object_buckets == "auto":
             _os.environ.pop("TMX_OBJECT_BUCKETS", None)
         else:
@@ -1060,7 +1038,7 @@ def cmd_workflow(args) -> int:
         import os as _os
 
         # env (not a plumbed parameter), same pattern as
-        # --reduction-strategy: the QC gate is part of the compiled-
+        # --object-buckets: the QC gate is part of the compiled-
         # program cache key (jterator.pipeline.cached_batch_fn) and is
         # re-read at every build site, so the request must outlive this
         # function; an explicit --no-qc writes "0" to beat the config
@@ -2227,47 +2205,6 @@ def _perf_schedule_summary(events: list) -> list:
     return out
 
 
-def _perf_strategy_comparison(programs: list) -> list:
-    """Group program profiles by (program, step, capacity) and keep the
-    groups recorded under two or more reduction strategies — the
-    fused-vs-unfused readout: same program identity, strategies side by
-    side with FLOPs/bytes/arithmetic-intensity/bound_by, so a kernel win
-    (or loss) is readable without re-deriving it from the gauges."""
-    groups: dict = {}
-    for e in programs:
-        if not isinstance(e, dict):
-            continue
-        key = (str(e.get("program") or "?"), str(e.get("step") or "?"),
-               e.get("capacity"))
-        groups.setdefault(key, []).append(e)
-    out = []
-    for (program, step, capacity), entries in groups.items():
-        strategies = {e.get("strategy") for e in entries}
-        if len(strategies) < 2:
-            continue
-        variants = sorted(
-            entries, key=lambda e: str(e.get("strategy") or "")
-        )
-        out.append({
-            "program": program,
-            "step": step,
-            "capacity": capacity,
-            "variants": [
-                {
-                    "strategy": v.get("strategy"),
-                    "flops": v.get("flops"),
-                    "bytes": v.get("bytes"),
-                    "arithmetic_intensity": v.get("arithmetic_intensity"),
-                    "bound_by": v.get("bound_by"),
-                    "compiles": v.get("compiles"),
-                }
-                for v in variants
-            ],
-        })
-    out.sort(key=lambda g: (g["program"], g["step"], g["capacity"] or 0))
-    return out
-
-
 def cmd_perf(args) -> int:
     """Performance attribution: the per-program roofline table the last
     run recorded (``workflow/perf.json``), the pipelined phase device/host
@@ -2349,12 +2286,9 @@ def cmd_perf(args) -> int:
                 and r.get("value") and not r.get("error")]
     latest = measured[-1] if measured else None
 
-    strategy_cmp = _perf_strategy_comparison(programs)
-
     if args.as_json:
         print(json.dumps({
             "programs": programs,
-            "strategy_comparison": strategy_cmp,
             "phases": phases_out,
             "padded_flops_avoided_frac": avoided,
             "slot_occupancy": occupancy,
@@ -2364,7 +2298,7 @@ def cmd_perf(args) -> int:
         return 0
 
     if programs:
-        print(f"{'program':<24} {'cap':>5} {'strategy':<8} {'backend':<8} "
+        print(f"{'program':<24} {'cap':>5} {'backend':<8} "
               f"{'compiles':>8} {'recomp':>6} {'compile_s':>9} "
               f"{'gflops':>9} {'mbytes':>9} {'flops/B':>8} bound-by")
         for e in programs:
@@ -2373,7 +2307,6 @@ def cmd_perf(args) -> int:
             print(
                 f"{str(e.get('program', '?')):<24} "
                 f"{str(e.get('capacity') or '-'):>5} "
-                f"{str(e.get('strategy') or '-'):<8} "
                 f"{str(e.get('backend') or '?'):<8} "
                 f"{e.get('compiles', 0):>8} "
                 f"{e.get('recompiles', 0):>6} "
@@ -2386,27 +2319,6 @@ def cmd_perf(args) -> int:
         print("(roofline verdict vs the v5e reference ridge "
               f"{perf.ridge_point():.0f} FLOPs/byte; MFU/HBM fractions are "
               "runtime numbers — see the bench line below)")
-        if strategy_cmp:
-            print()
-            print("strategy comparison (same program/step/capacity, "
-                  "side by side):")
-            print(f"{'program':<24} {'step':<10} {'cap':>5} "
-                  f"{'strategy':<8} {'gflops':>9} {'mbytes':>9} "
-                  f"{'flops/B':>8} bound-by")
-            for grp in strategy_cmp:
-                for v in grp["variants"]:
-                    flops = v.get("flops")
-                    nbytes = v.get("bytes")
-                    print(
-                        f"{str(grp['program']):<24} "
-                        f"{str(grp['step']):<10} "
-                        f"{str(grp['capacity'] or '-'):>5} "
-                        f"{str(v.get('strategy') or '-'):<8} "
-                        f"{(round(flops / 1e9, 3) if flops else '-'):>9} "
-                        f"{(round(nbytes / 1e6, 2) if nbytes else '-'):>9} "
-                        f"{(v.get('arithmetic_intensity') or '-'):>8} "
-                        f"{v.get('bound_by') or '-'}"
-                    )
     else:
         print("no perf attribution recorded — run `tmx workflow submit` "
               "with telemetry enabled (workflow/perf.json)")
@@ -2525,7 +2437,7 @@ def cmd_cache(args) -> int:
               f"stale: {stats['stale_entries']}")
         if rows:
             print(f"{'digest':<18} {'program':<24} {'cap':>5} "
-                  f"{'strategy':<10} {'size':>9} {'age':>8} fp")
+                  f"{'size':>9} {'age':>8} fp")
             for m in rows:
                 age = m.get("age_s")
                 age_txt = "-" if age is None else (
@@ -2536,7 +2448,6 @@ def cmd_cache(args) -> int:
                 print(f"{str(m.get('digest'))[:16]:<18} "
                       f"{str(m.get('program'))[:24]:<24} "
                       f"{str(m.get('capacity') if m.get('capacity') is not None else '-'):>5} "
-                      f"{str(m.get('strategy') or '-')[:10]:<10} "
                       f"{int(m.get('size_bytes') or 0):>9} "
                       f"{age_txt:>8} {fp}")
         return 0

@@ -174,15 +174,6 @@ class LibraryConfig:
     aot_speculate: str = dataclasses.field(
         default_factory=lambda: _setting("aot_speculate", "1")
     )
-    # ------------------------------------------------- grouped reductions
-    #: grouped-reduction strategy for the measurement stack
-    #: ("auto" | "onehot" | "sort" | "scatter"); "auto" falls through to
-    #: the tuned TUNING.json verdict, then a backend-safe default
-    #: (ops/reduction.py documents the full resolution order — the
-    #: TMX_REDUCTION_STRATEGY env set by the CLI knob beats this setting)
-    reduction_strategy: str = dataclasses.field(
-        default_factory=lambda: _setting("reduction_strategy", "auto")
-    )
     #: work-aware site scheduling mode for the jterator dispatch plane
     #: ("auto" | "pack" | "off"); "auto" falls through to the tuned
     #: TUNING.json verdict, then packing on (workflow/schedule.py

@@ -48,10 +48,10 @@ from tmlibrary_tpu.parallel.compat import shard_map
 #: otherwise pay a full re-trace + XLA load per instance, which at
 #: plate-batch granularity is pure overhead (~1 s/run measured on the
 #: CPU backend).  Keyed by the description's full content, the object
-#: cap, the crop window, the backend, the donation flag, the resolved
-#: reduction-strategy request, and every env knob that changes what the
-#: trace emits (TMX_PALLAS kernel override, TMX_NATIVE CPU kill switch,
-#: TMX_SITE_STATS measure-kernel gate).  Bounded FIFO: a
+#: cap, the crop window, the backend (which also decides the measure
+#: kernels, ops/reduction.py), the donation flag, and every env knob that
+#: changes what the trace emits (TMX_PALLAS kernel override, TMX_NATIVE
+#: CPU kill switch, TMX_SITE_STATS measure-kernel gate).  Bounded FIFO: a
 #: long-lived service crossing many experiments (each align crop window
 #: is a distinct key) must not retain every compiled program forever.
 #: Sized for the bucket router: one pipeline now legitimately holds a
@@ -81,7 +81,6 @@ _PROGRAM_ENV_KNOBS = (
     "TMX_NATIVE",        # CPU native-helper kill switch
     "TMX_SITE_STATS",    # measure-kernel gate
     "TMX_PALLAS_CHUNK",  # Pallas label-kernel chunking
-    "TMX_FUSED_CHUNK",   # fused measure-megakernel chunking
 )
 
 
@@ -148,7 +147,7 @@ def program_digest_extras(
     description: PipelineDescription | None = None, qc: bool = False
 ) -> tuple:
     """Every gate beyond (description, capacity, window, backend,
-    donation, strategy) that must split the compiled-program identity —
+    donation) that must split the compiled-program identity —
     the QC-shape gate, the trace-shaping env knobs, and the content
     digests of any model weights the description binds.
 
@@ -209,33 +208,23 @@ def cached_batch_fn(
     max_objects: int,
     window: "tuple[int, int, int, int] | None" = None,
     donate: "bool | None" = None,
-    reduction_strategy: "str | None" = None,
     qc: "bool | None" = None,
 ) -> Callable:
     """Memoized :meth:`ImageAnalysisPipeline.build_batch_fn` — same
     compiled program for the same (description, cap, window, backend,
-    donation, reduction-strategy request, QC gate).  ``donate=None``
-    resolves the :func:`donation_enabled` config default;
-    ``reduction_strategy=None`` resolves the live request chain
-    (env/config/tuned verdict) so a CLI ``--reduction-strategy`` run
-    never reuses a program compiled for a different strategy;
-    ``qc=None`` resolves :func:`tmlibrary_tpu.qc.enabled` — the gate is
-    part of the cache key because a QC-on program returns
-    ``(SiteResult, qc_stats)`` instead of a bare ``SiteResult``.
+    donation, QC gate).  ``donate=None`` resolves the
+    :func:`donation_enabled` config default; ``qc=None`` resolves
+    :func:`tmlibrary_tpu.qc.enabled` — the gate is part of the cache key
+    because a QC-on program returns ``(SiteResult, qc_stats)`` instead of
+    a bare ``SiteResult``.
 
     Everything else that shapes the trace — the QC gate, the
     trace-shaping env knobs, the content digests of any model weights —
     joins the key as one :func:`program_digest_extras` tuple, the same
     tuple the perf program digest hashes."""
-    from tmlibrary_tpu.ops import reduction
     from tmlibrary_tpu import qc as qc_mod
 
     donate = donation_enabled() if donate is None else bool(donate)
-    requested = (
-        reduction_strategy
-        if reduction_strategy not in (None, "auto")
-        else reduction.requested_reduction_strategy()
-    )
     qc = qc_mod.enabled() if qc is None else bool(qc)
     extras = program_digest_extras(description, qc=qc)
     key = (
@@ -244,16 +233,12 @@ def cached_batch_fn(
         window,
         jax.default_backend(),
         donate,
-        requested,
         extras,
     )
     fn = _BATCH_FN_CACHE.get(key)
     if fn is None:
         pipe = ImageAnalysisPipeline(description, max_objects=max_objects)
-        fn = pipe.build_batch_fn(
-            window=window, donate=donate, reduction_strategy=requested,
-            qc=qc,
-        )
+        fn = pipe.build_batch_fn(window=window, donate=donate, qc=qc)
         while len(_BATCH_FN_CACHE) >= _BATCH_FN_CACHE_MAX:
             _BATCH_FN_CACHE.pop(next(iter(_BATCH_FN_CACHE)))
         _BATCH_FN_CACHE[key] = fn
@@ -264,7 +249,7 @@ def cached_batch_fn(
     # Attach the perf-attribution wrapper OUTSIDE the cache: the cache
     # holds the raw jitted program (so an enabled->disabled flip never
     # pays wrapper overhead), while every enabled caller shares compile /
-    # cost state keyed by (program, capacity, strategy) in perf's global
+    # cost state keyed by (program, capacity) in perf's global
     # store.  The wrapper AOT-compiles on first call per signature — one
     # compile, same executable jit would build — so attribution adds no
     # extra compiles and cannot perturb results.
@@ -274,7 +259,7 @@ def cached_batch_fn(
     if wrapped is None or wrapped.__wrapped__ is not fn:
         # the digest names the perf-attribution program, which keys the
         # AOT executable cache in perf._RUNTIME together with (step,
-        # capacity, strategy) — every program_digest_extras gate MUST
+        # capacity) — every program_digest_extras gate MUST
         # join it: QC-on and QC-off programs share description/window/
         # shapes but return different pytrees, and two checkpoints of
         # the same weights name share the whole description, so a stale
@@ -289,7 +274,6 @@ def cached_batch_fn(
             program=f"jterator_batch@{digest}",
             step="jterator",
             capacity=max_objects,
-            strategy=requested or "default",
             sub_costs=_model_sub_costs(weight_digests(description)),
         )
         while len(_WRAPPED_FN_CACHE) >= _BATCH_FN_CACHE_MAX:
@@ -508,7 +492,6 @@ class ImageAnalysisPipeline:
         window: tuple[int, int, int, int] | None = None,
         jit: bool = True,
         donate: bool = False,
-        reduction_strategy: str | None = None,
         qc: bool = False,
     ) -> Callable:
         """jit(vmap(preprocess ∘ site_fn)) over the site-batch axis.
@@ -526,12 +509,6 @@ class ImageAnalysisPipeline:
         batch) but NOT for timing loops that re-invoke on the same
         buffers.
 
-        ``reduction_strategy`` pins the grouped-reduction request for the
-        whole program at build time (``ops/reduction.py``); ``None``/
-        ``"auto"`` captures the live request chain once, so the lazy
-        first-call trace cannot diverge from the build-time decision the
-        compiled-program cache keyed on.
-
         ``qc=True`` additionally computes the fused per-site image QC
         statistics (``tmlibrary_tpu.ops.qc``) from the RAW channel
         images — before correction/alignment, so the stats describe the
@@ -543,44 +520,36 @@ class ImageAnalysisPipeline:
         only *reads* the pipeline's arrays; the dataflow is untouched,
         which is what keeps outputs bit-identical with QC on/off.
         """
-        from tmlibrary_tpu.ops import reduction
-
-        requested = (
-            reduction_strategy
-            if reduction_strategy not in (None, "auto")
-            else reduction.requested_reduction_strategy()
-        )
         site_fn = self.build_site_fn(collect_diagnostics=qc)
         preprocess = self.build_preprocess_fn(window)
         desc = self.description
 
         def one_site(raw, stats, shift):
-            with reduction.strategy_scope(requested):
-                with jax.named_scope("preprocess"):
-                    images = preprocess(raw, stats, shift)
-                # pass loaded objects (if any) through; label images loaded
-                # from the store live in the uncropped site frame, so they
-                # get the same intersection crop as the pixel channels
-                for key, val in raw.items():
-                    if key not in images:
-                        if window is not None and jnp.ndim(val) == 2:
-                            val = image_ops.crop_window(val, *window)
-                        images[key] = val
-                if not qc:
-                    return site_fn(images)
-                result, diagnostics = site_fn(images)
-                from tmlibrary_tpu.ops import qc as qc_ops
+            with jax.named_scope("preprocess"):
+                images = preprocess(raw, stats, shift)
+            # pass loaded objects (if any) through; label images loaded
+            # from the store live in the uncropped site frame, so they
+            # get the same intersection crop as the pixel channels
+            for key, val in raw.items():
+                if key not in images:
+                    if window is not None and jnp.ndim(val) == 2:
+                        val = image_ops.crop_window(val, *window)
+                    images[key] = val
+            if not qc:
+                return site_fn(images)
+            result, diagnostics = site_fn(images)
+            from tmlibrary_tpu.ops import qc as qc_ops
 
-                qc_stats = {
-                    ch.name: qc_ops.site_qc_stats(raw[ch.name])
-                    for ch in desc.channels
-                }
-                if diagnostics:
-                    # module diagnostic streams (model-output stats) ride
-                    # the qc pytree under a reserved pseudo-channel; the
-                    # persist path routes them into the feature sketches
-                    qc_stats[MODEL_QC_KEY] = diagnostics
-                return result, qc_stats
+            qc_stats = {
+                ch.name: qc_ops.site_qc_stats(raw[ch.name])
+                for ch in desc.channels
+            }
+            if diagnostics:
+                # module diagnostic streams (model-output stats) ride
+                # the qc pytree under a reserved pseudo-channel; the
+                # persist path routes them into the feature sketches
+                qc_stats[MODEL_QC_KEY] = diagnostics
+            return result, qc_stats
 
         batched = jax.vmap(one_site, in_axes=(0, None, 0))
         if not jit:
@@ -593,7 +562,6 @@ class ImageAnalysisPipeline:
         axis: str | tuple[str, ...] = "sites",
         window: tuple[int, int, int, int] | None = None,
         donate: bool = False,
-        reduction_strategy: str | None = None,
     ) -> Callable:
         """``jit(shard_map(vmap(site_fn)))`` over a site mesh — the
         multi-chip form of :meth:`build_batch_fn`.
@@ -616,9 +584,7 @@ class ImageAnalysisPipeline:
         """
         from jax.sharding import PartitionSpec as P
 
-        batched = self.build_batch_fn(
-            window, jit=False, reduction_strategy=reduction_strategy
-        )
+        batched = self.build_batch_fn(window, jit=False)
         # check_vma off: the iterative ops' while loops carry literal
         # bool flags, which the varying-axes checker rejects under
         # shard_map (carry starts unvarying, body output is varying).

@@ -26,7 +26,6 @@ import numpy as np
 from tmlibrary_tpu.ops.label import shift_with_fill
 from tmlibrary_tpu.ops.reduction import (
     capacity_segments,
-    explicit_reduction_request,
     resolve_reduction_strategy,
     segmented_max,
     segmented_min,
@@ -64,10 +63,10 @@ def grouped_sums(
     site-batch vmap multiplies it).  Returns ``(max_objects, n_channels)``
     float32 (label ids 1..max_objects; background dropped).
 
-    ``method`` is a reduction-strategy name (``ops/reduction.py``):
+    ``method`` names a strategy of ``ops/reduction.py``:
     ``"onehot"`` (alias ``"matmul"``) is the chunked MXU contraction,
-    ``"scatter"`` the segment scatter-add, ``"sort"`` the deterministic
-    sorted-run reduction, ``"native"`` the explicit-opt-in C callback.
+    ``"scatter"`` the segment scatter-add, ``"native"`` the
+    explicit-opt-in C callback.
     ``"auto"`` resolves through the strategy layer — by default the
     matmul on accelerators and the scatter on CPU, where scatters are
     cheap and the one-hot materialization is the bottleneck (~25x for
@@ -87,11 +86,6 @@ def grouped_sums(
         # itself is bit-identical and parity-tested — and the strategy
         # resolver never selects it.
         method = resolve_reduction_strategy()
-    if method == "fused":
-        from tmlibrary_tpu.ops.fused_measure import grouped_stats
-
-        sums, _, _ = grouped_stats(labels, channels, max_objects)
-        return sums
     if method == "onehot":
         method = "matmul"
     if method == "native":
@@ -120,9 +114,8 @@ def grouped_sums(
             flat, stacked,
             vmap_method=native.callback_vmap_method(),
         )
-    if method in ("scatter", "sort"):
-        out = segmented_sum(stacked, flat, capacity_segments(max_objects), method)
-        return out[1:]
+    if method == "scatter":
+        return segmented_sum(stacked, flat, capacity_segments(max_objects))[1:]
     if method != "matmul":
         raise ValueError(f"unknown grouped_sums method '{method}'")
     p = flat.shape[0]
@@ -221,24 +214,19 @@ def grouped_minmax(
     the strategy layer: segment_min/max scatters on CPU (see
     :func:`grouped_sums`), the masked reduce elsewhere.  ``"onehot"``
     aliases ``"reduce"`` — min/max have no matmul form, so the dense
-    masked broadcast is that strategy's shape here; all strategies agree
+    masked broadcast is that strategy's shape here; both strategies agree
     bit-exactly (min/max are accumulation-order-free)."""
     flat_l = labels.reshape(-1)
     flat_v = jnp.asarray(values, jnp.float32).reshape(-1)
     if method == "auto":
         # see grouped_minmax_multi: native is explicit opt-in on CPU
         method = resolve_reduction_strategy()
-    if method == "fused":
-        from tmlibrary_tpu.ops.fused_measure import grouped_stats
-
-        _, mn, mx = grouped_stats(labels, [values], max_objects)
-        return mn[:, 0], mx[:, 0]
     if method == "onehot":
         method = "reduce"
-    if method in ("scatter", "sort"):
+    if method == "scatter":
         segs = capacity_segments(max_objects)
-        mn = segmented_min(flat_v, flat_l, segs, method)
-        mx = segmented_max(flat_v, flat_l, segs, method)
+        mn = segmented_min(flat_v, flat_l, segs)
+        mx = segmented_max(flat_v, flat_l, segs)
         return mn[1:], mx[1:]
     if method != "reduce":
         raise ValueError(f"unknown grouped_minmax method '{method}'")
@@ -292,11 +280,6 @@ def grouped_minmax_multi(
         # interaction is understood, and the strategy resolver never
         # selects it
         method = resolve_reduction_strategy()
-    if method == "fused":
-        from tmlibrary_tpu.ops.fused_measure import grouped_stats
-
-        _, mn, mx = grouped_stats(labels, values, max_objects)
-        return mn, mx
     if method == "onehot":
         method = "reduce"
     if method == "native":
@@ -326,10 +309,10 @@ def grouped_minmax_multi(
             flat_l, stacked,
             vmap_method=native.callback_vmap_method(),
         )
-    if method in ("scatter", "sort"):
+    if method == "scatter":
         segs = capacity_segments(max_objects)
-        mn = segmented_min(stacked, flat_l, segs, method)
-        mx = segmented_max(stacked, flat_l, segs, method)
+        mn = segmented_min(stacked, flat_l, segs)
+        mx = segmented_max(stacked, flat_l, segs)
         return mn[1:], mx[1:]
     if method != "reduce":
         raise ValueError(f"unknown grouped_minmax_multi method '{method}'")
@@ -415,31 +398,15 @@ def intensity_features(
     labels = jnp.asarray(labels, jnp.int32)
     img = jnp.asarray(intensity, jnp.float32)
     if method == "auto":
-        # a pinned/requested "fused" strategy outranks the CPU native
-        # heuristic — the megakernel is the thing being requested
-        if resolve_reduction_strategy() == "fused":
-            method = "fused"
-        else:
-            from tmlibrary_tpu import native
+        from tmlibrary_tpu import native
 
-            method = (
-                "native"
-                if native.cpu_native_enabled() and native.has_site_stats()
-                else "xla"
-            )
+        method = (
+            "native"
+            if native.cpu_native_enabled() and native.has_site_stats()
+            else "xla"
+        )
     if method == "native":
         count, total, sq, mn, mx = _native_site_stats(labels, img, max_objects)
-    elif method == "fused":
-        # all five accumulators in ONE megakernel pass: count/sum/sumsq
-        # from the sum columns, min/max of the intensity channel from the
-        # same shared one-hot (the unfused path takes two full passes)
-        from tmlibrary_tpu.ops.fused_measure import grouped_stats
-
-        sums, mns, mxs = grouped_stats(
-            labels, [jnp.ones_like(img), img, img * img], max_objects
-        )
-        count, total, sq = sums[:, 0], sums[:, 1], sums[:, 2]
-        mn, mx = mns[:, 1], mxs[:, 1]
     else:
         sums = grouped_sums(
             labels, [jnp.ones_like(img), img, img * img], max_objects
@@ -485,9 +452,9 @@ def intensity_quantiles(
 
     ``method`` selects the histogram-accumulation strategy
     (``ops/reduction.py``): ``"onehot"`` the dual one-hot contraction,
-    ``"scatter"``/``"sort"`` a fused (label*bins + bucket) index into one
-    segmented count.  Counts are integers < 2^24 → exact in f32, so every
-    strategy returns bit-identical quantiles.
+    ``"scatter"`` a fused (label*bins + bucket) index into one segmented
+    count.  Counts are integers < 2^24 → exact in f32, so both return
+    bit-identical quantiles.
     """
     labels = jnp.asarray(labels, jnp.int32)
     img = jnp.asarray(intensity, jnp.float32)
@@ -496,17 +463,6 @@ def intensity_quantiles(
     lo = jnp.where(present, raw_lo, 0.0)
     span = jnp.where(present, raw_hi - lo, 1.0)
     strategy = resolve_reduction_strategy(method)
-    if strategy == "fused":
-        # quantization + accumulation inside the megakernel; the bounds
-        # come from the fused min/max above, so counts (exact integers)
-        # are bit-identical to every other strategy
-        from tmlibrary_tpu.ops.fused_measure import intensity_hist
-
-        counts = intensity_hist(
-            labels, img, max_objects, bins, (raw_lo, raw_hi)
-        )
-        return _quantiles_from_counts(counts, lo, span, present, qs, bins)
-
     q_pix = quantize_per_object(
         labels, img, max_objects, bins, bounds=(raw_lo, raw_hi)
     )
@@ -517,12 +473,11 @@ def intensity_quantiles(
     # plain fused-index scatter is the fast path (see grouped_sums).
     lab_flat = labels.reshape(-1)
     q_flat = q_pix.reshape(-1)
-    if strategy in ("scatter", "sort"):
+    if strategy == "scatter":
         idx = lab_flat * bins + q_flat
         segs = capacity_segments(max_objects)
         counts = segmented_sum(
-            jnp.ones_like(idx, jnp.float32), idx,
-            segs * bins, strategy,
+            jnp.ones_like(idx, jnp.float32), idx, segs * bins
         ).reshape(segs, bins)[1:]
         return _quantiles_from_counts(counts, lo, span, present, qs, bins)
     p = lab_flat.shape[0]
@@ -646,19 +601,10 @@ def morphology_features(labels: jax.Array, max_objects: int) -> dict[str, jax.Ar
     boundary = boundary & (labels > 0)
 
     chans = [ones, yy, xx, boundary.astype(jnp.float32)]
-    if resolve_reduction_strategy() == "fused":
-        # the per-object sums AND the bounding box from ONE megakernel
-        # pass — the min/max of the yy/xx channels ride the same shared
-        # one-hot as the sums (the unfused path below is two passes)
-        from tmlibrary_tpu.ops.fused_measure import grouped_stats
-
-        sums, mins_all, maxs_all = grouped_stats(labels, chans, max_objects)
-        mins, maxs = mins_all[:, 1:3], maxs_all[:, 1:3]
-    else:
-        # all per-object sums in one MXU pass
-        sums = grouped_sums(labels, chans, max_objects)
-        # bounding box: both axes' min/max in ONE pass over the pixels
-        mins, maxs = grouped_minmax_multi(labels, [yy, xx], max_objects)
+    # all per-object sums in one MXU pass
+    sums = grouped_sums(labels, chans, max_objects)
+    # bounding box: both axes' min/max in ONE pass over the pixels
+    mins, maxs = grouped_minmax_multi(labels, [yy, xx], max_objects)
     area = sums[:, 0]
     safe_a = jnp.maximum(area, 1.0)
     perimeter = sums[:, 3]
@@ -809,13 +755,10 @@ def _glcm_scatter(
     max_objects: int,
     levels: int,
     offset: tuple[int, int],
-    strategy: str = "scatter",
 ) -> jax.Array:
-    """GLCM accumulation via one segmented count per direction over fused
-    (label, q1, q2) cell indices — ``strategy="scatter"`` (portable
-    fallback; fastest on CPU where scatters are cheap) or ``"sort"`` (the
-    deterministic sorted-run form; counts are order-free integers, so the
-    result is bit-identical either way)."""
+    """GLCM accumulation via one scatter-add per direction over fused
+    (label, q1, q2) cell indices: the CPU platform's path (scatters are
+    cheap there) and the reference the contraction is compared with."""
     dy, dx = offset
     lab2 = shift_with_fill(labels, -dy, -dx, 0)
     q2 = shift_with_fill(quantized, -dy, -dx, 0)
@@ -831,7 +774,6 @@ def _glcm_scatter(
         valid.reshape(-1).astype(jnp.float32),
         idx.reshape(-1),
         capacity_segments(max_objects) * levels * levels,
-        strategy,
     )
     glcm = counts.reshape(capacity_segments(max_objects), levels, levels)[1:]
     return glcm + jnp.swapaxes(glcm, 1, 2)
@@ -842,31 +784,22 @@ def _resolve_glcm_method(method: str) -> str:
         return "matmul"
     if method != "auto":
         return method
-    # an explicit strategy request (CLI env, config, the tuned
-    # reduction_strategy verdict, or a build-time pin) overrides the
-    # backend heuristics below — including GLCM's own matmul-vs-scatter
-    # verdict, which only decides genuinely-unrequested "auto"
-    requested = explicit_reduction_request()
-    if requested is not None:
-        return "matmul" if requested == "onehot" else requested
-    backend = jax.default_backend()
-    if backend == "cpu":
-        # "native" (tm_site_glcm: quantization + all 4 GLCMs in one C
-        # pass, bit-identical — counts are exact integers) stays an
-        # EXPLICIT opt-in like the channel-sum kernels: auto-routing it
-        # stalled XLA-CPU's runtime from batch 16 up regardless of vmap
-        # method (batch 8 and the whole existing callback family run
-        # fine; the direct C call does the full batch-128 workload in
-        # 0.12 s), so the stall is a runtime interaction this release
-        # does not ship on by default.
-        return "scatter"
-    if backend == "tpu":
-        # the committed tuning verdict was measured on a TPU — scope it
-        from tmlibrary_tpu.ops.pallas_kernels import _tuning_results
-
-        wins = _tuning_results().get("glcm_matmul_wins")
-        return "matmul" if wins in (None, True) else "scatter"
-    return "matmul"  # gpu and friends: untuned, keep the matmul default
+    # "native" (tm_site_glcm: quantization + all 4 GLCMs in one C pass,
+    # bit-identical — counts are exact integers) stays an EXPLICIT opt-in
+    # like the channel-sum kernels: auto-routing it stalled XLA-CPU's
+    # runtime from batch 16 up regardless of vmap method (batch 8 and the
+    # whole existing callback family run fine; the direct C call does the
+    # full batch-128 workload in 0.12 s), so the stall is a runtime
+    # interaction this release does not ship on by default.
+    #
+    # Off the CPU the contraction is a constant, not a tuned verdict: on
+    # a TPU v5e at one 2160x2160 field and capacity 1024 it takes 81.0 ms
+    # against 180 for the scatter (and 161 / 340 for the Pallas kernel
+    # and the sorted form that were retired for it) — XLA fuses the
+    # one-hot into the dot, 79.9 % of the MXU's peak
+    # (scripts/tune_measure_tpu.py, PR 27; tuning/TUNING.json ``glcm_ms``;
+    # until PR 29 a ``glcm_matmul_wins`` key there was read back here).
+    return "scatter" if jax.default_backend() == "cpu" else "matmul"
 
 
 def quantize_per_object(
@@ -970,20 +903,9 @@ def haralick_features(
             vmap_method="sequential",
         )
         glcms = [packed[d] for d in range(4)]
-    elif method == "fused" and quantization == "object":
-        # quantization + all 4 directions in the fused Pallas pass; the
-        # bounds come from the fused stats kernel (counts are exact
-        # integers, the per-object stretch the same f32 expression tree,
-        # so the GLCMs are bit-identical to the matmul/scatter paths)
-        from tmlibrary_tpu.ops.fused_measure import glcm_all
-
-        bounds = grouped_minmax(labels, img, max_objects, method="fused")
-        glcms = glcm_all(labels, img, max_objects, levels, offsets, bounds)
     else:
         if method == "native":
             method = "scatter"  # global quantization: no native path
-        if method == "fused":
-            method = "matmul"  # global quantization: no per-object bounds
         if quantization == "object":
             q = quantize_per_object(labels, img, max_objects, levels)
         elif quantization == "global":
@@ -999,9 +921,9 @@ def haralick_features(
         if method == "matmul":
             # all 4 directions share each chunk's row one-hot in one pass
             glcms = _glcm_matmul_all(labels, q, max_objects, levels, offsets)
-        elif method in ("scatter", "sort"):
+        elif method == "scatter":
             glcms = [
-                _glcm_scatter(labels, q, max_objects, levels, off, method)
+                _glcm_scatter(labels, q, max_objects, levels, off)
                 for off in offsets
             ]
         else:

@@ -2,81 +2,66 @@
 
 Every per-object measurement in ``ops/measure.py`` is a segmented
 reduction over the label image: per-object sums/min/max, the quantile
-histogram rows, the GLCM cells.  Three device strategies compute the same
-reduction with very different hardware profiles, and the right one is a
-property of the backend (and ultimately a *measured* verdict, not a
-hardcode — see "Tuning for Tissue Image Segmentation Workflows",
-PAPERS.md):
+histogram rows, the GLCM cells.  Two strategies compute the same
+reduction, and which one runs is a property of the backend:
 
 ``"onehot"``
-    Contract a one-hot of the segment ids against the values on the MXU
-    (``jnp.einsum`` at ``Precision.HIGHEST``, chunked over pixels).  Rides
-    the matrix unit on TPU; the one-hot materialization is ~25x overhead
-    on CPU.  min/max have no matmul form, so "onehot" there means the
-    dense masked-broadcast reduce (the same memory shape: pixels ×
-    segments).  The specialized one-hot kernels live at their call sites
-    in ``ops/measure.py`` — they exploit factored structure (shared GLCM
-    row one-hots, dual label×bucket contractions) a generic primitive
-    cannot.
-``"sort"``
-    ``jax.lax.sort_key_val`` by segment id (stable), then
-    ``jax.ops.segment_{sum,min,max}`` over the sorted runs with
-    ``indices_are_sorted=True``.  Exactly deterministic run-to-run: the
-    stable sort fixes the within-segment accumulation order to pixel
-    order regardless of how XLA schedules the scatter.
+    The accelerators' path.  Contract a one-hot of the segment ids
+    against the values on the MXU (``jnp.einsum`` at
+    ``Precision.HIGHEST``, chunked over pixels).  min/max have no matmul
+    form, so "onehot" there means the dense masked-broadcast reduce (the
+    same memory shape: pixels x segments).  The one-hot kernels live at
+    their call sites in ``ops/measure.py`` — they exploit factored
+    structure (shared GLCM row one-hots, dual label x bucket
+    contractions) a generic primitive cannot.  On the CPU the one-hot
+    materialization costs ~25x the scatter.
 ``"scatter"``
-    Direct ``.at[ids].add/min/max`` scatters — cheapest on CPU where
-    scatters lower to serial element updates anyway.
-``"fused"``
-    The Pallas measure megakernels (``ops/fused_measure.py``): the site
-    tile streams through VMEM once per kernel while the per-object
-    accumulators (sums, min/max, quantile histogram, GLCM cells) stay
-    resident on chip — one HBM read of the tile instead of one per
-    reduction family.  Off-TPU the kernels run in interpret mode, so
-    the strategy is selectable (and parity-tested) everywhere.  Like
-    ``"onehot"``, its kernels live at the measure call sites; the
-    generic ``segmented_*`` primitives have no fused path.
+    The CPU platform's path, and the reference the parity tests compare
+    ``onehot`` against.  Direct ``.at[ids].add/min/max`` scatters
+    (:func:`segmented_sum` / ``_min`` / ``_max`` below) — cheap where
+    scatters lower to serial element updates anyway, serialized on a TPU.
 
-Determinism contract (pinned by ``tests/test_reduction.py`` on CPU):
-min/max agree bit-exactly across all strategies (order-free); counts and
-integer-valued sums (uint16 microscopy pixels, histogram/GLCM cells) are
-exact in f32 and therefore bit-identical across all strategies; general
-fp32 sums may differ from the one-hot reference in the last ulps
-(documented tolerance 1e-6 relative) because the accumulation order
-differs — ``fused`` shares that tolerance (chunked MXU accumulation in
-a different order) — while sort-vs-scatter stay bit-identical to each
-other on CPU (same pixel-order accumulation).
+Determinism contract (pinned by ``tests/test_reduction.py`` on the CPU):
+min/max agree bit-exactly (order-free); counts and integer-valued sums
+(uint16 microscopy pixels, histogram/GLCM cells) are exact in f32 and
+therefore bit-identical; fractional f32 sums of ``onehot`` are within
+1e-6 relative of ``scatter`` (the accumulation order differs).
 
-``"auto"`` resolution order (highest first): a pinned build-time scope
-(:func:`strategy_scope` — how compiled batch programs freeze their
-choice), the ``TMX_REDUCTION_STRATEGY`` env (the CLI
-``--reduction-strategy`` knob), the install config
-(``TM_REDUCTION_STRATEGY`` / INI ``reduction_strategy``), the
-provenance-gated ``reduction_strategy`` entry of ``tuning/TUNING.json``
-(written by ``bench.py --sweep``; same gate as ``glcm`` and
-``pipeline_depth``), then a backend-safe default: ``scatter`` on CPU
-(pure XLA — the host-callback routes documented in ``measure.py`` hang
-XLA-CPU's runtime when auto-routed, so auto never selects them),
-``onehot`` on accelerators.
+Resolution (:func:`resolve_reduction_strategy`): an explicit ``method=``
+on the call, else ``"scatter"`` on the cpu backend and ``"onehot"`` on
+every other.  Nothing else selects a strategy: no environment variable,
+no configuration key, no tuning entry.  ``method=`` stays so that tests
+and ``scripts/tune_measure_tpu.py`` can run the chip's path on the CPU.
+(The host-callback routes documented in ``measure.py`` — ``"native"`` —
+are explicit opt-ins of their call sites and never resolved to.)
+
+Why two and not four: a ``"sort"`` strategy (stable sort by segment id,
+sorted-run segment reductions) and a ``"fused"`` one (Pallas measure
+megakernels) existed until PR 29, selectable through an environment
+variable, a CLI flag, an INI key, a step argument and a tuning verdict.
+The chip decided (``scripts/tune_measure_tpu.py``, one 2160x2160 field at
+capacity 1024 on a TPU v5e, PR 27; kept in ``tuning/TUNING.json`` under
+``reduction_strategy_ms`` and ``glcm_ms``): ``onehot`` wins every family
+— intensity 17.7 ms against ``fused`` 37.6, ``scatter`` 128, ``sort``
+192; morphology 45.8 / 87.4 / 185 / 268; Zernike 64.4 / 264 / 154 / 384
+— and the XLA contraction wins the GLCM (81.0 ms against the Pallas
+kernel's 161, ``scatter`` 180, ``sort`` 340).  On the CPU ``scatter`` was
+the default and ``sort`` never selected, so the two won on no backend.
 """
 
 from __future__ import annotations
-
-import contextlib
-import os
-import threading
 
 import jax
 import jax.numpy as jnp
 
 #: the explicit strategies; "auto" resolves to one of these
-STRATEGIES = ("onehot", "sort", "scatter", "fused")
+STRATEGIES = ("onehot", "scatter")
 
 
 def capacity_segments(capacity: int) -> int:
     """Segment count for an object-capacity of ``capacity``: one row per
     object id plus row 0 for background — the ONE place the capacity →
-    ``num_segments`` convention lives for all three strategies.
+    ``num_segments`` convention lives for both strategies.
 
     Capacity-invariance contract (pinned by ``tests/test_reduction.py``
     and relied on by the bucket router in ``tmlibrary_tpu.capacity``):
@@ -88,161 +73,44 @@ def capacity_segments(capacity: int) -> int:
     not."""
     return int(capacity) + 1
 
-_PIN = threading.local()
-_UNSET = object()
-
-
-@contextlib.contextmanager
-def strategy_scope(strategy: "str | None"):
-    """Pin the *requested* strategy for the duration of a trace.
-
-    ``build_batch_fn`` resolves the request ONCE at build time and wraps
-    the traced site function in this scope, so the compiled program is a
-    pure function of the build-time choice — env/config changes between
-    build and (lazy) first-call trace cannot make the program disagree
-    with its compiled-program cache key.  ``None`` pins "no explicit
-    request": inside the scope resolution goes straight to the backend
-    defaults (and GLCM keeps its own tuned ``glcm_matmul_wins`` verdict)
-    instead of re-reading the live env."""
-    if strategy is not None:
-        _validate(strategy)
-    prev = getattr(_PIN, "value", _UNSET)
-    _PIN.value = strategy
-    try:
-        yield
-    finally:
-        if prev is _UNSET:
-            del _PIN.value
-        else:
-            _PIN.value = prev
-
-
-def _validate(strategy: str) -> None:
-    if strategy not in STRATEGIES:
-        raise ValueError(
-            f"unknown reduction strategy '{strategy}' "
-            f"(expected one of {STRATEGIES} or 'auto')"
-        )
-
-
-def requested_reduction_strategy() -> "str | None":
-    """The explicitly-requested strategy — env (CLI) beats config beats
-    the tuned verdict — or None when nothing asks for one.  Explicit
-    requests fail LOUD on an unknown name; a malformed machine-written
-    tuning entry is ignored instead (stale data must degrade to the
-    default, not crash production)."""
-    env = os.environ.get("TMX_REDUCTION_STRATEGY")
-    if env:
-        _validate(env)
-        return env
-    from tmlibrary_tpu.config import _setting
-
-    configured = _setting("reduction_strategy", "auto")
-    if configured and configured != "auto":
-        _validate(configured)
-        return configured
-    from tmlibrary_tpu.tuning import tuned_reduction_strategy
-
-    return tuned_reduction_strategy(jax.default_backend())
-
-
-def explicit_reduction_request() -> "str | None":
-    """The explicit strategy request in effect, or None.  Inside a
-    :func:`strategy_scope` this is the build-time pin (which may be None:
-    "the build had no request"); outside it is the live env/config/tuned
-    chain.  GLCM dispatch consults this: only an *explicit* request
-    overrides its own tuned ``glcm_matmul_wins`` verdict."""
-    pinned = getattr(_PIN, "value", _UNSET)
-    if pinned is not _UNSET:
-        return pinned
-    return requested_reduction_strategy()
-
 
 def resolve_reduction_strategy(method: str = "auto") -> str:
-    """Resolve ``method`` to a concrete strategy name (see module
-    docstring for the precedence chain)."""
+    """Resolve ``method`` to a concrete strategy name: an explicit name
+    wins, ``"auto"`` is the backend's (see the module docstring)."""
     if method and method != "auto":
-        _validate(method)
+        if method not in STRATEGIES:
+            raise ValueError(
+                f"unknown reduction strategy '{method}' "
+                f"(expected one of {STRATEGIES} or 'auto')"
+            )
         return method
-    requested = explicit_reduction_request()
-    if requested is not None:
-        return requested
     return "scatter" if jax.default_backend() == "cpu" else "onehot"
 
 
-# ----------------------------------------------------------- sort machinery
-def sort_by_segment(
-    segment_ids: jax.Array, *values: jax.Array
-) -> tuple[jax.Array, ...]:
-    """Stable-sort flat ``values`` rows by ``segment_ids``; returns
-    ``(sorted_ids, sorted_value0, ...)``.  The stable sort keeps
-    within-segment pixel order, which makes every downstream sorted-run
-    reduction exactly deterministic."""
-    flat = segment_ids.reshape(-1)
-    iota = jnp.arange(flat.shape[0], dtype=jnp.int32)
-    sorted_ids, order = jax.lax.sort_key_val(flat, iota, is_stable=True)
-    return (sorted_ids,) + tuple(
-        jnp.take(v, order, axis=0) for v in values
-    )
-
-
 # ------------------------------------------------------------- primitives
+# The scatter strategy.  Three functions, because each hides its
+# reduction's identity element (what an absent segment reads).
 def segmented_sum(
-    values: jax.Array,
-    segment_ids: jax.Array,
-    num_segments: int,
-    strategy: str = "scatter",
+    values: jax.Array, segment_ids: jax.Array, num_segments: int
 ) -> jax.Array:
-    """Per-segment sums of ``values`` (``(P,)`` or ``(P, C)``) for the
-    ``sort`` and ``scatter`` strategies (the one-hot matmul forms stay at
-    their specialized call sites in ``ops/measure.py``)."""
-    if strategy == "sort":
-        ids, vals = sort_by_segment(segment_ids, values)
-        return jax.ops.segment_sum(
-            vals, ids, num_segments=num_segments, indices_are_sorted=True
-        )
-    if strategy == "scatter":
-        init = jnp.zeros((num_segments,) + values.shape[1:], values.dtype)
-        return init.at[segment_ids].add(values)
-    raise ValueError(f"segmented_sum has no '{strategy}' path")
+    """Per-segment sums of ``values`` (``(P,)`` or ``(P, C)``); absent
+    segments come back 0."""
+    init = jnp.zeros((num_segments,) + values.shape[1:], values.dtype)
+    return init.at[segment_ids].add(values)
 
 
 def segmented_min(
-    values: jax.Array,
-    segment_ids: jax.Array,
-    num_segments: int,
-    strategy: str = "scatter",
+    values: jax.Array, segment_ids: jax.Array, num_segments: int
 ) -> jax.Array:
     """Per-segment minima; absent segments come back +inf (the identity),
     matching ``jax.ops.segment_min``."""
-    if strategy == "sort":
-        ids, vals = sort_by_segment(segment_ids, values)
-        return jax.ops.segment_min(
-            vals, ids, num_segments=num_segments, indices_are_sorted=True
-        )
-    if strategy == "scatter":
-        init = jnp.full(
-            (num_segments,) + values.shape[1:], jnp.inf, values.dtype
-        )
-        return init.at[segment_ids].min(values)
-    raise ValueError(f"segmented_min has no '{strategy}' path")
+    init = jnp.full((num_segments,) + values.shape[1:], jnp.inf, values.dtype)
+    return init.at[segment_ids].min(values)
 
 
 def segmented_max(
-    values: jax.Array,
-    segment_ids: jax.Array,
-    num_segments: int,
-    strategy: str = "scatter",
+    values: jax.Array, segment_ids: jax.Array, num_segments: int
 ) -> jax.Array:
     """Per-segment maxima; absent segments come back -inf."""
-    if strategy == "sort":
-        ids, vals = sort_by_segment(segment_ids, values)
-        return jax.ops.segment_max(
-            vals, ids, num_segments=num_segments, indices_are_sorted=True
-        )
-    if strategy == "scatter":
-        init = jnp.full(
-            (num_segments,) + values.shape[1:], -jnp.inf, values.dtype
-        )
-        return init.at[segment_ids].max(values)
-    raise ValueError(f"segmented_max has no '{strategy}' path")
+    init = jnp.full((num_segments,) + values.shape[1:], -jnp.inf, values.dtype)
+    return init.at[segment_ids].max(values)

@@ -32,7 +32,7 @@ place the XLA cost model is read and interpreted:
   :func:`speculate_compile` lets a background thread precompile the
   likely next capacity rung so escalation lands ``warm``;
 * a process-wide profile store (:func:`perf_profiles` /
-  :func:`perf_snapshot`) keyed by (program, step, capacity, strategy),
+  :func:`perf_snapshot`) keyed by (program, step, capacity),
   mirrored into ``tmx_perf_*`` registry metrics and persisted by the
   engine as ``workflow/perf.json`` for ``tmx perf``;
 * the **bench-history sentinel** (:func:`compare_history`) behind
@@ -214,7 +214,7 @@ def flops_fields(flops, n_items, best_s, device_kind,
 # Per-program attribution store + compile telemetry
 
 _LOCK = threading.Lock()
-#: (program, step, capacity, strategy) -> serializable profile dict
+#: (program, step, capacity) -> serializable profile dict
 _PROFILES: dict[tuple, dict] = {}
 #: same key -> runtime state {"sigs": {signature: compiled|None}, "dead": bool}
 _RUNTIME: dict[tuple, dict] = {}
@@ -248,7 +248,7 @@ def perf_snapshot() -> dict:
 
 
 def record_compile(*, program: str, step: str = "jterator",
-                   capacity: int | None = None, strategy: str | None = None,
+                   capacity: int | None = None,
                    backend: str = "unknown", compile_s: float | None = None,
                    cost: ProgramCost | None = None,
                    recompile: bool = False) -> dict:
@@ -257,13 +257,12 @@ def record_compile(*, program: str, step: str = "jterator",
     histogram per capacity rung, recompile counter, static cost gauges).
     Telemetry failures never propagate."""
     cost = cost or ProgramCost()
-    key = (program, step, capacity, strategy)
+    key = (program, step, capacity)
     with _LOCK:
         entry = _PROFILES.setdefault(key, {
             "program": program,
             "step": step,
             "capacity": capacity,
-            "strategy": strategy,
             "backend": backend,
             "flops": None,
             "bytes": None,
@@ -299,7 +298,6 @@ def record_compile(*, program: str, step: str = "jterator",
                 "program": str(program),
                 "step": str(step),
                 "capacity": str(capacity) if capacity else "none",
-                "strategy": str(strategy) if strategy else "auto",
             }
             reg.counter("tmx_perf_compiles_total", **labels).inc()
             if recompile:
@@ -341,7 +339,6 @@ def _args_signature(args, kwargs):
 def instrument_batch_fn(fn: Callable, *, program: str,
                         step: str = "jterator",
                         capacity: int | None = None,
-                        strategy: str | None = None,
                         sub_costs: Callable | None = None) -> Callable:
     """Wrap a jitted batch fn with compile/cost attribution.
 
@@ -361,7 +358,7 @@ def instrument_batch_fn(fn: Callable, *, program: str,
     sub-programs (the dl configs' conv forward, whose arithmetic
     intensity the whole-program XLA readout averages away under the
     decoder's integer traffic) get their own ``bound_by`` attribution."""
-    key = (program, step, capacity, strategy)
+    key = (program, step, capacity)
 
     def wrapped(*args, **kwargs):
         from tmlibrary_tpu import telemetry
@@ -389,7 +386,7 @@ def _any_deleted(args, kwargs) -> bool:
 def _instrumented_call(fn, key, args, kwargs, sub_costs=None):
     from tmlibrary_tpu import aotstore
 
-    program, step, capacity, strategy = key
+    program, step, capacity = key
     sig = _args_signature(args, kwargs)
     with _LOCK:
         state = _RUNTIME.setdefault(key, {"sigs": {}, "dead": False})
@@ -416,7 +413,6 @@ def _instrumented_call(fn, key, args, kwargs, sub_costs=None):
             # so the zero-new-compiles pinning (warm-start tests / CI
             # smoke) holds; the profile store still learns about it
             record_import(program=program, step=step, capacity=capacity,
-                          strategy=strategy,
                           saved_s=meta.get("compile_s"))
         else:
             import jax
@@ -441,7 +437,7 @@ def _instrumented_call(fn, key, args, kwargs, sub_costs=None):
                     state["sigs"][sig] = compiled
             backend = jax.default_backend()
             record_compile(program=program, step=step, capacity=capacity,
-                           strategy=strategy, backend=backend,
+                           backend=backend,
                            compile_s=compile_s, cost=cost,
                            recompile=recompile)
             if compiled is not None:
@@ -449,14 +445,14 @@ def _instrumented_call(fn, key, args, kwargs, sub_costs=None):
                 if aotstore.cache_hits_seen() == cache_hits:
                     aotstore.export_entry(
                         compiled, program=program, step=step,
-                        capacity=capacity, strategy=strategy,
+                        capacity=capacity,
                         signature=sig, compile_s=compile_s,
                     )
             if sub_costs is not None:
                 for sub_name, sub_cost in sub_costs(args, kwargs):
                     record_compile(
                         program=f"{program}:{sub_name}", step=step,
-                        capacity=capacity, strategy=strategy,
+                        capacity=capacity,
                         backend=backend, cost=sub_cost,
                         recompile=recompile,
                     )
@@ -471,8 +467,8 @@ def _instrumented_call(fn, key, args, kwargs, sub_costs=None):
         with _LOCK:
             state["sigs"][sig] = None
         logger.warning(
-            "perf: AOT executable of %s (capacity %s, %s) failed, dropped "
-            "in favour of jit: %s: %s", program, capacity, strategy,
+            "perf: AOT executable of %s (capacity %s) failed, dropped "
+            "in favour of jit: %s: %s", program, capacity,
             type(exc).__name__, exc,
         )
         if _any_deleted(args, kwargs):
@@ -486,29 +482,28 @@ def _instrumented_call(fn, key, args, kwargs, sub_costs=None):
 # Serialized-executable store hooks + compile-ahead speculation
 
 def _try_store_import(key, sig):
-    """Look the (program, capacity, strategy, signature) executable up in
+    """Look the (program, capacity, signature) executable up in
     the serialized store.  None on a miss or when the store is off;
     ``aotstore.import_entry`` reports a refused artifact itself."""
     from tmlibrary_tpu import aotstore
 
-    program, _step, capacity, strategy = key
+    program, _step, capacity = key
     return aotstore.import_entry(program=program, capacity=capacity,
-                                 strategy=strategy, signature=sig)
+                                 signature=sig)
 
 
 def record_import(*, program: str, step: str = "jterator",
-                  capacity: int | None = None, strategy: str | None = None,
+                  capacity: int | None = None,
                   saved_s: float | None = None) -> dict:
     """Record one store import hit in the profile store.  Deliberately
     does NOT touch the compile counters — an import is the *absence* of
     a compile, and the warm-start tests pin that distinction."""
-    key = (program, step, capacity, strategy)
+    key = (program, step, capacity)
     with _LOCK:
         entry = _PROFILES.setdefault(key, {
             "program": program,
             "step": step,
             "capacity": capacity,
-            "strategy": strategy,
             "backend": "unknown",
             "flops": None,
             "bytes": None,
@@ -588,7 +583,7 @@ def speculate_compile(wrapped_fn, args, kwargs) -> str | None:
         compiled, meta = imported
         if adopt_executable(key, sig, compiled):
             record_import(program=key[0], step=key[1], capacity=key[2],
-                          strategy=key[3], saved_s=meta.get("compile_s"))
+                          saved_s=meta.get("compile_s"))
             return "imported"
         return "known"
     from tmlibrary_tpu import aotstore
@@ -608,7 +603,7 @@ def speculate_compile(wrapped_fn, args, kwargs) -> str | None:
         try:
             aotstore.export_entry(
                 compiled, program=key[0], step=key[1], capacity=key[2],
-                strategy=key[3], signature=sig, compile_s=compile_s,
+                signature=sig, compile_s=compile_s,
             )
         except Exception:
             pass
@@ -669,8 +664,7 @@ def _methodology_class(rec: dict) -> str:
     the specific fetch depth may drift with tuning, but a pipelined
     capture must never be judged against a host-synchronous one (the
     fetch tax makes them different experiments), nor a bucket-routed
-    capture against a full-capacity one, nor a fused-megakernel capture
-    against an unfused one (a different measure-family program), nor a
+    capture against a full-capacity one, nor a
     model-backed capture (the ``dl`` config) against one that ran a
     different checkpoint — the ``model=<digest>`` provenance token
     survives the collapse so the sentinel never compares across
@@ -681,8 +675,6 @@ def _methodology_class(rec: dict) -> str:
         return "legacy"
     if m.startswith("pipelined"):
         cls = "pipelined+bucketed" if "bucketed" in m else "pipelined"
-        if "strategy=fused" in m:
-            cls += "+fused"
         # work-aware site scheduling changes the dispatch plan (packed
         # rung-homogeneous batches vs directory order) — a packed capture
         # is a different experiment from an unpacked one

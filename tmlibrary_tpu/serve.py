@@ -25,7 +25,7 @@ Execution reuses the whole engine stack: each job is one
 experiment store (``resume=True`` whenever the job's ledger already
 exists, so re-admitted work converges bit-identically).  Jobs from
 different tenants that route to the same compiled program — same
-pipeline content, capacity rung and strategy — coalesce for free on the
+pipeline content and capacity rung — coalesce for free on the
 process-level ``cached_batch_fn`` / AOT caches; keeping the daemon
 resident is precisely what makes cross-job compile reuse possible.
 

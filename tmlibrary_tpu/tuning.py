@@ -141,42 +141,17 @@ def tuned_batch_size() -> int | None:
     return _positive_int(tuning.get("best_batch")) if tuning else None
 
 
-_REDUCTION_STRATEGIES = ("onehot", "sort", "scatter", "fused")
-
-
-def tuned_reduction_strategy(backend: str | None = None) -> str | None:
-    """The swept grouped-reduction strategy verdict for ``backend``, or
-    None.  Two shapes are accepted: a per-backend dict
-    (``{"cpu": "scatter", "tpu": "onehot"}`` — what ``bench.py --sweep``
-    writes via :func:`record_config_sweep`) or a plain string scoped by
-    the file's top-level ``backend`` field.  A verdict measured on one
-    backend never sets another backend's default, and malformed values
-    degrade to None (the static default) rather than erroring."""
-    tuning = load_tuning()
-    if not tuning:
-        return None
-    if backend is None:
-        import jax
-
-        backend = jax.default_backend()
-    entry = tuning.get("reduction_strategy")
-    if isinstance(entry, dict):
-        value = entry.get(backend)
-    elif isinstance(entry, str) and tuning.get("backend") == backend:
-        value = entry
-    else:
-        value = None
-    return value if value in _REDUCTION_STRATEGIES else None
-
-
 def tuned_object_capacity(backend: str | None = None) -> int | None:
     """The swept object-capacity bucket verdict for ``backend``, or None.
 
-    ``bench.py --sweep`` records the winning capacity (``best_capacity``)
-    when ``BENCH_SWEEP_CAPACITIES`` puts the bucket ladder on the grid;
-    the jterator step uses it as the first-batch routing hint before any
-    on-run object counts exist.  Same provenance and backend-scoping
-    rules as :func:`tuned_reduction_strategy`."""
+    A sweep records the winning capacity through
+    :func:`record_config_sweep` (``best_capacity``); the jterator step
+    uses it as the first-batch routing hint before any on-run object
+    counts exist.  Two shapes are accepted: a per-backend dict
+    (``{"cpu": 64, "tpu": 1024}``) or a plain value scoped by the file's
+    top-level ``backend`` field.  A verdict measured on one backend never
+    sets another backend's default, and malformed values degrade to None
+    (the static default) rather than erroring."""
     tuning = load_tuning()
     if not tuning:
         return None
@@ -197,12 +172,12 @@ _SCHEDULE_MODES = ("pack", "off")
 
 def tuned_schedule(backend: str | None = None) -> str | None:
     """The swept work-aware scheduling verdict for ``backend``
-    (``"pack"`` | ``"off"``), or None.  ``bench.py --sweep`` records the
-    winner (``best_schedule``) when ``BENCH_SWEEP_SCHEDULE`` puts the
-    packing axis on the grid; the jterator dispatch plane consumes it
-    through ``workflow.schedule.resolve_schedule``'s precedence chain.
-    Same provenance and backend-scoping rules as
-    :func:`tuned_reduction_strategy` — a verdict measured on one backend
+    (``"pack"`` | ``"off"``), or None.  A sweep records the winner
+    through :func:`record_config_sweep` (``best_schedule``); the jterator
+    dispatch plane consumes it through
+    ``workflow.schedule.resolve_schedule``'s precedence chain.  Same
+    provenance and backend-scoping rules as
+    :func:`tuned_object_capacity` — a verdict measured on one backend
     never sets another's default, and malformed values degrade to None
     (the default: packing on)."""
     tuning = load_tuning()
@@ -230,7 +205,7 @@ def tuned_analytics_index(backend: str | None = None) -> str | None:
     (``"ivf"`` | ``"brute"``), or None.  ``bench.py`` BENCH_CONFIG=
     analytics records the winner (``best_index``) when the sweep is
     asked to persist its verdict; same provenance and backend-scoping
-    rules as :func:`tuned_reduction_strategy` — a verdict measured on
+    rules as :func:`tuned_object_capacity` — a verdict measured on
     one backend never sets another's default, and malformed values
     degrade to None (the auto size cutover)."""
     tuning = load_tuning()
@@ -253,13 +228,13 @@ def tuned_analytics_index(backend: str | None = None) -> str | None:
 def record_config_sweep(config: str, entry: dict) -> dict:
     """Merge one per-config sweep verdict into the tuning file.
 
-    ``bench.py --sweep`` calls this once per ``BENCH_CONFIG`` with a row
-    like ``{"backend": ..., "best_pipeline": N, "best_strategy": ...,
-    "rows": [...]}``.  Existing keys written by ``tune_tpu.py`` (the
-    top-level ``best_batch``/``best_pipeline`` and their provenance
-    stamps) are preserved — the sweep only owns ``config_sweeps[config]``
-    and the per-backend ``reduction_strategy`` verdict.  Returns the
-    merged document."""
+    ``bench.py``'s analytics mode calls this with a row like
+    ``{"backend": ..., "best_index": ..., "rows": [...]}``.  Existing
+    keys written by ``tune_tpu.py`` (the top-level
+    ``best_batch``/``best_pipeline`` and their provenance stamps) are
+    preserved — the sweep only owns ``config_sweeps[config]`` and the
+    per-backend verdicts its entry names (``best_capacity``,
+    ``best_schedule``, ``best_index``).  Returns the merged document."""
     path = tuning_json_path()
     try:
         with open(path) as f:
@@ -270,22 +245,9 @@ def record_config_sweep(config: str, entry: dict) -> dict:
         data = {}
     # provenance: only stamp authorship when this write creates the file;
     # never claim tune_tpu.py's measurements as our own
-    data.setdefault("written_by", "bench.py --sweep")
+    data.setdefault("written_by", "bench.py analytics sweep")
     data.setdefault("config_sweeps", {})[str(config)] = entry
     backend = entry.get("backend")
-    strategy = entry.get("best_strategy")
-    if backend and strategy in _REDUCTION_STRATEGIES:
-        verdicts = data.get("reduction_strategy")
-        if not isinstance(verdicts, dict):
-            # migrate a legacy plain-string verdict under its backend scope
-            legacy = verdicts if verdicts in _REDUCTION_STRATEGIES else None
-            verdicts = (
-                {data["backend"]: legacy}
-                if legacy and data.get("backend")
-                else {}
-            )
-        verdicts[backend] = strategy
-        data["reduction_strategy"] = verdicts
     capacity = _positive_int(entry.get("best_capacity"))
     if backend and capacity:
         caps = data.get("object_capacity")
@@ -312,22 +274,3 @@ def record_config_sweep(config: str, entry: dict) -> dict:
         path, json.dumps(data, indent=2, sort_keys=True) + "\n"
     )
     return data
-
-
-def config_sweep(config: str, *, model_digest: str | None = None) -> dict | None:
-    """The recorded sweep verdict for ``config``, or None.
-
-    For model-backed configs (bench ``dl``), pass the current weight
-    content digest: a sweep recorded against a DIFFERENT checkpoint is
-    treated as absent rather than served — its depth/strategy/capacity
-    verdicts were measured on different work (PR-8's QC-gate digest
-    lesson, applied to tuning state).  An entry recorded without a
-    digest never matches a digest-constrained read."""
-    tuning = load_tuning()
-    sweeps = tuning.get("config_sweeps") if tuning else None
-    entry = sweeps.get(str(config)) if isinstance(sweeps, dict) else None
-    if not isinstance(entry, dict):
-        return None
-    if model_digest is not None and entry.get("model_digest") != model_digest:
-        return None
-    return entry

@@ -177,15 +177,6 @@ class ImageAnalysisRunner(Step):
                       "the tuned verdict, then packs. Results are "
                       "bit-identical per site either way — scheduling is "
                       "purely a performance decision"),
-        Argument("reduction_strategy", str, default="auto",
-                 choices=("auto", "onehot", "sort", "scatter", "fused"),
-                 help="grouped-reduction strategy for the measurement "
-                      "stack (ops/reduction.py): one-hot MXU matmuls, "
-                      "deterministic sort+segment reductions, direct "
-                      "scatters, or the single-pass Pallas measure "
-                      "megakernels (ops/fused_measure.py); 'auto' "
-                      "follows TMX_REDUCTION_STRATEGY / config / the "
-                      "tuned verdict, then a backend-safe default"),
         Argument("donate_buffers", bool, default=True,
                  help="donate each batch's raw-image/stats/shift device "
                       "buffers to the compiled program so XLA reuses "
@@ -469,7 +460,6 @@ class ImageAnalysisRunner(Step):
                     # TM_DONATE_BUFFERS=0 still disables it); arg False
                     # forces donation off for this run
                     donate=None if args.get("donate_buffers", True) else False,
-                    reduction_strategy=args.get("reduction_strategy", "auto"),
                     qc=qc_on,
                 )
             return self._desc, self._compiled[cache_key]
@@ -1445,8 +1435,6 @@ class ImageAnalysisRunner(Step):
                     desc, int(rung), self._window,
                     donate=None if args.get("donate_buffers", True)
                     else False,
-                    reduction_strategy=args.get("reduction_strategy",
-                                                "auto"),
                     qc=qc_mod.enabled(),
                 )
                 outcome = perf.speculate_compile(fn, abs_args, abs_kwargs)
