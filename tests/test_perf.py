@@ -213,6 +213,46 @@ def test_instrument_batch_fn_counts_compiles_and_recompiles():
     assert "tmx_perf_program_arithmetic_intensity" in gauges
 
 
+def test_instrument_batch_fn_builds_a_program_once_for_many_threads():
+    """Four persist workers re-launching fields at one rung ask for the
+    same unseen signature at once: one of them compiles (or imports), the
+    others wait and take its executable."""
+    import threading
+    import time
+
+    jitted = jax.jit(lambda x: x + 1.0)
+    lowered = []
+
+    class Slow:
+        def __call__(self, x):
+            return jitted(x)
+
+        def lower(self, x):
+            lowered.append(threading.current_thread().name)
+            time.sleep(0.2)  # a compile takes long enough to collide
+            return jitted.lower(x)
+
+    wrapped = perf.instrument_batch_fn(Slow(), program="prog@pool",
+                                       capacity=512)
+    x = jnp.arange(4.0)
+    outs = [None] * 4
+    gate = threading.Barrier(4)
+
+    def call(i):
+        gate.wait(timeout=5)
+        outs[i] = np.asarray(wrapped(x))
+
+    threads = [threading.Thread(target=call, args=(i,)) for i in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert len(lowered) == 1
+    for out in outs:
+        np.testing.assert_array_equal(out, np.asarray(x) + 1.0)
+    assert perf.perf_profiles()[0]["compiles"] == 1
+
+
 def test_instrument_batch_fn_zero_cost_when_disabled():
     telemetry.reset_registry(enabled=False)
     fn = jax.jit(lambda x: x + 1.0)

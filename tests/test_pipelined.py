@@ -7,7 +7,11 @@ Two layers of guarantees:
   (regression: flushing only the previous batch dropped completed
   batches' ledger events at depth > 1), HBM exhaustion halves the depth
   and retries instead of failing, and the depth/source resolution obeys
-  the cli > config > tuning > default precedence.
+  the cli > config > tuning > default precedence.  The persist stage
+  runs on a pool sized like the prefetch stage's: N sleeping persists
+  take ceil(N / workers) sleeps, a persist that raises lets every other
+  launched batch persist first, two live ``persist`` arms of the
+  watchdog and two live ``persist`` spans keep to their own batch.
 - Bit-identity on the real jterator step: the pipelined executor at
   depths 2/4/8 must persist exactly the sequential path's label stacks
   and feature tables, for BOTH the sites and the spatial layout — the
@@ -96,8 +100,9 @@ def test_executor_yields_in_order_with_prefetch():
     assert [r["value"] for _, r in out] == [i * 10 for i in range(10)]
     # dispatch stays on the calling thread in batch order
     assert step.launched == list(range(10))
-    # one persist worker drains in submission order
-    assert step.persisted == list(range(10))
+    # every batch persisted once; with a pool the workers may enter
+    # persist_batch in any order, the YIELDS above are what is ordered
+    assert sorted(step.persisted) == list(range(10))
     # prefetch really ran on the worker pool, once per batch
     assert len(step.prefetch_threads) == 10
     assert all(t.startswith("tmx-prefetch") for t in step.prefetch_threads)
@@ -116,7 +121,7 @@ def test_midwindow_launch_failure_drains_whole_window():
         for b, r in gen:
             yielded.append(b["index"])
     assert yielded == [0, 1]
-    assert step.persisted == [0, 1]
+    assert sorted(step.persisted) == [0, 1]
     # nothing past the failure launched
     assert step.launched == [0, 1]
 
@@ -141,7 +146,7 @@ def test_oom_clamps_depth_and_retries():
     telemetry.drain_spans()
     out = list(ex.run(_batches(6)))
     assert [b["index"] for b, _ in out] == list(range(6))
-    assert step.persisted == list(range(6))
+    assert sorted(step.persisted) == list(range(6))
     # the control-flow events are exactly one depth clamp; the phase
     # spans do not ride that callback, they wait in the process buffer
     assert events == [{
@@ -188,6 +193,201 @@ def test_is_resource_exhausted_classifier():
     assert is_resource_exhausted(RuntimeError("ran Out of Memory on chip"))
     assert not is_resource_exhausted(ValueError("bad geometry"))
     assert not is_resource_exhausted(OSError("connection reset"))
+
+
+# ------------------------------------------------------------ persist pool
+class SleepyStep(FakeStep):
+    """Persists by sleeping: what a field's re-launch looks like to the
+    executor (the worker waits on the device, then writes)."""
+
+    def __init__(self, sleep=0.2, persist_fail_at=None, serial=False):
+        super().__init__()
+        self.sleep = sleep
+        self.persist_fail_at = persist_fail_at
+        if serial:
+            self.persist_serial = True
+        self.persist_threads: set[str] = set()
+
+    def persist_batch(self, batch, ctx):
+        from tmlibrary_tpu import telemetry
+
+        self.persist_threads.add(threading.current_thread().name)
+        with telemetry.span("inner", own=batch["index"]):
+            time.sleep(self.sleep)
+        if batch["index"] == self.persist_fail_at:
+            raise OSError("disk gone mid-persist")
+        return super().persist_batch(batch, ctx)
+
+
+@pytest.mark.parametrize(
+    "depth, n, kwargs, serial, workers",
+    [
+        (4, 8, {}, False, 4),     # the prefetch stage's rule: min(depth, 4, n)
+        (8, 8, {}, False, 4),     # capped at four however deep the window
+        (2, 8, {}, False, 2),     # the CPU backend's default depth
+        (8, 3, {}, False, 3),     # fewer batches than workers
+        (1, 3, {}, False, 1),     # depth 1, the clamp's floor: one worker
+        (4, 1, {}, False, 1),     # a single batch
+        (4, 4, {"persist_workers": 1}, False, 1),  # the explicit argument
+        (4, 4, {"persist_workers": 3}, False, 3),
+        (4, 4, {}, True, 1),      # a step that persists in batch order
+    ],
+)
+def test_persist_pool_is_sized_like_the_prefetch_stage(
+        depth, n, kwargs, serial, workers):
+    """N sleeping persists finish in about ceil(N / workers) sleeps, are
+    yielded in submission order, and ``persist_peak_concurrency`` reads
+    the pool's size."""
+    sleep = 0.2
+    step = SleepyStep(sleep=sleep, serial=serial)
+    stats = PipelineStats(depth, "cli", step="fake")
+    ex = PipelinedExecutor(step, depth=depth, stats=stats, **kwargs)
+    t0 = time.perf_counter()
+    out = list(ex.run(_batches(n)))
+    elapsed = time.perf_counter() - t0
+    assert [b["index"] for b, _ in out] == list(range(n))
+    assert [r["value"] for _, r in out] == [i * 10 for i in range(n)]
+    rounds = -(-n // workers)
+    assert elapsed >= rounds * sleep * 0.98
+    # a loaded test machine stretches a sleep; a queue of one would take
+    # n sleeps, and no stretch comes near that for the wide cases
+    assert elapsed < rounds * sleep + 0.6
+    summary = stats.summary()
+    assert summary["persist_workers"] == workers
+    assert summary["persist_peak_concurrency"] == workers
+    assert len(step.persist_threads) == workers
+    assert all(t.startswith("tmx-persist") for t in step.persist_threads)
+
+
+@pytest.mark.parametrize("n, fail_at, launched", [
+    (6, 2, 6),   # the failure surfaces in the final drain: all six launched
+    (8, 2, 7),   # it surfaces while batch 7 is still to launch
+    (6, 0, 5),   # the very first pop: batch 5 never launched
+])
+def test_persist_failure_lets_every_launched_batch_persist(
+        n, fail_at, launched):
+    """A persist that raises in the middle: the batches before it are
+    yielded, the error surfaces at its place in the order, and by then
+    every other launched batch has persisted (``shutdown(wait=True)``) —
+    no worker is still writing when the engine's sequential path re-runs
+    the failed batch."""
+    step = SleepyStep(sleep=0.05, persist_fail_at=fail_at)
+    ex = PipelinedExecutor(step, depth=4)
+    yielded = []
+    with pytest.raises(OSError, match="mid-persist"):
+        for b, _ in ex.run(_batches(n)):
+            yielded.append(b["index"])
+    assert yielded == list(range(fail_at))
+    assert step.launched == list(range(launched))
+    assert sorted(step.persisted) == [
+        i for i in range(launched) if i != fail_at]
+
+
+def test_clamp_to_depth_one_resolves_one_persist_worker():
+    """The depth clamp's floor is today's behaviour: after 2 -> 1 the
+    remaining batches persist on one worker."""
+    step = FakeStep(
+        fail_at=2, fail_exc=RuntimeError("RESOURCE_EXHAUSTED: HBM"),
+        fail_times=1,
+    )
+    stats = PipelineStats(2, "cli")
+    ex = PipelinedExecutor(step, depth=2, stats=stats)
+    assert ex._resolve_persist_workers(6) == 2
+    out = list(ex.run(_batches(6)))
+    assert [b["index"] for b, _ in out] == list(range(6))
+    assert ex.depth == 1
+    assert ex._resolve_persist_workers(4) == 1
+    assert stats.summary()["persist_workers"] == 1
+
+
+@pytest.mark.parametrize("qc_on, workers", [(True, 1), (False, 4)])
+def test_jterator_persists_in_order_only_while_qc_is_on(qc_on, workers):
+    """The QC session folds running statistics in the order batches are
+    observed, so a QC-on jterator says ``persist_serial`` and gets one
+    worker; by what the step says of itself, not by a setting of the
+    executor's."""
+    from tmlibrary_tpu import qc
+    from tmlibrary_tpu.workflow.steps.jterator import ImageAnalysisRunner
+
+    class Step(FakeStep):
+        persist_serial = ImageAnalysisRunner.persist_serial
+
+    qc.set_enabled(qc_on)
+    try:
+        ex = PipelinedExecutor(Step(), depth=8)
+        assert ex._resolve_persist_workers(9) == workers
+    finally:
+        qc.set_enabled(None)
+
+
+def test_spans_under_two_live_persists_keep_their_own_batch():
+    """A span opened inside worker 2's ``persist`` carries that
+    ``persist`` as ``parent`` and its own ``batch``: the span stack and
+    the ambient scope are per thread."""
+    from tmlibrary_tpu import telemetry
+
+    telemetry.drain_spans()
+    step = SleepyStep(sleep=0.15)
+    stats = PipelineStats(4, "cli", step="fake")
+    list(PipelinedExecutor(step, depth=4, stats=stats).run(_batches(4)))
+    assert stats.summary()["persist_peak_concurrency"] == 4
+    spans = telemetry.drain_spans()
+    inner = [e for e in spans if e["span"] == "inner"]
+    persist = {e["batch"]: e for e in spans if e["span"] == "persist"}
+    assert sorted(e["own"] for e in inner) == [0, 1, 2, 3]
+    assert sorted(persist) == [0, 1, 2, 3]
+    for e in inner:
+        assert e["parent"] == "persist"
+        assert e["batch"] == e["own"]
+        assert e["step"] == "fake"
+        outer = persist[e["own"]]
+        assert outer["t0"] <= e["t0"]
+        assert e["t0"] + e["elapsed"] <= outer["t0"] + outer["elapsed"] + 1e-3
+    # the four persists really were alive together
+    starts = [e["t0"] for e in persist.values()]
+    ends = [e["t0"] + e["elapsed"] for e in persist.values()]
+    assert max(starts) < min(ends)
+
+
+@pytest.mark.parametrize("slow", [1, 2])
+def test_watchdog_keeps_two_live_persist_arms_apart(slow):
+    """``arm("persist", batch=1)`` and ``arm("persist", batch=2)`` alive
+    together: leaving one does not disarm the other, and only the one
+    that overruns fires."""
+    from tmlibrary_tpu.resilience import PhaseWatchdog, WatchdogTimeout
+
+    wd = PhaseWatchdog({"persist": 0.25}, poll=0.02)
+    both_armed = threading.Barrier(2)
+    outcome = {}
+
+    def persist(batch):
+        try:
+            with wd.arm("persist", step="fake", batch=batch):
+                both_armed.wait(timeout=5)
+                time.sleep(0.7 if batch == slow else 0.02)
+            outcome[batch] = "ok"
+        except WatchdogTimeout as exc:
+            outcome[batch] = str(exc)
+
+    threads = [threading.Thread(target=persist, args=(b,)) for b in (1, 2)]
+    try:
+        for t in threads:
+            t.start()
+        time.sleep(0.1)
+        # the fast arm has left, the slow one is still armed
+        with wd._lock:
+            armed = [(e["phase"], e["batch"]) for e in wd._armed.values()]
+        assert armed == [("persist", slow)]
+        for t in threads:
+            t.join()
+    finally:
+        wd.stop()
+    fast = 3 - slow
+    assert outcome[fast] == "ok"
+    assert f"batch {slow} overran" in outcome[slow]
+    events = wd.drain_events()
+    assert [(e["phase"], e["batch"]) for e in events] == [("persist", slow)]
+    assert not wd._armed
 
 
 # ----------------------------------------------------------- prefetch_iter

@@ -403,59 +403,18 @@ def _instrumented_call(fn, key, args, kwargs, sub_costs=None):
         # already built this executable: no critical-path compile
         aotstore.note_warm(program)
     if not known:
-        imported = _try_store_import(key, sig)
-        if imported is not None:
-            compiled, meta = imported
+        # one import or compile a program, however many threads ask at
+        # once (the executor's persist workers re-launching fields at
+        # the same rung): the others wait and take what the first built
+        with _LOCK:
+            build_lock = state.setdefault("build_lock", threading.Lock())
+        with build_lock:
             with _LOCK:
-                if len(state["sigs"]) < _MAX_SIGNATURES:
-                    state["sigs"][sig] = compiled
-            # an import hit is NOT a compile: record_compile is skipped
-            # so the zero-new-compiles pinning (warm-start tests / CI
-            # smoke) holds; the profile store still learns about it
-            record_import(program=program, step=step, capacity=capacity,
-                          saved_s=meta.get("compile_s"))
-        else:
-            import jax
-
-            compile_s = None
-            compiled = None
-            cache_hits = aotstore.cache_hits_seen()
-            if hasattr(fn, "lower"):
-                # a program the backend's compiler refuses raises here,
-                # exactly as the plain jit call would
-                t0 = time.perf_counter()
-                with aotstore.compile_for_store():
-                    compiled = fn.lower(*args, **kwargs).compile()
-                compile_s = time.perf_counter() - t0
-            cost = cost_from_compiled(compiled) if compiled is not None \
-                else ProgramCost()
-            with _LOCK:
-                recompile = bool(state["sigs"])
-                if len(state["sigs"]) >= _MAX_SIGNATURES:
-                    state["dead"] = True
-                else:
-                    state["sigs"][sig] = compiled
-            backend = jax.default_backend()
-            record_compile(program=program, step=step, capacity=capacity,
-                           backend=backend,
-                           compile_s=compile_s, cost=cost,
-                           recompile=recompile)
-            if compiled is not None:
-                aotstore.note_cold(program)
-                if aotstore.cache_hits_seen() == cache_hits:
-                    aotstore.export_entry(
-                        compiled, program=program, step=step,
-                        capacity=capacity,
-                        signature=sig, compile_s=compile_s,
-                    )
-            if sub_costs is not None:
-                for sub_name, sub_cost in sub_costs(args, kwargs):
-                    record_compile(
-                        program=f"{program}:{sub_name}", step=step,
-                        capacity=capacity,
-                        backend=backend, cost=sub_cost,
-                        recompile=recompile,
-                    )
+                known = sig in state["sigs"]
+                compiled = state["sigs"].get(sig)
+            if not known:
+                compiled = _build_signature(fn, key, sig, state, args,
+                                            kwargs, sub_costs)
     if compiled is None:
         return fn(*args, **kwargs)
     try:
@@ -476,6 +435,69 @@ def _instrumented_call(fn, key, args, kwargs, sub_costs=None):
             # is nothing left to run jit on
             raise
     return fn(*args, **kwargs)
+
+
+def _build_signature(fn, key, sig, state, args, kwargs, sub_costs):
+    """The executable of a signature seen for the first time: imported
+    from the store, else compiled (and exported).  Called under the
+    program's build lock; returns None for a ``fn`` without ``lower()``."""
+    from tmlibrary_tpu import aotstore
+
+    program, step, capacity = key
+    imported = _try_store_import(key, sig)
+    if imported is not None:
+        compiled, meta = imported
+        with _LOCK:
+            if len(state["sigs"]) < _MAX_SIGNATURES:
+                state["sigs"][sig] = compiled
+        # an import hit is NOT a compile: record_compile is skipped
+        # so the zero-new-compiles pinning (warm-start tests / CI
+        # smoke) holds; the profile store still learns about it
+        record_import(program=program, step=step, capacity=capacity,
+                      saved_s=meta.get("compile_s"))
+        return compiled
+    import jax
+
+    compile_s = None
+    compiled = None
+    cache_hits = aotstore.cache_hits_seen()
+    if hasattr(fn, "lower"):
+        # a program the backend's compiler refuses raises here,
+        # exactly as the plain jit call would
+        t0 = time.perf_counter()
+        with aotstore.compile_for_store():
+            compiled = fn.lower(*args, **kwargs).compile()
+        compile_s = time.perf_counter() - t0
+    cost = cost_from_compiled(compiled) if compiled is not None \
+        else ProgramCost()
+    with _LOCK:
+        recompile = bool(state["sigs"])
+        if len(state["sigs"]) >= _MAX_SIGNATURES:
+            state["dead"] = True
+        else:
+            state["sigs"][sig] = compiled
+    backend = jax.default_backend()
+    record_compile(program=program, step=step, capacity=capacity,
+                   backend=backend,
+                   compile_s=compile_s, cost=cost,
+                   recompile=recompile)
+    if compiled is not None:
+        aotstore.note_cold(program)
+        if aotstore.cache_hits_seen() == cache_hits:
+            aotstore.export_entry(
+                compiled, program=program, step=step,
+                capacity=capacity,
+                signature=sig, compile_s=compile_s,
+            )
+    if sub_costs is not None:
+        for sub_name, sub_cost in sub_costs(args, kwargs):
+            record_compile(
+                program=f"{program}:{sub_name}", step=step,
+                capacity=capacity,
+                backend=backend, cost=sub_cost,
+                recompile=recompile,
+            )
+    return compiled
 
 
 # ---------------------------------------------------------------------------

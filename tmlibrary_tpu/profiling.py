@@ -52,6 +52,14 @@ class PipelineStats:
     same observations are mirrored into ``tmx_pipeline_phase_seconds``
     registry histograms.
 
+    ``persist`` (and ``device_block``) totals are SUMS OVER THE PERSIST
+    WORKERS, and a worker that waits for a program it re-launched counts
+    the device time of every re-launch queued before its own: with
+    several workers the total is a sum of sleeps that can exceed the
+    step's wall-clock, not a cost.  ``persist_workers`` (the pool the
+    executor resolved) and ``persist_peak_concurrency`` (the most tasks
+    inside ``step.persist_batch`` at one instant) say how wide it ran.
+
     Thread-safe: dispatch timings come from the main thread while
     device-block/persist timings come from persist workers.
     """
@@ -76,6 +84,14 @@ class PipelineStats:
         self._clamps: list[dict] = []
         #: seconds of ``persist`` spent waiting for a re-launched program
         self._persist_device_wait = 0.0
+        self._persist_workers = 0
+        self._persist_live = 0
+        self._persist_peak = 0
+        self._reg_persist = {
+            name: reg.gauge(f"tmx_pipeline_persist_{name}",
+                            step=step or "unknown")
+            for name in ("workers", "peak_concurrency")
+        }
 
     def record(self, phase: str, seconds: float) -> None:
         self._hist[phase].observe(seconds)
@@ -89,6 +105,26 @@ class PipelineStats:
     def batch_done(self) -> None:
         with self._lock:
             self._batches += 1
+
+    def note_persist_workers(self, workers: int) -> None:
+        """The persist pool the executor resolved for its window."""
+        with self._lock:
+            self._persist_workers = int(workers)
+        self._reg_persist["workers"].set(int(workers))
+
+    @contextlib.contextmanager
+    def persisting(self):
+        """Around one ``step.persist_batch`` call, on its worker: counts
+        how many are inside at once."""
+        with self._lock:
+            self._persist_live += 1
+            self._persist_peak = max(self._persist_peak, self._persist_live)
+            self._reg_persist["peak_concurrency"].set(self._persist_peak)
+        try:
+            yield
+        finally:
+            with self._lock:
+                self._persist_live -= 1
 
     def record_clamp(self, from_depth: int, to_depth: int) -> None:
         with self._lock:
@@ -106,6 +142,7 @@ class PipelineStats:
             batches = self._batches
             clamps = list(self._clamps)
             wait = self._persist_device_wait
+            workers, peak = self._persist_workers, self._persist_peak
         phases = {}
         for phase in PIPELINE_PHASES:
             hist = self._hist[phase]
@@ -124,6 +161,9 @@ class PipelineStats:
             "n_batches": batches,
             "phases": phases,
         }
+        if workers:
+            out["persist_workers"] = workers
+            out["persist_peak_concurrency"] = peak
         device_s = wait + sum(
             p["total_s"] for ph, p in phases.items()
             if PHASE_RESOURCE.get(ph) == "device"

@@ -38,8 +38,14 @@ Semantics the engine depends on (and the equivalence tests pin down):
   batch at the lower depth instead of failing the step — HBM pressure
   from too many in-flight batches degrades throughput, not correctness.
 - **Bit-identity**: dispatch happens on the calling thread in batch
-  order and persists default to ONE worker draining in submission
-  order, so results are bit-identical to sequential execution.
+  order; persists run on a pool sized like the prefetch stage's
+  (``min(depth, 4, batches)``), so several batches persist at once and
+  may FINISH out of order, but every persisted artifact is sharded by
+  batch (disjoint rows of a label stack, one Parquet shard a batch) and
+  results are still yielded in submission order, so the store and the
+  ledger are bit-identical to sequential execution.  A step whose
+  persist folds state in batch order says so with a true
+  ``persist_serial`` attribute and gets one worker.
 
 Fault plans (``faults.py``) targeting ``batch_run``/``ledger_append``
 force the engine onto the sequential path *before* this executor is
@@ -189,7 +195,7 @@ class PipelinedExecutor:
         step,
         depth: int | None = None,
         depth_source: str | None = None,
-        persist_workers: int = 1,
+        persist_workers: int | None = None,
         on_event: Callable[..., None] | None = None,
         stats=None,
         should_stop: Callable[[], bool] | None = None,
@@ -201,11 +207,18 @@ class PipelinedExecutor:
         self.step = step
         self.depth = max(1, int(depth))
         self.depth_source = depth_source or "explicit"
-        # >1 persist workers would reorder writes across batches; every
-        # persisted artifact is batch-sharded so that is SAFE, but one
-        # worker keeps the write order deterministic and is already off
-        # the critical path — more only helps when persist dominates
-        self.persist_workers = max(1, int(persist_workers))
+        # None: sized per window as the prefetch stage is
+        # (:meth:`_resolve_persist_workers`).  Persist is where a step
+        # waits on the device a second time (jterator re-launches a
+        # field at the rung its demand selects) and then does its host
+        # work (hulls, Parquet, label stacks): on one worker the two
+        # alternate and the device idles through every write; on several
+        # a field's re-launch runs while the field before it is written.
+        # Every persisted artifact is batch-sharded, so the order in
+        # which batches FINISH does not reach the store
+        self.persist_workers = (
+            None if persist_workers is None else max(1, int(persist_workers))
+        )
         self.on_event = on_event
         self.stats = stats
         #: graceful drain: polled before each launch — when it flips the
@@ -257,6 +270,23 @@ class PipelinedExecutor:
                     continue  # _run_window drained: pos is the failed batch
                 raise
 
+    def _stage_workers(self, n_batches: int) -> int:
+        """Threads of a host stage (prefetch, persist) over one window:
+        no more than the window is deep, than there are batches, or than
+        four — past that the stages contend for the host they overlap."""
+        return max(1, min(self.depth, 4, n_batches))
+
+    def _resolve_persist_workers(self, n_batches: int) -> int:
+        """The persist pool of one window: an explicit constructor value,
+        else one worker for a step that persists in batch order
+        (``persist_serial``), else the prefetch stage's size.  Depth 1 —
+        the clamp's floor — and a single batch resolve to one worker."""
+        if self.persist_workers is not None:
+            return self.persist_workers
+        if getattr(self.step, "persist_serial", False):
+            return 1
+        return self._stage_workers(n_batches)
+
     # --------------------------------------------------------------- window
     def _run_window(self, batches: list[dict]) -> Iterator[tuple[dict, dict]]:
         step = self.step
@@ -284,12 +314,15 @@ class PipelinedExecutor:
         prefetcher = None
         if has_prefetch and len(batches) > 1:
             prefetcher = concurrent.futures.ThreadPoolExecutor(
-                max_workers=min(self.depth, 4, len(batches)),
+                max_workers=self._stage_workers(len(batches)),
                 thread_name_prefix="tmx-prefetch",
             )
+        persist_workers = self._resolve_persist_workers(len(batches))
         persister = concurrent.futures.ThreadPoolExecutor(
-            max_workers=self.persist_workers, thread_name_prefix="tmx-persist"
+            max_workers=persist_workers, thread_name_prefix="tmx-persist"
         )
+        if stats is not None:
+            stats.note_persist_workers(persist_workers)
         # launched-but-not-yet-yielded batches, in submission order
         window: collections.deque = collections.deque()
         prefetched: dict[int, concurrent.futures.Future] = {}
@@ -308,7 +341,8 @@ class PipelinedExecutor:
                 # sigterm, hang) — inside the armed phase so an injected
                 # hang exercises the watchdog like a real wedged write
                 faults.maybe_fire("persist", step=step_name, batch=idx)
-                result = step.persist_batch(eff, ctx)
+                with (_NULL_CM if stats is None else stats.persisting()):
+                    result = step.persist_batch(eff, ctx)
             if stats is not None:
                 # what the persist spent waiting for a program it re-launched
                 if isinstance(result, dict) and result.get("device_wait_s"):

@@ -738,9 +738,25 @@ class ImageAnalysisRunner(Step):
         if payload["sec"] is not None:
             jax.block_until_ready(payload["sec"][2])
 
+    @property
+    def persist_serial(self) -> bool:
+        """True when :meth:`persist_batch` must see batches one at a time,
+        in submission order: the executor then gives persist one worker.
+        Only the QC session asks for it — it folds running statistics
+        (z-scores against the sites seen so far, P² sketches) in the
+        order batches are observed, and that order is in the ledger's
+        ``qc_batch`` events.  Everything else a persist touches is per
+        batch (one Parquet shard, disjoint rows of a label stack, one
+        well's mosaic) or merges commutatively under a lock (the routing
+        history's max, ``saturation.json`` keyed by batch)."""
+        from tmlibrary_tpu import qc as qc_mod
+
+        return qc_mod.enabled()
+
     def persist_batch(self, batch: dict, ctx) -> dict:
         """Fetch + write one launched batch (the effective batch from
-        :meth:`launch_batch`)."""
+        :meth:`launch_batch`).  Entered by several persist workers at
+        once, each with another batch (:attr:`persist_serial`)."""
         kind, payload = ctx
         if kind == "spatial":
             return self._persist_spatial(batch, payload)
