@@ -182,6 +182,75 @@ def test_spatial_layout_compiles_at_the_smokes_mosaic(program, n_devices,
         f"{program} on {n_devices} devices took {seconds:.0f}s to compile")
 
 
+#: the ``cp3-mosaic.x4`` cell: one well's 3 x 3 fields as one mosaic, on
+#: the four-chip host's own 2 x 2 mesh, root table as the cell sets it
+MOSAIC_SIDE = 3 * SMOKE_FIELD
+MOSAIC_ROOTS = 8192
+
+
+@pytest.mark.parametrize("program", ["smooth", "otsu", "cc", "watershed"])
+def test_spatial_grid_programs_compile_at_the_cells_mosaic(program, topo,
+                                                           on_tpu, capsys):
+    """Every sharded program of ``--layout spatial`` under ``spatial_grid:
+    grid`` at the cell's 6480 x 6480 mosaic on a described ``v5e:2x2``
+    (shards of 3240 x 3240): the 2-D halo smooth, the sharded Otsu, the
+    2-D connected components with their seam join and root table, the 2-D
+    watershed with its ``psum`` a step.  None had been compiled for the
+    chip at this size before PR 33; a program over 5 minutes is a fault."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from tmlibrary_tpu.ops.smooth import gaussian_radius
+    from tmlibrary_tpu.parallel import halo
+    from tmlibrary_tpu.parallel import label as plabel
+
+    side, axes = MOSAIC_SIDE, ("rows", "cols")
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(2, 2), axes)
+    tiles = NamedSharding(mesh, PartitionSpec(*axes))
+    replicated = NamedSharding(mesh, PartitionSpec())
+
+    def plane(dtype):
+        return jax.ShapeDtypeStruct((side, side), dtype, sharding=tiles)
+
+    # the lru-cached builders' own functions: a described mesh must not
+    # stay in the process's cache
+    if program == "smooth":
+        fn = halo._cached_gaussian_halo_2d.__wrapped__(
+            mesh, 1.5, gaussian_radius(1.5), *axes)
+        args = [plane(jnp.float32)]
+    elif program == "otsu":
+        fn = plabel._otsu_program(mesh, axes, 256)
+        args = [plane(jnp.float32),
+                jax.ShapeDtypeStruct((), jnp.float32, sharding=replicated)]
+    elif program == "cc":
+        fn = plabel._cc_2d_program(mesh, side // 2, side // 2, side, 8,
+                                   MOSAIC_ROOTS, *axes)
+        args = [plane(jnp.bool_)]
+    else:
+        fn = plabel._watershed_program(mesh, 16, 8, axes)
+        args = [plane(jnp.float32), plane(jnp.int32), plane(jnp.bool_)]
+    compiled, seconds = _compile(fn, *args)
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    with capsys.disabled():
+        print(f"\nmosaic {program} {side}x{side} on 2x2: compiled in "
+              f"{seconds:.1f} s, args {mem.argument_size_in_bytes / 1e6:.0f}"
+              f" MB, out {mem.output_size_in_bytes / 1e6:.0f} MB, temp "
+              f"{mem.temp_size_in_bytes / 1e6:.0f} MB a device; "
+              f"collective-permute {text.count('collective-permute(')}"
+              f"+{text.count('collective-permute-start(')}, all-reduce "
+              f"{text.count('all-reduce(')}+"
+              f"{text.count('all-reduce-start(')}, all-gather "
+              f"{text.count('all-gather(')}+"
+              f"{text.count('all-gather-start(')}")
+    assert seconds < 300, f"{program} took {seconds:.0f}s to compile"
+    # a chip holds its tile and the program's temporaries, never the well
+    resident = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+    assert resident < 4e9, f"{resident / 1e9:.1f} GB a device"
+    assert "callback" not in text
+
+
 # ------------------------------------------------------- whole-site programs
 def _program_case(config):
     from tmlibrary_tpu import benchmarks

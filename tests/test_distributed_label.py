@@ -15,7 +15,7 @@ from jax.sharding import Mesh
 from tmlibrary_tpu.errors import ShardingError
 from tmlibrary_tpu.parallel.label import (
     distributed_connected_components,
-    sharded_segment_mosaic,
+    segment_mosaic,
 )
 
 
@@ -81,7 +81,7 @@ def test_root_overflow_detected(mesh):
         distributed_connected_components(mask, mesh, max_roots_per_shard=64)
 
 
-def test_sharded_segment_mosaic_end_to_end(mesh, rng):
+def test_segment_mosaic_end_to_end(mesh, rng):
     """Giant-mosaic demo path: smooth + otsu + distributed CC equals the
     single-device chain on the gathered image."""
     from tmlibrary_tpu.ops.label import connected_components
@@ -93,7 +93,7 @@ def test_sharded_segment_mosaic_end_to_end(mesh, rng):
     for cy, cx in ((10, 12), (30, 40), (52, 20), (33, 33)):
         img += 3000 * np.exp(-((yy - cy) ** 2 + (xx - cx) ** 2) / 18.0)
 
-    labels, count = sharded_segment_mosaic(img, mesh, sigma=1.5)
+    labels, count, _ = segment_mosaic(img, mesh, sigma=1.5)
 
     sm = gaussian_smooth(jnp.asarray(img), 1.5)
     golden_mask = np.asarray(sm > otsu_value(sm))
@@ -118,7 +118,7 @@ def test_distributed_watershed_bit_identical(mesh, rng):
     including tie-breaks (every adopt step exchanges 1-row halos)."""
     from tmlibrary_tpu.ops.label import connected_components
     from tmlibrary_tpu.ops.segment_secondary import watershed_from_seeds
-    from tmlibrary_tpu.parallel.label import distributed_watershed_from_seeds
+    from tmlibrary_tpu.parallel.label import watershed_mosaic
 
     yy, xx = np.mgrid[0:64, 0:48]
     img = rng.normal(100, 10, (64, 48)).astype(np.float32)
@@ -133,7 +133,7 @@ def test_distributed_watershed_bit_identical(mesh, rng):
                              jnp.asarray(mask), n_levels=8, method="xla")
     )
     sharded = np.asarray(
-        distributed_watershed_from_seeds(img, seeds, mask, mesh, n_levels=8)
+        watershed_mosaic(img, seeds, mask, mesh, n_levels=8)[0]
     )
     assert np.array_equal(sharded, golden)
     assert sharded.max() > 0
@@ -150,7 +150,7 @@ def test_single_device_mesh_takes_native_shortcut(rng):
         _native_cc_available,
         distributed_connected_components,
         distributed_connected_components_2d,
-        distributed_watershed_from_seeds,
+        watershed_mosaic,
     )
 
     if not _native_cc_available():
@@ -171,8 +171,8 @@ def test_single_device_mesh_takes_native_shortcut(rng):
     intensity = rng.random((64, 48)).astype(np.float32) * 100
     seeds = np.where(np.asarray(l1) <= 3, np.asarray(l1), 0)
     grow = mask | (rng.random((64, 48)) > 0.5)
-    w1 = distributed_watershed_from_seeds(intensity, seeds, grow, mesh1)
-    w8 = distributed_watershed_from_seeds(intensity, seeds, grow, mesh8)
+    w1, _ = watershed_mosaic(intensity, seeds, grow, mesh1)
+    w8, _ = watershed_mosaic(intensity, seeds, grow, mesh8)
     np.testing.assert_array_equal(np.asarray(w1), np.asarray(w8))
 
     # the degenerate 1x1 2-D mesh hits the same pathology: same shortcut
@@ -182,11 +182,5 @@ def test_single_device_mesh_takes_native_shortcut(rng):
     assert int(c11) == int(c1)
     np.testing.assert_array_equal(np.asarray(l11), np.asarray(l1))
 
-    from tmlibrary_tpu.parallel.label import (
-        distributed_watershed_from_seeds_2d,
-    )
-
-    w11 = distributed_watershed_from_seeds_2d(
-        intensity, seeds, grow, mesh11
-    )
+    w11, _ = watershed_mosaic(intensity, seeds, grow, mesh11)
     np.testing.assert_array_equal(np.asarray(w11), np.asarray(w1))

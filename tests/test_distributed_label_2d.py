@@ -22,8 +22,7 @@ from tmlibrary_tpu.parallel.halo import (
 from tmlibrary_tpu.parallel.label import (
     distributed_connected_components,
     distributed_connected_components_2d,
-    sharded_segment_mosaic,
-    sharded_segment_mosaic_2d,
+    segment_mosaic,
 )
 
 
@@ -136,10 +135,7 @@ def test_distributed_watershed_2d_bit_identical(mesh42, mesh24, rng):
     step, corners carried by the two-step exchange)."""
     from tmlibrary_tpu.ops.label import connected_components
     from tmlibrary_tpu.ops.segment_secondary import watershed_from_seeds
-    from tmlibrary_tpu.parallel.label import (
-        distributed_watershed_from_seeds,
-        distributed_watershed_from_seeds_2d,
-    )
+    from tmlibrary_tpu.parallel.label import watershed_mosaic
 
     yy, xx = np.mgrid[0:64, 0:48]
     img = rng.normal(100, 10, (64, 48)).astype(np.float32)
@@ -156,34 +152,30 @@ def test_distributed_watershed_2d_bit_identical(mesh42, mesh24, rng):
     )
     for mesh in (mesh42, mesh24):
         sharded = np.asarray(
-            distributed_watershed_from_seeds_2d(
-                img, seeds, mask, mesh, n_levels=8
-            )
+            watershed_mosaic(img, seeds, mask, mesh, n_levels=8)[0]
         )
         assert np.array_equal(sharded, golden)
     assert golden.max() > 0
     # and the 1-D path agrees on the same inputs
     mesh1d = Mesh(np.asarray(mesh42.devices).reshape(-1), ("rows",))
     one_d = np.asarray(
-        distributed_watershed_from_seeds(img, seeds, mask, mesh1d, n_levels=8)
+        watershed_mosaic(img, seeds, mask, mesh1d, n_levels=8)[0]
     )
     assert np.array_equal(one_d, golden)
 
 
 def test_distributed_watershed_2d_dims_must_divide(mesh42):
-    from tmlibrary_tpu.parallel.label import (
-        distributed_watershed_from_seeds_2d,
-    )
+    from tmlibrary_tpu.parallel.label import watershed_mosaic
 
     bad = np.zeros((63, 48), np.float32)
     with pytest.raises(ShardingError):
-        distributed_watershed_from_seeds_2d(
+        watershed_mosaic(
             bad, np.zeros((63, 48), np.int32), np.zeros((63, 48), bool),
             mesh42,
         )
 
 
-def test_sharded_segment_mosaic_2d_end_to_end(mesh42, mesh24, rng):
+def test_segment_mosaic_2d_end_to_end(mesh42, mesh24, rng):
     """Blob mosaic: smooth + global otsu + 2-D CC matches the 1-D sharded
     path (itself scipy-golden-tested) exactly."""
     img = np.zeros((64, 64), np.float32)
@@ -191,11 +183,11 @@ def test_sharded_segment_mosaic_2d_end_to_end(mesh42, mesh24, rng):
     for cy, cx in [(10, 12), (31, 33), (50, 20), (18, 52), (32, 0)]:
         img += np.exp(-(((yy - cy) ** 2 + (xx - cx) ** 2) / 18.0))
     img += rng.normal(0, 0.02, img.shape).astype(np.float32)
-    l2d, c2d = sharded_segment_mosaic_2d(img, mesh42, sigma=1.5)
+    l2d, c2d, _ = segment_mosaic(img, mesh42, sigma=1.5)
     mesh1d = Mesh(np.asarray(mesh42.devices).reshape(-1), ("rows",))
-    l1d, c1d = sharded_segment_mosaic(img, mesh1d, sigma=1.5)
+    l1d, c1d, _ = segment_mosaic(img, mesh1d, sigma=1.5)
     assert int(c2d) == int(c1d) > 0
     assert np.array_equal(np.asarray(l2d), np.asarray(l1d))
-    l24, c24 = sharded_segment_mosaic_2d(img, mesh24, sigma=1.5)
+    l24, c24, _ = segment_mosaic(img, mesh24, sigma=1.5)
     assert int(c24) == int(c2d)
     assert np.array_equal(np.asarray(l24), np.asarray(l2d))

@@ -122,8 +122,10 @@ def _cached_gaussian_halo_2d(mesh: Mesh, sigma: float, radius: int,
     from tmlibrary_tpu.ops.smooth import gaussian_smooth
 
     def body(block):
-        extended = halo_exchange_2d(block, radius, row_axis, col_axis)
-        return gaussian_smooth(extended, sigma)[radius:-radius, radius:-radius]
+        with jax.named_scope("mosaic_smooth"):
+            extended = halo_exchange_2d(block, radius, row_axis, col_axis)
+            return gaussian_smooth(extended, sigma)[
+                radius:-radius, radius:-radius]
 
     return jax.jit(shard_map(
         body,
@@ -199,8 +201,9 @@ def _cached_gaussian_halo(mesh: Mesh, sigma: float, radius: int, axis: str):
     from tmlibrary_tpu.ops.smooth import gaussian_smooth
 
     def body(block):
-        extended = halo_exchange(block, radius, axis)
-        return gaussian_smooth(extended, sigma)[radius:-radius]
+        with jax.named_scope("mosaic_smooth"):
+            extended = halo_exchange(block, radius, axis)
+            return gaussian_smooth(extended, sigma)[radius:-radius]
 
     return jax.jit(shard_map(
         body,

@@ -28,6 +28,7 @@ block is the (devices x max_roots_per_shard) root table.
 
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -105,18 +106,31 @@ def distributed_connected_components(
     if n == 1 and _native_cc_available():
         return _native_cc_shortcut(mask, mesh, connectivity,
                                    PartitionSpec(axis))
-    rows = h // n
-    k = max_roots_per_shard
-    mapped = _cc_1d_program(mesh, rows, w, connectivity, k, axis)
     sharded = jax.device_put(mask, NamedSharding(mesh, PartitionSpec(axis)))
-    labels, count, overflow = jax.jit(mapped)(sharded)
+    labels, count, _ = _checked_roots(
+        _cached_cc_1d(mesh, h // n, w, connectivity, max_roots_per_shard,
+                      axis)(sharded),
+        max_roots_per_shard,
+    )
+    return labels, count
+
+
+def _checked_roots(out, k):
+    """``(labels, count, info)`` of a CC program's four outputs; raises
+    :class:`ShardingError` naming the demand when a shard held more roots
+    than the static table ``k`` (the labels are then clipped).  ``info``
+    carries what the program counted: the fullest shard's roots and the
+    outer seam loop's rounds."""
+    labels, count, overflow, rounds = out
     max_local = int(overflow)
     if max_local > k:
         raise ShardingError(
             f"a shard holds {max_local} components > "
             f"max_roots_per_shard={k}; raise the bound"
         )
-    return labels, count
+    return labels, count, {
+        "roots_max_per_shard": max_local, "seam_rounds": int(rounds),
+    }
 
 
 def _native_cc_available() -> bool:
@@ -144,47 +158,53 @@ def _native_cc_shortcut(mask, mesh, connectivity, spec):
     )
 
 
+def _dense_ranks(labels, block, linear, k, axes):
+    """Scan-order ids from converged min-index labels: roots sorted per
+    shard, merged by ``all_gather``; every pixel's rank is a
+    ``searchsorted`` into the merged table.  Returns ``(out, count,
+    fullest shard's roots)``, the two scalars replicated (a multi-host
+    caller can fetch a replicated array, but a sharded one spans devices
+    it cannot address)."""
+    is_root = block & (labels == linear)
+    n_local = jnp.sum(is_root.astype(jnp.int32))
+    roots = jnp.sort(jnp.where(is_root, linear, _BIG).reshape(-1))[:k]
+    all_roots = jnp.sort(lax.all_gather(roots, axes).reshape(-1))
+    rank = jnp.searchsorted(all_roots, labels.reshape(-1)).reshape(
+        labels.shape
+    )
+    out = jnp.where(block, rank + 1, 0).astype(jnp.int32)
+    return out, lax.psum(n_local, axes), lax.pmax(n_local, axes)
+
+
 def _cc_1d_program(mesh, rows, w, connectivity, k, axis):
     """The jittable shard_map program behind
     :func:`distributed_connected_components` — split out so tooling
-    (scripts/comm_budget.py) can lower and inspect its HLO."""
+    (scripts/comm_budget.py) can lower and inspect its HLO.  Returns
+    ``(labels, count, fullest shard's roots, seam rounds)``."""
 
     def body(block):
-        idx = lax.axis_index(axis)
-        row0 = idx * rows
-        yy = (row0 + jnp.arange(rows, dtype=jnp.int32))[:, None]
-        xx = jnp.arange(w, dtype=jnp.int32)[None, :]
-        linear = yy * w + xx
-        labels = jnp.where(block, linear, _BIG)
-        labels = _local_fixpoint(labels, block, connectivity, axis)
+        with jax.named_scope("mosaic_cc"):
+            idx = lax.axis_index(axis)
+            row0 = idx * rows
+            yy = (row0 + jnp.arange(rows, dtype=jnp.int32))[:, None]
+            xx = jnp.arange(w, dtype=jnp.int32)[None, :]
+            linear = yy * w + xx
+            labels = jnp.where(block, linear, _BIG)
+            labels = _local_fixpoint(labels, block, connectivity, axis)
 
-        def outer(state):
-            lab, _ = state
-            lab, changed = _seam_join(lab, block, axis, connectivity)
-            lab = _local_fixpoint(lab, block, connectivity, axis)
-            return lab, lax.psum(changed.astype(jnp.int32), axis) > 0
+            def outer(state):
+                lab, _, rounds = state
+                with jax.named_scope("mosaic_seam"):
+                    lab, changed = _seam_join(lab, block, axis, connectivity)
+                lab = _local_fixpoint(lab, block, connectivity, axis)
+                return (lab, lax.psum(changed.astype(jnp.int32), axis) > 0,
+                        rounds + 1)
 
-        # psum makes the outer flag replicated, so its init stays plain
-        labels, _ = lax.while_loop(
-            lambda s: s[1], outer, (labels, jnp.bool_(True))
-        )
-
-        # dense ranks: roots sorted per shard, merged by all_gather
-        is_root = block & (labels == linear)
-        n_local = jnp.sum(is_root.astype(jnp.int32))
-        roots = jnp.sort(
-            jnp.where(is_root, linear, _BIG).reshape(-1)
-        )[:k]
-        all_roots = jnp.sort(lax.all_gather(roots, axis).reshape(-1))
-        rank = jnp.searchsorted(all_roots, labels.reshape(-1)).reshape(labels.shape)
-        out = jnp.where(block, rank + 1, 0).astype(jnp.int32)
-        # psum/pmax results are replicated across the mesh — return them
-        # as replicated scalars, not per-shard rows: a multi-host caller
-        # can fetch a replicated array, but a sharded one spans devices
-        # it cannot address
-        count = lax.psum(n_local, axis)
-        overflow = lax.pmax(n_local, axis)
-        return out, count, overflow
+            # psum makes the outer flag replicated, so its init stays plain
+            labels, _, rounds = lax.while_loop(
+                lambda s: s[1], outer, (labels, jnp.bool_(True), jnp.int32(0))
+            )
+            return (*_dense_ranks(labels, block, linear, k, axis), rounds)
 
     return shard_map(
         body,
@@ -194,8 +214,18 @@ def _cc_1d_program(mesh, rows, w, connectivity, k, axis):
             PartitionSpec(axis),
             PartitionSpec(),
             PartitionSpec(),
+            PartitionSpec(),
         ),
     )
+
+
+@functools.lru_cache(maxsize=64)
+def _cached_cc_1d(mesh, rows, w, connectivity, k, axis):
+    """The compiled 1-D program, built and jitted once per (mesh, shard
+    shape, connectivity, bound): a fresh ``jax.jit(shard_map(...))`` per
+    call re-traced, re-lowered and read the compile cache for every well
+    (``halo._cached_gaussian_halo_2d`` says what that cost)."""
+    return jax.jit(_cc_1d_program(mesh, rows, w, connectivity, k, axis))
 
 
 def _edge_extend(vec_lab, vec_msk, other_axis):
@@ -312,50 +342,51 @@ def distributed_connected_components_2d(
         # same degenerate-mesh pathology as the 1-D entry point
         return _native_cc_shortcut(mask, mesh, connectivity,
                                    PartitionSpec(row_axis, col_axis))
-    rows, cols = h // nr, w // nc
-    k = max_roots_per_shard
+    sharded = jax.device_put(
+        mask, NamedSharding(mesh, PartitionSpec(row_axis, col_axis))
+    )
+    labels, count, _ = _checked_roots(
+        _cached_cc_2d(mesh, h // nr, w // nc, w, connectivity,
+                      max_roots_per_shard, row_axis, col_axis)(sharded),
+        max_roots_per_shard,
+    )
+    return labels, count
+
+
+def _cc_2d_program(mesh, rows, cols, w, connectivity, k, row_axis, col_axis):
+    """The 2-D twin of :func:`_cc_1d_program`: ``(rows, cols)`` tiles of a
+    mosaic ``w`` wide."""
     axes = (row_axis, col_axis)
 
     def body(block):
-        ridx = lax.axis_index(row_axis)
-        cidx = lax.axis_index(col_axis)
-        yy = (ridx * rows + jnp.arange(rows, dtype=jnp.int32))[:, None]
-        xx = (cidx * cols + jnp.arange(cols, dtype=jnp.int32))[None, :]
-        linear = yy * w + xx
-        labels = jnp.where(block, linear, _BIG)
-        labels = _local_fixpoint(labels, block, connectivity, axes)
+        with jax.named_scope("mosaic_cc"):
+            ridx = lax.axis_index(row_axis)
+            cidx = lax.axis_index(col_axis)
+            yy = (ridx * rows + jnp.arange(rows, dtype=jnp.int32))[:, None]
+            xx = (cidx * cols + jnp.arange(cols, dtype=jnp.int32))[None, :]
+            linear = yy * w + xx
+            labels = jnp.where(block, linear, _BIG)
+            labels = _local_fixpoint(labels, block, connectivity, axes)
 
-        def outer(state):
-            lab, _ = state
-            lab, ch_r = _seam_join_2d_axis(
-                lab, block, row_axis, col_axis, connectivity
+            def outer(state):
+                lab, _, rounds = state
+                with jax.named_scope("mosaic_seam"):
+                    lab, ch_r = _seam_join_2d_axis(
+                        lab, block, row_axis, col_axis, connectivity
+                    )
+                    lab_t, ch_c = _seam_join_2d_axis(
+                        lab.T, block.T, col_axis, row_axis, connectivity
+                    )
+                lab = _local_fixpoint(lab_t.T, block, connectivity, axes)
+                changed = ch_r.astype(jnp.int32) + ch_c.astype(jnp.int32)
+                return lab, lax.psum(changed, axes) > 0, rounds + 1
+
+            labels, _, rounds = lax.while_loop(
+                lambda s: s[1], outer, (labels, jnp.bool_(True), jnp.int32(0))
             )
-            lab_t, ch_c = _seam_join_2d_axis(
-                lab.T, block.T, col_axis, row_axis, connectivity
-            )
-            lab = lab_t.T
-            lab = _local_fixpoint(lab, block, connectivity, axes)
-            changed = ch_r.astype(jnp.int32) + ch_c.astype(jnp.int32)
-            return lab, lax.psum(changed, axes) > 0
+            return (*_dense_ranks(labels, block, linear, k, axes), rounds)
 
-        labels, _ = lax.while_loop(
-            lambda s: s[1], outer, (labels, jnp.bool_(True))
-        )
-
-        is_root = block & (labels == linear)
-        n_local = jnp.sum(is_root.astype(jnp.int32))
-        roots = jnp.sort(jnp.where(is_root, linear, _BIG).reshape(-1))[:k]
-        all_roots = jnp.sort(lax.all_gather(roots, axes).reshape(-1))
-        rank = jnp.searchsorted(all_roots, labels.reshape(-1)).reshape(
-            labels.shape
-        )
-        out = jnp.where(block, rank + 1, 0).astype(jnp.int32)
-        # replicated scalars (see the 1-D twin's multi-host note)
-        count = lax.psum(n_local, axes)
-        overflow = lax.pmax(n_local, axes)
-        return out, count, overflow
-
-    mapped = shard_map(
+    return shard_map(
         body,
         mesh=mesh,
         in_specs=PartitionSpec(row_axis, col_axis),
@@ -363,93 +394,138 @@ def distributed_connected_components_2d(
             PartitionSpec(row_axis, col_axis),
             PartitionSpec(),
             PartitionSpec(),
+            PartitionSpec(),
         ),
     )
-    sharded = jax.device_put(
-        mask, NamedSharding(mesh, PartitionSpec(row_axis, col_axis))
+
+
+@functools.lru_cache(maxsize=64)
+def _cached_cc_2d(mesh, rows, cols, w, connectivity, k, row_axis, col_axis):
+    """See :func:`_cached_cc_1d`."""
+    return jax.jit(_cc_2d_program(
+        mesh, rows, cols, w, connectivity, k, row_axis, col_axis))
+
+
+def _otsu_program(mesh, axes, bins):
+    """``(plane, factor) -> (plane > otsu * factor, reading)`` over a
+    plane sharded on ``axes``: global range by ``pmin`` / ``pmax``, every
+    shard's fixed-bin histogram ``psum``-med, the between-class argmax on
+    the (bins,) result — :func:`~tmlibrary_tpu.ops.threshold.otsu_value`'s
+    XLA formulation with the plane never leaving its shards.  ``reading``
+    is what the cut was taken from, replicated: ``cut`` (before
+    ``factor``), ``hist``, ``lo``, ``hi``."""
+    from tmlibrary_tpu.ops.histogram import histogram_fixed_bins
+    from tmlibrary_tpu.ops.threshold import _otsu_argmax
+
+    spec = PartitionSpec(*axes)
+
+    def body(block, factor):
+        with jax.named_scope("mosaic_otsu"):
+            lo = lax.pmin(jnp.min(block), axes)
+            hi = lax.pmax(jnp.max(block), axes)
+            span = jnp.maximum(hi - lo, 1e-6)
+            idx = jnp.clip(
+                ((block - lo) / span * bins).astype(jnp.int32), 0, bins - 1
+            )
+            hist = lax.psum(histogram_fixed_bins(
+                idx, bins,
+                method="scatter" if jax.default_backend() == "cpu"
+                else "matmul",
+            ), axes)
+            centers = (
+                lo + (jnp.arange(bins, dtype=jnp.float32) + 0.5) / bins * span
+            )
+            t = _otsu_argmax(hist, centers)
+            return block > t * factor, {
+                "cut": t, "hist": hist, "lo": lo, "hi": hi}
+
+    return shard_map(
+        body,
+        mesh=mesh,
+        in_specs=(spec, PartitionSpec()),
+        out_specs=(spec, PartitionSpec()),
+        # the matmul histogram's loop starts from a plain zeros carry,
+        # which the varying-axis checker refuses inside a shard; the
+        # reading is replicated by the psum of the histogram
+        check_vma=False,
     )
-    labels, count, overflow = jax.jit(mapped)(sharded)
-    max_local = int(overflow)
-    if max_local > k:
-        raise ShardingError(
-            f"a shard holds {max_local} components > "
-            f"max_roots_per_shard={k}; raise the bound"
+
+
+@functools.lru_cache(maxsize=64)
+def _cached_otsu(mesh, axes, bins):
+    return jax.jit(_otsu_program(mesh, axes, bins))
+
+
+def sharded_otsu_mask(
+    image: jax.Array, mesh: Mesh, correction_factor: float = 1.0,
+    bins: int = 256,
+) -> tuple[jax.Array, dict]:
+    """``(image > otsu(image) * correction_factor, reading)`` for a plane
+    sharded over every axis of ``mesh``; the mask is sharded like the
+    plane, ``reading`` holds the ``cut`` (Otsu's, before the factor) as a
+    device scalar and, from the sharded program, the ``hist``, ``lo`` and
+    ``hi`` it was taken from.  A 1-device CPU mesh has nothing sharded,
+    and the fused native histogram pass is ~4x faster there (the shortcut
+    the distributed CC takes)."""
+    img = jnp.asarray(image, jnp.float32)
+    if mesh.devices.size == 1 and _native_cc_available():
+        from tmlibrary_tpu.ops.threshold import otsu_value
+
+        cut = otsu_value(img, bins=bins)
+        return img > cut * correction_factor, {"cut": cut}
+    return _cached_otsu(mesh, tuple(mesh.axis_names), bins)(
+        img, jnp.float32(correction_factor)
+    )
+
+
+def segment_mosaic(
+    intensity: jax.Array,
+    mesh: Mesh,
+    sigma: float = 1.5,
+    threshold: float | None = None,
+    connectivity: int = 8,
+    max_roots_per_shard: int = 4096,
+) -> tuple[jax.Array, jax.Array, dict]:
+    """Smooth + threshold + label a mosaic sharded over ``mesh`` — one
+    axis (row shards) or two (rows x cols tiles): halo-exact Gaussian
+    smoothing (corners included), a global Otsu cut when ``threshold`` is
+    None, then the distributed connected components.  Returns ``(labels,
+    count, info)``: ``info`` as :func:`_checked_roots` gives it, and under
+    ``otsu`` the reading of :func:`sharded_otsu_mask` (un-fetched device
+    arrays; the ``cut`` alone under an explicit ``threshold``)."""
+    from tmlibrary_tpu.parallel.halo import (
+        sharded_gaussian_smooth,
+        sharded_gaussian_smooth_2d,
+    )
+
+    img = jnp.asarray(intensity, jnp.float32)
+    axes = tuple(mesh.axis_names)
+    h, w = img.shape
+    if len(axes) == 2:
+        smoothed = sharded_gaussian_smooth_2d(
+            img, mesh, sigma, row_axis=axes[0], col_axis=axes[1]
         )
-    return labels, count
-
-
-def sharded_segment_mosaic_2d(
-    intensity: jax.Array,
-    mesh: Mesh,
-    sigma: float = 1.5,
-    threshold: float | None = None,
-    connectivity: int = 8,
-    row_axis: str = "rows",
-    col_axis: str = "cols",
-) -> tuple[jax.Array, jax.Array]:
-    """Smooth + threshold + label a mosaic sharded on both spatial axes:
-    the giant-image path for meshes with a 2-D spatial layout.  Halo-exact
-    smoothing (corners included), global Otsu, then
-    :func:`distributed_connected_components_2d`."""
-    from tmlibrary_tpu.ops.threshold import otsu_value
-    from tmlibrary_tpu.parallel.halo import sharded_gaussian_smooth_2d
-
-    img = jnp.asarray(intensity, jnp.float32)
-    smoothed = sharded_gaussian_smooth_2d(
-        img, mesh, sigma, row_axis=row_axis, col_axis=col_axis
-    )
-    # method choice: on a REAL mesh ``smoothed`` is a globally sharded
-    # array — the native host-callback path cannot run on one (the
-    # partitioner must gather the operand to a single device, which
-    # Shardy cannot express and the CPU SPMD runtime deadlocks on), so
-    # the XLA path reduces the histogram with global ops on the sharded
-    # array.  A 1-device mesh has nothing sharded, and the fused native
-    # pass is ~4x faster there (same shortcut the distributed CC takes).
-    otsu_method = "xla" if mesh.devices.size > 1 else "auto"
-    t = (otsu_value(smoothed, method=otsu_method) if threshold is None
-         else jnp.float32(threshold))
-    return distributed_connected_components_2d(
-        smoothed > t,
-        mesh,
-        connectivity=connectivity,
-        row_axis=row_axis,
-        col_axis=col_axis,
-    )
-
-
-def sharded_segment_mosaic(
-    intensity: jax.Array,
-    mesh: Mesh,
-    sigma: float = 1.5,
-    threshold: float | None = None,
-    connectivity: int = 8,
-    axis: str = "rows",
-) -> tuple[jax.Array, jax.Array]:
-    """Smooth + threshold + label a row-sharded mosaic end-to-end.
-
-    The giant-image demonstration path: halo-exact Gaussian smoothing, a
-    global Otsu cut when ``threshold`` is None (histogram reduced with
-    ``psum``-free global ops on the sharded array), then
-    :func:`distributed_connected_components`.  Returns (labels, count).
-    """
-    from tmlibrary_tpu.ops.threshold import otsu_value
-    from tmlibrary_tpu.parallel.halo import sharded_gaussian_smooth
-
-    img = jnp.asarray(intensity, jnp.float32)
-    smoothed = sharded_gaussian_smooth(img, mesh, sigma, axis=axis)
-    # method choice: on a REAL mesh ``smoothed`` is a globally sharded
-    # array — the native host-callback path cannot run on one (the
-    # partitioner must gather the operand to a single device, which
-    # Shardy cannot express and the CPU SPMD runtime deadlocks on), so
-    # the XLA path reduces the histogram with global ops on the sharded
-    # array.  A 1-device mesh has nothing sharded, and the fused native
-    # pass is ~4x faster there (same shortcut the distributed CC takes).
-    otsu_method = "xla" if mesh.devices.size > 1 else "auto"
-    t = (otsu_value(smoothed, method=otsu_method) if threshold is None
-         else jnp.float32(threshold))
-    return distributed_connected_components(
-        smoothed > t, mesh, connectivity=connectivity, axis=axis
-    )
+    else:
+        smoothed = sharded_gaussian_smooth(img, mesh, sigma, axis=axes[0])
+    if threshold is None:
+        mask, otsu = sharded_otsu_mask(smoothed, mesh)
+    else:
+        otsu = {"cut": jnp.float32(threshold)}
+        mask = smoothed > otsu["cut"]
+    if mesh.devices.size == 1 and _native_cc_available():
+        labels, count = _native_cc_shortcut(
+            mask, mesh, connectivity, PartitionSpec(*axes))
+        return labels, count, {"otsu": otsu}
+    k = max_roots_per_shard
+    if len(axes) == 2:
+        nr, nc = mesh.shape[axes[0]], mesh.shape[axes[1]]
+        program = _cached_cc_2d(
+            mesh, h // nr, w // nc, w, connectivity, k, *axes)
+    else:
+        program = _cached_cc_1d(
+            mesh, h // mesh.devices.size, w, connectivity, k, axes[0])
+    labels, count, info = _checked_roots(program(mask), k)
+    return labels, count, {**info, "otsu": otsu}
 
 
 # ------------------------------------------------------------- watershed
@@ -492,97 +568,115 @@ def _sharded_adopt_2d(labels, allowed, row_axis, col_axis, connectivity):
     return new_ext[1:-1, 1:-1]
 
 
-def distributed_watershed_from_seeds_2d(
+def watershed_mosaic(
     intensity: jax.Array,
     seeds: jax.Array,
     mask: jax.Array,
     mesh: Mesh,
     n_levels: int = 32,
     connectivity: int = 8,
-    row_axis: str = "rows",
-    col_axis: str = "cols",
-) -> jax.Array:
-    """Level-ordered watershed flooding over a mosaic sharded on BOTH
-    spatial axes — the 2-D twin of
-    :func:`distributed_watershed_from_seeds`, bit-identical to the
-    single-device ``watershed_from_seeds`` on the gathered image (global
-    level thresholds via ``pmin``/``pmax`` over both mesh axes, 1-pixel
-    zero-filled halos each adopt step so every tie-break matches the
-    synchronous schedule)."""
+) -> tuple[jax.Array, "jax.Array | None"]:
+    """Level-ordered watershed flooding over a mosaic sharded over
+    ``mesh`` (row shards, or rows x cols tiles), bit-identical to the
+    single-device ``watershed_from_seeds`` on the gathered image: the
+    level thresholds are global (``pmin``/``pmax`` of the masked
+    intensity), and every adopt step exchanges 1-pixel zero-filled halos
+    so the synchronous adoption schedule — and therefore every tie-break
+    — matches the single-device iteration exactly.  Returns ``(labels,
+    adopt steps)``, both un-fetched; the steps are None on a 1-device CPU
+    mesh, where the native frontier flood counts none."""
     intensity = jnp.asarray(intensity, jnp.float32)
     seeds = jnp.asarray(seeds, jnp.int32)
     mask = jnp.asarray(mask, bool)
+    axes = tuple(mesh.axis_names)
     h, w = intensity.shape
-    nr = mesh.shape[row_axis]
-    nc = mesh.shape[col_axis]
+    nr = mesh.shape[axes[0]]
+    nc = mesh.shape[axes[1]] if len(axes) == 2 else 1
     if h % nr != 0 or w % nc != 0:
         raise ShardingError(
             f"mosaic {h}x{w} not divisible by mesh {nr}x{nc}"
         )
+    spec = NamedSharding(mesh, PartitionSpec(*axes))
     if nr * nc == 1 and _native_cc_available():
+        # 1-device CPU mesh: the single-device twin IS the semantics
+        # this function is tested bit-identical against, and its auto
+        # dispatch routes to the native frontier flood on cpu
         from tmlibrary_tpu.ops.segment_secondary import watershed_from_seeds
 
         out = watershed_from_seeds(
             intensity, seeds, mask,
             n_levels=n_levels, connectivity=connectivity,
         )
-        return jax.device_put(
-            out,
-            NamedSharding(mesh, PartitionSpec(row_axis, col_axis)),
-        )
-    axes = (row_axis, col_axis)
-
-    def body(int_block, seed_block, mask_block):
-        mask_b = mask_block | (seed_block > 0)
-        lo = lax.pmin(
-            jnp.min(jnp.where(mask_b, int_block, jnp.inf)), axes
-        )
-        hi = lax.pmax(
-            jnp.max(jnp.where(mask_b, int_block, -jnp.inf)), axes
-        )
-        span = jnp.maximum(hi - lo, 1e-6)
-
-        def flood(labels, allowed):
-            def inner(state):
-                lab, _ = state
-                new = _sharded_adopt_2d(
-                    lab, allowed, row_axis, col_axis, connectivity
-                )
-                changed = lax.psum(
-                    jnp.any(new != lab).astype(jnp.int32), axes
-                )
-                return new, changed > 0
-
-            out, _ = lax.while_loop(
-                lambda s: s[1], inner, (labels, jnp.bool_(True))
-            )
-            return out
-
-        def level_body(i, labels):
-            level = hi - span * (i + 1) / n_levels
-            allowed = mask_b & (int_block >= level)
-            return flood(labels, allowed)
-
-        labels = lax.fori_loop(0, n_levels, level_body, seed_block)
-        labels = flood(labels, mask_b)
-        return jnp.where(mask_b, labels, 0)
-
-    mapped = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(
-            PartitionSpec(row_axis, col_axis),
-            PartitionSpec(row_axis, col_axis),
-            PartitionSpec(row_axis, col_axis),
-        ),
-        out_specs=PartitionSpec(row_axis, col_axis),
-    )
-    spec = NamedSharding(mesh, PartitionSpec(row_axis, col_axis))
-    return jax.jit(mapped)(
+        return jax.device_put(out, spec), None
+    return _cached_watershed(mesh, n_levels, connectivity, axes)(
         jax.device_put(intensity, spec),
         jax.device_put(seeds, spec),
         jax.device_put(mask, spec),
     )
+
+
+def _watershed_program(mesh, n_levels, connectivity, axes):
+    """The jittable shard_map program behind both distributed watersheds
+    (``axes``: one mesh axis of row shards, or rows x cols).  Returns
+    ``(labels, adopt steps)``: the synchronous adopt steps of every level
+    and of the last unrestricted flood, each one a halo exchange and a
+    ``psum`` (a replicated scalar)."""
+    if len(axes) == 2:
+        def adopt(lab, allowed):
+            return _sharded_adopt_2d(lab, allowed, *axes, connectivity)
+    else:
+        def adopt(lab, allowed):
+            return _sharded_adopt(lab, allowed, axes[0], connectivity)
+
+    def body(int_block, seed_block, mask_block):
+        with jax.named_scope("mosaic_watershed"):
+            mask_b = mask_block | (seed_block > 0)
+            lo = lax.pmin(
+                jnp.min(jnp.where(mask_b, int_block, jnp.inf)), axes
+            )
+            hi = lax.pmax(
+                jnp.max(jnp.where(mask_b, int_block, -jnp.inf)), axes
+            )
+            span = jnp.maximum(hi - lo, 1e-6)
+
+            def flood(labels, allowed, steps):
+                def inner(state):
+                    lab, _, n = state
+                    new = adopt(lab, allowed)
+                    changed = lax.psum(
+                        jnp.any(new != lab).astype(jnp.int32), axes
+                    )
+                    return new, changed > 0, n + 1
+
+                out, _, steps = lax.while_loop(
+                    lambda s: s[1], inner, (labels, jnp.bool_(True), steps)
+                )
+                return out, steps
+
+            def level_body(i, state):
+                level = hi - span * (i + 1) / n_levels
+                allowed = mask_b & (int_block >= level)
+                return flood(state[0], allowed, state[1])
+
+            labels, steps = lax.fori_loop(
+                0, n_levels, level_body, (seed_block, jnp.int32(0))
+            )
+            labels, steps = flood(labels, mask_b, steps)
+            return jnp.where(mask_b, labels, 0), steps
+
+    spec = PartitionSpec(*axes)
+    return shard_map(
+        body,
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=(spec, PartitionSpec()),
+    )
+
+
+@functools.lru_cache(maxsize=64)
+def _cached_watershed(mesh, n_levels, connectivity, axes):
+    """See :func:`_cached_cc_1d`."""
+    return jax.jit(_watershed_program(mesh, n_levels, connectivity, axes))
 
 
 def _sharded_adopt(labels, allowed, axis_name, connectivity):
@@ -597,88 +691,3 @@ def _sharded_adopt(labels, allowed, axis_name, connectivity):
     allowed_ext = jnp.concatenate([false_row, allowed, false_row], axis=0)
     new_ext = _adopt_step(ext, allowed_ext, connectivity)
     return new_ext[1:-1]
-
-
-def distributed_watershed_from_seeds(
-    intensity: jax.Array,
-    seeds: jax.Array,
-    mask: jax.Array,
-    mesh: Mesh,
-    n_levels: int = 32,
-    connectivity: int = 8,
-    axis: str = "rows",
-) -> jax.Array:
-    """Level-ordered watershed flooding over a row-sharded mosaic.
-
-    Bit-identical to ``ops.segment_secondary.watershed_from_seeds`` on the
-    gathered image: the level thresholds are global (``pmin``/``pmax`` of
-    the masked intensity), and every adopt step exchanges 1-row halos so
-    the synchronous adoption schedule — and therefore every tie-break —
-    matches the single-device iteration exactly.
-    """
-    intensity = jnp.asarray(intensity, jnp.float32)
-    seeds = jnp.asarray(seeds, jnp.int32)
-    mask = jnp.asarray(mask, bool)
-    h, w = intensity.shape
-    n = mesh.devices.size
-    if h % n != 0:
-        raise ShardingError(f"rows {h} not divisible by mesh size {n}")
-    if n == 1 and _native_cc_available():
-        # 1-device CPU mesh: the single-device twin IS the semantics
-        # this function is tested bit-identical against, and its auto
-        # dispatch routes to the native frontier flood on cpu
-        from tmlibrary_tpu.ops.segment_secondary import watershed_from_seeds
-
-        out = watershed_from_seeds(
-            intensity, seeds, mask,
-            n_levels=n_levels, connectivity=connectivity,
-        )
-        return jax.device_put(
-            out, NamedSharding(mesh, PartitionSpec(axis))
-        )
-
-    def body(int_block, seed_block, mask_block):
-        mask_b = mask_block | (seed_block > 0)
-        lo = lax.pmin(
-            jnp.min(jnp.where(mask_b, int_block, jnp.inf)), axis
-        )
-        hi = lax.pmax(
-            jnp.max(jnp.where(mask_b, int_block, -jnp.inf)), axis
-        )
-        span = jnp.maximum(hi - lo, 1e-6)
-
-        def flood(labels, allowed):
-            def inner(state):
-                lab, _ = state
-                new = _sharded_adopt(lab, allowed, axis, connectivity)
-                changed = lax.psum(
-                    jnp.any(new != lab).astype(jnp.int32), axis
-                )
-                return new, changed > 0
-
-            out, _ = lax.while_loop(
-                lambda s: s[1], inner, (labels, jnp.bool_(True))
-            )
-            return out
-
-        def level_body(i, labels):
-            level = hi - span * (i + 1) / n_levels
-            allowed = mask_b & (int_block >= level)
-            return flood(labels, allowed)
-
-        labels = lax.fori_loop(0, n_levels, level_body, seed_block)
-        labels = flood(labels, mask_b)
-        return jnp.where(mask_b, labels, 0)
-
-    mapped = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(PartitionSpec(axis), PartitionSpec(axis), PartitionSpec(axis)),
-        out_specs=PartitionSpec(axis),
-    )
-    spec = NamedSharding(mesh, PartitionSpec(axis))
-    return jax.jit(mapped)(
-        jax.device_put(intensity, spec),
-        jax.device_put(seeds, spec),
-        jax.device_put(mask, spec),
-    )

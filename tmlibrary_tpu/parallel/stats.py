@@ -63,6 +63,23 @@ def _mesh_axis_size(mesh: Mesh, axis: "str | tuple[str, ...]") -> int:
     return out
 
 
+@functools.lru_cache(maxsize=16)
+def _cached_scan_and_merge(mesh: Mesh, axis):
+    """The compiled scan-and-fold, built and jitted once per (mesh,
+    axis): a fresh ``jax.jit(shard_map(...))`` per call re-traced,
+    re-lowered and read the compile cache once per channel of every well
+    (``halo._cached_gaussian_halo_2d`` says what that cost)."""
+    return jax.jit(shard_map(
+        functools.partial(_scan_and_merge, axis=axis),
+        mesh=mesh,
+        in_specs=PartitionSpec(axis),
+        out_specs=PartitionSpec(),  # merged state identical on all shards
+        # the all_gather + in-order fold makes outputs replicated, but the
+        # varying-axis checker can't prove it statically
+        check_vma=False,
+    ))
+
+
 def sharded_welford(stack: jax.Array, mesh: Mesh, axis: str = "sites") -> WelfordState:
     """Merged :class:`WelfordState` over a (B, H, W) stack sharded on the
     leading axis.
@@ -80,17 +97,9 @@ def sharded_welford(stack: jax.Array, mesh: Mesh, axis: str = "sites") -> Welfor
     size = _mesh_axis_size(mesh, axis)
     b = stack.shape[0]
     head = (b // size) * size
-    fn = shard_map(
-        functools.partial(_scan_and_merge, axis=axis),
-        mesh=mesh,
-        in_specs=PartitionSpec(axis),
-        out_specs=PartitionSpec(),  # merged state identical on all shards
-        # the all_gather + in-order fold makes outputs replicated, but the
-        # varying-axis checker can't prove it statically
-        check_vma=False,
-    )
+    fn = _cached_scan_and_merge(mesh, axis)
     if head == b:
-        return jax.jit(fn)(stack)
+        return fn(stack)
     if head == 0:
         # fewer sites than devices: plain local scan (no shard has a
         # full row to work on)
@@ -99,7 +108,7 @@ def sharded_welford(stack: jax.Array, mesh: Mesh, axis: str = "sites") -> Welfor
     # op-by-op execution keeps them bit-reproducible against the same
     # composition written by hand (jit refuses nothing but fuses
     # differently)
-    head_state = jax.jit(fn)(stack[:head])
+    head_state = fn(stack[:head])
     tail_state = welford_scan(stack[head:])
     return welford_merge(head_state, tail_state)
 

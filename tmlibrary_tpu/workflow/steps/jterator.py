@@ -54,19 +54,49 @@ def _well_shard(batch: dict) -> str:
     return f"well_{plate}_{well_row:02d}_{well_col:02d}"
 
 
-def _best_spatial_grid(requested: int, hm: int, wm: int) -> tuple[int, int]:
+def _best_spatial_grid(requested: int, hm: int, wm: int,
+                       squarest: bool = False) -> tuple[int, int]:
     """Largest ``nr * nc <= requested`` with ``nr`` dividing the mosaic
     rows and ``nc`` the columns; equal products prefer more rows (the
-    1-D-like shape, fewer seam axes)."""
+    1-D-like shape, fewer seam axes) or, with ``squarest``, the shape
+    nearest a square (a four-chip host's own 2 x 2), more rows among
+    equally square ones."""
     best = (1, 1)
     for nr in range(requested, 0, -1):
         if hm % nr:
             continue
         cap = requested // nr
         nc = next(k for k in range(cap, 0, -1) if wm % k == 0)
-        if nr * nc > best[0] * best[1]:
+        more = nr * nc - best[0] * best[1]
+        if more > 0 or (squarest and more == 0
+                        and abs(nr - nc) < abs(best[0] - best[1])):
             best = (nr, nc)
     return best
+
+
+def _spatial_mesh_shape(kind: str, requested: int, hm: int,
+                       wm: int) -> tuple[int, int]:
+    """``(rows, cols)`` of the mesh ``--layout spatial`` lays over an
+    ``hm x wm`` mosaic when ``requested`` devices are there, as
+    ``spatial_grid`` (``kind``) resolves it; ``cols`` is 1 for row shards.
+
+    The mesh must divide the mosaic EXACTLY — padding would corrupt the
+    global Otsu histogram and edge smoothing, breaking bit-identity with
+    the unsharded chain; shrink to divisors instead.  Candidates: 1-D row
+    shards vs a 2-D rows x cols tile grid — a 2-D factorization often
+    keeps MORE devices busy (e.g. 100 rows on 8 devices: rows-only
+    shrinks to 5, a 4x2 grid uses all 8), and the outputs are
+    layout-invariant either way.  An explicit ``grid`` takes the squarest
+    of the factorizations that use equally many devices; ``auto`` takes
+    the grid only where it uses more devices than row shards."""
+    if kind == "grid":
+        return _best_spatial_grid(requested, hm, wm, squarest=True)
+    n_rows1d = next(k for k in range(requested, 0, -1) if hm % k == 0)
+    if kind == "auto":
+        nr, nc = _best_spatial_grid(requested, hm, wm)
+        if nr * nc > n_rows1d:
+            return nr, nc
+    return n_rows1d, 1
 
 
 def _correct_batch(imgs, mean_log, std_log) -> "np.ndarray":
@@ -157,7 +187,10 @@ class ImageAnalysisRunner(Step):
                  help="sites per device batch (0 = auto: the tuning "
                       "sweep's best_batch on device backends, else 32)"),
         Argument("max_objects", int, default=256,
-                 help="static per-site object capacity"),
+                 help="static per-site object capacity; under layout "
+                      "'spatial' it bounds the connected components one "
+                      "shard of the mosaic may hold, and never under "
+                      "4096"),
         Argument("object_buckets", str, default="auto",
                  help="object-capacity bucket ladder (capacity.py): "
                       "'auto' compiles power-of-two buckets up to "
@@ -733,10 +766,11 @@ class ImageAnalysisRunner(Step):
             # SiteResult is a registered pytree: block on all leaves
             jax.block_until_ready(payload[0])
             return
-        jax.block_until_ready(payload["labels_dev"])
-        jax.block_until_ready(payload["count_dev"])
-        if payload["sec"] is not None:
-            jax.block_until_ready(payload["sec"][2])
+        with telemetry.span("device_wait"):
+            jax.block_until_ready(payload["labels_dev"])
+            jax.block_until_ready(payload["count_dev"])
+            if payload["sec"] is not None:
+                jax.block_until_ready(payload["sec"][2])
 
     @property
     def persist_serial(self) -> bool:
@@ -789,29 +823,30 @@ class ImageAnalysisRunner(Step):
         apply at mosaic scale (it would shrink tiles out of the grid), so
         shifted-in edges are zero-filled exactly like the sites path's
         ``shift_image``."""
-        imgs = self.store.read_sites(
-            sites, cycle=args["cycle"], channel=ch_index,
-            tpoint=args["tpoint"], zplane=args["zplane"],
-        )
-        if self.store.has_illumstats(cycle=args["cycle"], channel=ch_index):
-            cont = IllumstatsContainer.from_store(
-                self.store.read_illumstats(cycle=args["cycle"], channel=ch_index)
+        with telemetry.span("stitch", bytes=4 * n_sy * h * n_sx * w):
+            imgs = self.store.read_sites(
+                sites, cycle=args["cycle"], channel=ch_index,
+                tpoint=args["tpoint"], zplane=args["zplane"],
             )
-            imgs = _correct_batch(imgs, cont.mean_log, cont.std_log)
-        shifts = None
-        if args.get("spatial_align", True) and self.store.has_shifts(
-            args["cycle"]
-        ):
-            shifts = self.store.read_shifts(args["cycle"])
-        mosaic = np.zeros((n_sy * h, n_sx * w), np.float32)
-        for img, r, site_idx in zip(imgs, srefs, sites):
-            if shifts is not None:
-                dy, dx = int(shifts[site_idx][0]), int(shifts[site_idx][1])
-                if dy or dx:
-                    img = _host_shift(img, dy, dx)
-            mosaic[r.site_y * h:(r.site_y + 1) * h,
-                   r.site_x * w:(r.site_x + 1) * w] = img
-        return mosaic
+            if self.store.has_illumstats(cycle=args["cycle"], channel=ch_index):
+                cont = IllumstatsContainer.from_store(
+                    self.store.read_illumstats(cycle=args["cycle"], channel=ch_index)
+                )
+                imgs = _correct_batch(imgs, cont.mean_log, cont.std_log)
+            shifts = None
+            if args.get("spatial_align", True) and self.store.has_shifts(
+                args["cycle"]
+            ):
+                shifts = self.store.read_shifts(args["cycle"])
+            mosaic = np.zeros((n_sy * h, n_sx * w), np.float32)
+            for img, r, site_idx in zip(imgs, srefs, sites):
+                if shifts is not None:
+                    dy, dx = int(shifts[site_idx][0]), int(shifts[site_idx][1])
+                    if dy or dx:
+                        img = _host_shift(img, dy, dx)
+                mosaic[r.site_y * h:(r.site_y + 1) * h,
+                       r.site_x * w:(r.site_x + 1) * w] = img
+            return mosaic
 
     def _stitch_validity(
         self, sites, srefs, args, n_sy, n_sx, h, w
@@ -858,7 +893,7 @@ class ImageAnalysisRunner(Step):
         mosaic = self._stitched_channel(sites, srefs, idx, args, n_sy, n_sx, h, w)
         valid = self._stitch_validity(sites, srefs, args, n_sy, n_sx, h, w)
         return {
-            "idx": idx, "srefs": srefs, "h": h, "w": w,
+            "idx": idx, "channel": ch_name, "srefs": srefs, "h": h, "w": w,
             "n_sy": n_sy, "n_sx": n_sx, "mosaic": mosaic, "valid": valid,
         }
 
@@ -887,10 +922,13 @@ class ImageAnalysisRunner(Step):
         whole-well overlay PNG per object family."""
         import jax
         import jax.numpy as jnp
-        import pandas as pd
-        from jax.sharding import Mesh
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-        from tmlibrary_tpu.parallel.label import sharded_segment_mosaic
+        from tmlibrary_tpu.parallel.label import (
+            segment_mosaic,
+            sharded_otsu_mask,
+            watershed_mosaic,
+        )
 
         args = batch["args"]
         sites = batch["sites"]
@@ -926,51 +964,28 @@ class ImageAnalysisRunner(Step):
         requested = args["n_devices"] or len(jax.devices())
         requested = min(requested, len(jax.devices()))
         hm, wm = mosaic.shape
-        # the mesh must divide the mosaic EXACTLY — padding would corrupt
-        # the global Otsu histogram and edge smoothing, breaking
-        # bit-identity with the unsharded chain; shrink to divisors
-        # instead.  Candidates: 1-D row shards vs a 2-D rows x cols tile
-        # grid — a 2-D factorization often keeps MORE devices busy (e.g.
-        # 100 rows on 8 devices: rows-only shrinks to 5, a 4x2 grid uses
-        # all 8), and the outputs are layout-invariant either way.
-        n_rows1d = next(k for k in range(requested, 0, -1) if hm % k == 0)
-        nr2, nc2 = _best_spatial_grid(requested, hm, wm)
         kind = args.get("spatial_grid", "auto")
-        use_grid = kind == "grid" or (
-            kind == "auto" and nr2 * nc2 > n_rows1d
-        )
-        if use_grid:
-            from tmlibrary_tpu.parallel.label import sharded_segment_mosaic_2d
-
-            n_dev = nr2 * nc2
-            if n_dev < requested:
-                logger.info(
-                    "spatial layout: %dx%d grid uses %d of %d devices — "
-                    "mosaic %dx%d must divide the mesh evenly",
-                    nr2, nc2, n_dev, requested, hm, wm,
-                )
+        nr, nc = _spatial_mesh_shape(kind, requested, hm, wm)
+        n_dev = nr * nc
+        if n_dev < requested:
+            logger.info(
+                "spatial layout: %dx%d mesh uses %d of %d devices — "
+                "mosaic %dx%d must divide the mesh evenly",
+                nr, nc, n_dev, requested, hm, wm,
+            )
+        # an explicit grid runs the 2-D programs also where the columns
+        # cannot be split (a column axis of one)
+        if kind == "grid" or nc > 1:
             mesh = Mesh(
-                np.asarray(jax.devices()[:n_dev]).reshape(nr2, nc2),
+                np.asarray(jax.devices()[:n_dev]).reshape(nr, nc),
                 ("rows", "cols"),
             )
-            mesh_shape = [nr2, nc2]
-            labels, count = sharded_segment_mosaic_2d(
-                jnp.asarray(mosaic), mesh, sigma=args["spatial_sigma"],
-                threshold=threshold,
-            )
         else:
-            n_dev = n_rows1d
-            if n_dev < requested:
-                logger.info(
-                    "spatial layout: using %d of %d devices — mosaic rows "
-                    "%d must divide the mesh evenly", n_dev, requested, hm,
-                )
             mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("rows",))
-            mesh_shape = [n_dev, 1]
-            labels, count = sharded_segment_mosaic(
-                jnp.asarray(mosaic), mesh, sigma=args["spatial_sigma"],
-                threshold=threshold,
-            )
+        # host memory to the shards directly: no device ever holds a
+        # whole plane, and nothing is re-sharded on the device
+        sharding = NamedSharding(mesh, PartitionSpec(*mesh.axis_names))
+
         # with a secondary channel every stitched mosaic is used at least
         # twice (watershed input + both families' intensity loops), so
         # memoize — accepting a peak of one mosaic per channel.  Without
@@ -988,48 +1003,62 @@ class ImageAnalysisRunner(Step):
                 stitched[i] = m
             return m
 
+        sec_np = None
+        if sec_ch:
+            sec_np = np.asarray(
+                get_channel(exp.channel_index(sec_ch)), np.float32)
+        h2d_bytes = int(mosaic.nbytes) + (
+            int(sec_np.nbytes) if sec_np is not None else 0)
+        with telemetry.span("upload", bytes=h2d_bytes):
+            img = jax.device_put(mosaic, sharding)
+            sec_img = (jax.device_put(sec_np, sharding)
+                       if sec_np is not None else None)
+
         # secondary objects over the whole mosaic: primary labels seed a
         # distributed watershed through a second channel (the sites
         # layout's segment_secondary chain — otsu mask, level flooding,
         # seed ids preserved), so cells keep their nucleus' GLOBAL id.
-        # Chained DEVICE-side on the un-fetched primary labels, so the
-        # whole well is one async dispatch chain.
-        sec = None
-        if sec_ch:
-            from tmlibrary_tpu.ops import threshold as threshold_ops
-            from tmlibrary_tpu.parallel.label import (
-                distributed_watershed_from_seeds,
-                distributed_watershed_from_seeds_2d,
+        # Chained DEVICE-side on the un-fetched primary labels; the one
+        # wait of the chain is the root table's overflow check.
+        sec = steps_dev = None
+        with telemetry.span("segment"):
+            labels, count, info = segment_mosaic(
+                img, mesh, sigma=args["spatial_sigma"], threshold=threshold,
+                # max_objects is a capacity a SITE (256 by default);
+                # a shard holds several sites' objects, so its root
+                # table never gets under 4096
+                max_roots_per_shard=max(args["max_objects"], 4096),
             )
+            # Otsu's cut a stain, as used (before the secondary factor),
+            # and un-fetched what each was taken from
+            otsu = {prefetched["channel"]: info.pop("otsu")}
+            if sec_img is not None:
+                if valid is not None:
+                    from tmlibrary_tpu.ops import threshold as threshold_ops
 
-            sec_idx = exp.channel_index(sec_ch)
-            sec_np = np.asarray(get_channel(sec_idx), np.float32)
-            img = jnp.asarray(sec_np)
-            if valid is not None:
-                # same zero-stripe exclusion as the primary threshold
-                t_sec = float(
-                    threshold_ops.otsu_value(jnp.asarray(sec_np[valid]))
-                ) * args["spatial_secondary_factor"]
-                mask = img > t_sec
-            else:
-                mask = threshold_ops.threshold_otsu(
-                    img,
-                    correction_factor=args["spatial_secondary_factor"],
+                    # same zero-stripe exclusion as the primary threshold
+                    t_sec = threshold_ops.otsu_value(
+                        jnp.asarray(sec_np[valid]))
+                    otsu[sec_ch] = {"cut": t_sec}
+                    mask = sec_img > (
+                        float(t_sec) * args["spatial_secondary_factor"])
+                else:
+                    mask, otsu[sec_ch] = sharded_otsu_mask(
+                        sec_img, mesh,
+                        correction_factor=args["spatial_secondary_factor"],
+                    )
+                sec_labels, steps_dev = watershed_mosaic(
+                    sec_img, labels, mask, mesh,
+                    n_levels=args["spatial_secondary_levels"],
                 )
-            flood = (
-                distributed_watershed_from_seeds_2d if use_grid
-                else distributed_watershed_from_seeds
-            )
-            sec = (args["spatial_secondary_objects"], sec_np, flood(
-                img, labels, mask, mesh,
-                n_levels=args["spatial_secondary_levels"],
-            ))
+                sec = (args["spatial_secondary_objects"], sec_np, sec_labels)
 
         return {
             "batch": batch, "labels_dev": labels, "count_dev": count,
             "sec": sec, "mosaic": mosaic, "get_channel": get_channel,
-            "sites": sites, "srefs": srefs, "mesh_shape": mesh_shape,
-            "tpoint": tpoint, "zplane": zplane,
+            "sites": sites, "srefs": srefs, "mesh_shape": [nr, nc],
+            "tpoint": tpoint, "zplane": zplane, "h2d_bytes": h2d_bytes,
+            "info": info, "adopt_steps_dev": steps_dev, "otsu_dev": otsu,
         }
 
     def _persist_spatial(self, batch: dict, ctx: dict) -> dict:
@@ -1043,8 +1072,18 @@ class ImageAnalysisRunner(Step):
         srefs = ctx["srefs"]
         tpoint, zplane = ctx["tpoint"], ctx["zplane"]
         get_channel = ctx["get_channel"]
-        labels = np.asarray(ctx["labels_dev"])
-        count = int(ctx["count_dev"])
+        with telemetry.span("fetch") as fetched:
+            labels = np.asarray(ctx["labels_dev"])
+            count = int(ctx["count_dev"])
+            sec_labels = (None if ctx["sec"] is None
+                          else np.asarray(ctx["sec"][2]))
+            fetched["bytes"] = int(labels.nbytes) + (
+                0 if sec_labels is None else int(sec_labels.nbytes))
+            # Otsu's cut a stain, as the programs used it: two scalars
+            # that come with the labels, so the launch waits for neither
+            otsu_cut = {stain: float(reading["cut"])
+                        for stain, reading in ctx["otsu_dev"].items()}
+            fetched["otsu_cut"] = otsu_cut
         shard = _well_shard(batch)
 
         def emit_figure(fam_name, fam_mosaic, fam_labels):
@@ -1065,9 +1104,8 @@ class ImageAnalysisRunner(Step):
         objects = {name: count}
         emit_figure(name, ctx["mosaic"], labels)
 
-        if ctx["sec"] is not None:
-            sec_name, sec_np, sec_labels_dev = ctx["sec"]
-            sec_labels = np.asarray(sec_labels_dev)
+        if sec_labels is not None:
+            sec_name, sec_np, _ = ctx["sec"]
             # watershed preserves seed ids: the id space (and count) is
             # the primary's, so features join across the two families
             self._persist_mosaic_objects(
@@ -1078,13 +1116,33 @@ class ImageAnalysisRunner(Step):
             emit_figure(sec_name, sec_np, sec_labels)
 
         self._note_sites(len(sites))
-        return {
+        summary = {
             "n_sites": len(sites),
             "objects": objects,
             "mosaic_shape": [int(labels.shape[0]), int(labels.shape[1])],
             "layout": "spatial",
             "mesh_shape": ctx["mesh_shape"],
+            # bytes handed to the device: every plane, once, to its shards
+            "h2d_bytes": ctx["h2d_bytes"],
+            "otsu_cut": otsu_cut,
         }
+        # what the sharded programs counted (a 1-device CPU mesh runs the
+        # native union-find and frontier flood, which count nothing): the
+        # fullest shard's roots against max_objects, the seam loop's
+        # rounds, the watershed's adopt steps
+        counted = dict(ctx["info"])
+        if ctx["adopt_steps_dev"] is not None:
+            counted["adopt_steps"] = int(ctx["adopt_steps_dev"])
+        summary.update(counted)
+        if counted and telemetry.enabled():
+            reg = telemetry.get_registry()
+            for key in ("seam_rounds", "adopt_steps"):
+                if key in counted:
+                    reg.counter(
+                        f"tmx_jterator_mosaic_{key}_total").inc(counted[key])
+            reg.gauge("tmx_jterator_mosaic_roots_max_per_shard").set(
+                counted["roots_max_per_shard"])
+        return summary
 
     def _persist_mosaic_objects(
         self, name, labels, count, batch, args, sites, srefs,
@@ -1105,8 +1163,9 @@ class ImageAnalysisRunner(Step):
                    r.site_x * w:(r.site_x + 1) * w]
             for r in srefs
         ])
-        self.store.write_labels(per_site, sites, name,
-                                tpoint=tpoint, zplane=zplane)
+        with telemetry.span("write_labels"):
+            self.store.write_labels(per_site, sites, name,
+                                    tpoint=tpoint, zplane=zplane)
 
         # ragged global features, host-side (object count is dynamic here —
         # nothing is padded to max_objects in the mosaic path).  ONE
@@ -1115,9 +1174,10 @@ class ImageAnalysisRunner(Step):
         # interpreter loop on a plate-scale mosaic.
         from tmlibrary_tpu import native as native_mod
 
-        area_i, cy_sum, cx_sum, ymin, ymax, xmin, xmax = (
-            native_mod.mosaic_morph_host(labels, count)
-        )
+        with telemetry.span("morph"):
+            area_i, cy_sum, cx_sum, ymin, ymax, xmin, xmax = (
+                native_mod.mosaic_morph_host(labels, count)
+            )
         area = area_i[1:].astype(np.float64)
         denom = np.maximum(area, 1)
         cy = cy_sum[1:] / denom
@@ -1131,9 +1191,10 @@ class ImageAnalysisRunner(Step):
         from tmlibrary_tpu import native as native_mod
 
         if count and native_mod.available():
-            solidity = native_mod.solidity_host(
-                labels, count, areas=area
-            ).astype(np.float64)
+            with telemetry.span("solidity"):
+                solidity = native_mod.solidity_host(
+                    labels, count, areas=area
+                ).astype(np.float64)
         else:
             if count:
                 logger.info(
@@ -1170,7 +1231,9 @@ class ImageAnalysisRunner(Step):
                     cols[f"Intensity_{stat}_{ch.name}"] = empty
                 continue
             vals_mosaic = get_channel(ch.index)
-            s2, q2, mn2, mx2 = _mosaic_intensity_stats(labels, vals_mosaic, count)
+            with telemetry.span("intensity", channel=ch.name):
+                s2, q2, mn2, mx2 = _mosaic_intensity_stats(
+                    labels, vals_mosaic, count)
             mean2 = s2[1:] / denom
             var2 = np.maximum(q2[1:] / denom - mean2 * mean2, 0.0)
             cols[f"Intensity_mean_{ch.name}"] = mean2
@@ -1191,8 +1254,13 @@ class ImageAnalysisRunner(Step):
             zern = zernike_host_features(labels, count, z_degree)
             for z_idx, (n_z, m_z, _) in enumerate(_zernike_coeffs(z_degree)):
                 cols[f"Zernike_{n_z}_{m_z}"] = zern[:, z_idx].astype(np.float64)
-        table = pd.DataFrame(cols)
-        self.store.append_features(name, table, shard=shard)
+        with telemetry.span("write_features") as written:
+            table = pd.DataFrame(cols)
+            self.store.append_features(name, table, shard=shard)
+            # object rows and feature columns (the seven site and label
+            # keys left out), as the sites path writes them
+            written["rows"] = len(table)
+            written["columns"] = len(cols) - 7
 
         if args.get("as_polygons"):
             # mosaic-frame polygons: one ring per GLOBAL object, traced on
