@@ -58,7 +58,13 @@ def drawn():
 
 @pytest.fixture(scope="module")
 def units(tmp_path_factory, drawn, devices):
-    """Two units of the same well in ONE process: ``[(store, events)]``."""
+    """Two units of the same well in ONE process: ``[(store, events)]``.
+    The first starts from empty jit caches, whatever ran in this process
+    before (``tests/benchmark/test_mosaic_cell.py`` submits the same unit:
+    on one xdist worker its programs would be this first unit's hits)."""
+    import jax
+
+    jax.clear_caches()
     work = tmp_path_factory.mktemp("cp3mosaic")
     return [submit(work, f"exp{i}", drawn[0]) for i in range(2)]
 

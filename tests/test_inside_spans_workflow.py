@@ -27,10 +27,15 @@ EXPECTED = {
     "imextract": {("step", "decode"), ("step", "write")},
     "corilla": {("step", "read_wait"), ("step", "scan"),
                 ("step", "finalize"), ("step", "write")},
-    "illuminati": {("step", "stats_read"), ("step", "read"),
-                   ("step", "prep"), ("step", "mosaic"),
-                   ("step", "pyramid"), ("step", "level_fetch"),
-                   ("step", "encode")},
+    # a channel through the executor: its reads on a prefetch worker (no
+    # enclosing span there), correction and layout under the engine
+    # thread's dispatch, the levels under a persist worker's persist
+    "illuminati": {("step", "prefetch_wait"), ("step", "dispatch"),
+                   (None, "persist"),
+                   (None, "stats_read"), (None, "read"),
+                   ("dispatch", "prep"), ("dispatch", "mosaic"),
+                   ("dispatch", "pyramid"), ("persist", "level_fetch"),
+                   ("persist", "encode")},
     "jterator": {("step", "prefetch_wait"), ("step", "dispatch"),
                  (None, "device_block"), (None, "persist"),
                  (None, "load"), ("dispatch", "upload"),
@@ -197,6 +202,41 @@ def test_pipeline_stats_books_the_escalations_wait_as_device_time(
     assert stats["host_s"] == pytest.approx(
         phases["prefetch_wait"]["total_s"] + phases["persist"]["total_s"]
         - wait, abs=2e-3)
+
+
+def test_first_batch_is_the_batch_programs_not_an_illuminati_channel(
+        ledger_events):
+    """illuminati has ``launch_batch`` too (it runs before jterator), but
+    ``first_batch`` / ``tmx_time_to_first_batch_seconds`` stay the cold
+    start of the step that runs batch programs — live and replayed."""
+    from tmlibrary_tpu import telemetry
+
+    (first,) = [e for e in ledger_events if e.get("event") == "first_batch"]
+    assert first["step"] == "jterator" and first["first_batch_index"] == 0
+    order = [(e.get("event"), e.get("step")) for e in ledger_events]
+    assert order.index(("step_done", "illuminati")) \
+        < order.index(("first_batch", "jterator"))
+    prom = telemetry.render_prometheus(
+        telemetry.registry_from_ledger(ledger_events).snapshot())
+    (sample,) = [v for n, _, v in telemetry.parse_prometheus(prom)
+                 if n == "tmx_time_to_first_batch_seconds"]
+    assert sample == pytest.approx(first["time_to_first_batch_s"])
+
+
+def test_illuminati_channels_ran_on_the_executor_beside_each_other(
+        ledger_events):
+    (done,) = [e for e in ledger_events if e.get("event") == "step_done"
+               and e.get("step") == "illuminati"]
+    stats = done["pipeline_stats"]
+    # five channels at depth 2: two stage workers
+    assert stats["n_batches"] == 5 and stats["persist_workers"] == 2
+    threads: dict = {}
+    for e in _spans(ledger_events, "illuminati"):
+        threads.setdefault(e["span"], set()).add(e["thread"])
+    assert all(t.startswith("tmx-persist") for t in threads["encode"])
+    assert all(t.startswith("tmx-prefetch") for t in threads["read"])
+    assert threads["mosaic"] == threads["dispatch"]
+    assert len(threads["mosaic"]) == 1
 
 
 # ------------------------------------------------- scopes: names, not values

@@ -1503,8 +1503,10 @@ def registry_from_ledger(events: Iterable[dict]) -> MetricsRegistry:
                         step=step, **hl).inc()
         elif kind == "first_batch":
             # cold-start attribution (engine.py): wall seconds from
-            # run_started to the first persisted batch — the number the
-            # aotstore warm path exists to shrink
+            # run_started to the first persisted batch of a step that
+            # runs batch programs (jterator's, not an illuminati
+            # channel's) — the number the aotstore warm path exists to
+            # shrink
             if "time_to_first_batch_s" in ev:
                 reg.gauge("tmx_time_to_first_batch_seconds", **hl).set(
                     float(ev["time_to_first_batch_s"]))

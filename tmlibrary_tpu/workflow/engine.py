@@ -1084,14 +1084,15 @@ class Workflow:
                             elapsed=b_elapsed,
                             attempts=outcome.attempts,
                             result=outcome.value)
-                        # only device-dispatching steps (the launch/
-                        # block/persist protocol — where the XLA
+                        # only steps that run batch programs (they
+                        # expose the compile-ahead hook — where the XLA
                         # compiles live) count: a metaconfig batch
-                        # landing in milliseconds would mask the
-                        # cold-start this metric exists to expose
+                        # landing in milliseconds, or an illuminati
+                        # channel, would mask the cold-start this
+                        # metric exists to expose
                         if (not getattr(self, "_first_batch_noted", True)
                                 and getattr(self, "_run_wall_t0", None)
-                                and hasattr(step, "launch_batch")):
+                                and hasattr(step, "speculate_ahead")):
                             self._first_batch_noted = True
                             ttfb = time.time() - self._run_wall_t0
                             # NOT batch= : any step+batch event mints a

@@ -10,17 +10,38 @@ batched on device, the mosaic assembles host-side (it can exceed HBM for
 large plates), the downsample chain runs on device per level, PNG tiles go
 to ``pyramids/<channel>/<level>/<row>_<col>.png`` — a zoomify-style layout
 any slippy-map viewer can serve statically.
+
+The channels are in flight together: the step exposes the pipelined
+executor's split (``workflow/pipelined.py``), so one channel's levels are
+fetched and encoded on a persist worker while the next channel's planes
+are read (prefetch pool), corrected and laid out (engine thread).  How many
+channels are between launch and the end of persist at once is bounded by
+what a channel holds — its float32 mosaic on the host, its pyramid on the
+device — against the memory the step finds free (:func:`channels_in_flight`);
+a plate whose pyramid fits once runs one channel at a time, as the
+sequential path (:meth:`PyramidBuilder.run_batch`) always does.  One PNG
+pool serves every channel that is persisting.
 """
 
 from __future__ import annotations
+
+import concurrent.futures as cf
+import contextlib
+import json
+import os
+import threading
+import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tmlibrary_tpu import telemetry
 from tmlibrary_tpu.errors import WorkflowError
 from tmlibrary_tpu.models.experiment import SiteRef
 from tmlibrary_tpu.models.image import IllumstatsContainer
+from tmlibrary_tpu.models.mapobject import plate_grid, plate_mosaic_shape
 from tmlibrary_tpu.models.metadata import ChannelLayer
 from tmlibrary_tpu.ops import image_ops
 from tmlibrary_tpu.ops.pyramid import cut_tiles, pyramid_levels, to_uint8
@@ -28,6 +49,56 @@ from tmlibrary_tpu.utils import create_partitions
 from tmlibrary_tpu.workflow.api import Step
 from tmlibrary_tpu.workflow.args import Argument, ArgumentCollection
 from tmlibrary_tpu.workflow.registry import register_step
+
+#: threads of the step's PNG pool
+ENCODE_THREAD_PREFIX = "tmx-illuminati-png"
+
+
+def free_memory() -> tuple[int | None, int | None]:
+    """``(device, host)`` bytes the step may plan with, None where the
+    platform does not say: the first device's ``bytes_limit`` less
+    ``bytes_in_use`` (``memory_stats()``; the CPU backend has none) and the
+    kernel's ``MemAvailable``."""
+    stats = jax.devices()[0].memory_stats() or {}
+    device = None
+    if stats.get("bytes_limit"):
+        device = int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+    host = None
+    try:
+        with open("/proc/meminfo") as f:
+            for line in f:
+                if line.startswith("MemAvailable:"):
+                    host = int(line.split()[1]) * 1024
+                    break
+    except OSError:
+        pass
+    return device, host
+
+
+def channels_in_flight(mosaic_bytes: int, device_free: int | None,
+                       host_free: int | None) -> int:
+    """How many channels may be between launch and the end of persist at
+    once.  A channel in flight holds, of its float32 mosaic's bytes: on the
+    device the level chain (4/3) and the uint8 level being fetched (1/4);
+    on the host its raw planes (1/2, read ahead), the mosaic until it is
+    uploaded (1) and the uint8 level being cut (1/4).  Half of what is free
+    is planned with — ``prep``'s batches, XLA's temporaries and everything
+    else the process allocates take the rest.  At least one."""
+    bounds = []
+    if device_free is not None:
+        bounds.append(device_free // 2 // max(1, mosaic_bytes * 19 // 12))
+    if host_free is not None:
+        bounds.append(host_free // 2 // max(1, mosaic_bytes * 7 // 4))
+    return max(1, min(bounds)) if bounds else 1
+
+
+class _Flight(NamedTuple):
+    """The step's in-flight plan (:meth:`PyramidBuilder._flight`)."""
+
+    #: one slot a channel between launch and the end of persist
+    slots: threading.Semaphore
+    #: whether the prefetch pool reads the planes ahead of the launch
+    reads_ahead: bool
 
 
 @register_step("illuminati")
@@ -44,6 +115,14 @@ class PyramidBuilder(Step):
                       "(mosaics larger than one chip's HBM)"),
     )
 
+    def __init__(self, store):
+        super().__init__(store)
+        self._lock = threading.Lock()
+        #: planned at the first batch (:meth:`_flight`)
+        self._flight_plan: _Flight | None = None
+        self._pool: cf.ThreadPoolExecutor | None = None
+        self._pool_users = 0
+
     def create_batches(self, args):
         exp = self.store.experiment
         return [
@@ -53,13 +132,57 @@ class PyramidBuilder(Step):
             if self.store.has_plane(cycle=args["cycle"], channel=ch.index)
         ]
 
-    # ------------------------------------------------------------------ run
-    def run_batch(self, batch: dict) -> dict:
-        import time
+    # ------------------------------------------------------ channels in flight
+    def _flight(self) -> _Flight:
+        """The step's in-flight plan, made once from the largest plate's
+        mosaic and the memory free at the first batch: a semaphore of
+        :func:`channels_in_flight` slots, and whether the prefetch pool
+        reads planes ahead — only when every planned channel fits in flight
+        at once, so that the planes read ahead are never more than the
+        plan counted."""
+        with self._lock:
+            if self._flight_plan is None:
+                exp = self.store.experiment
+                mosaic_bytes = 4 * max(
+                    int(np.prod(plate_mosaic_shape(exp, p.name)))
+                    for p in exp.plates)
+                slots = channels_in_flight(mosaic_bytes, *free_memory())
+                self._flight_plan = _Flight(
+                    threading.Semaphore(slots),
+                    slots >= len(self.list_batches()),
+                )
+            return self._flight_plan
 
-        from tmlibrary_tpu import telemetry
+    @contextlib.contextmanager
+    def _encode_pool(self):
+        """The step's one PNG pool, for as long as any channel persists:
+        the first to arrive opens it, the last to leave (done or failed)
+        closes it.  cv2 releases the GIL during imencode, so the threads
+        encode side by side (the reference fanned per-level tile jobs out
+        to the cluster)."""
+        with self._lock:
+            if self._pool_users == 0:
+                self._pool = cf.ThreadPoolExecutor(
+                    max_workers=min(8, os.cpu_count() or 1),
+                    thread_name_prefix=ENCODE_THREAD_PREFIX)
+            self._pool_users += 1
+            pool = self._pool
+        try:
+            yield pool
+        finally:
+            with self._lock:
+                self._pool_users -= 1
+                if self._pool_users == 0:
+                    self._pool = None
+                    pool.shutdown(wait=True)
 
-        bt0 = time.perf_counter()
+    # ------------------------------------------------- launch/persist split
+    # (the pipelined executor's step protocol — workflow/pipelined.py)
+    def prefetch_batch(self, batch: dict) -> dict:
+        """Host-side input loading only (illumination statistics, the shift
+        table, the site grid and — where the plan allows — the channel's
+        planes): safe on a prefetch worker thread."""
+        t0 = time.perf_counter()
         args = batch["args"]
         exp = self.store.experiment
         channel = batch["channel"]
@@ -72,6 +195,66 @@ class PyramidBuilder(Step):
                 stats = IllumstatsContainer.from_store(
                     self.store.read_illumstats(cycle=cycle, channel=channel)
                 )
+        refs = [
+            (SiteRef(plate.name, w.row, w.column, s.y, s.x), w, s)
+            for w in plate.wells
+            for s in w.sites
+        ]
+        parts = [
+            (part, [self.store.site_linear_index(r) for r, _, _ in part])
+            for part in create_partitions(refs, args["batch_size"])
+        ]
+        shifts_table = (
+            self.store.read_shifts(cycle)
+            if args["align"] and self.store.has_shifts(cycle)
+            else np.zeros((self.store.n_sites, 2), np.int32)
+        )
+        stacks = None
+        if self._flight().reads_ahead:
+            stacks = [self._read(idx, cycle, channel) for _, idx in parts]
+        return {"plate": plate, "stats": stats, "parts": parts,
+                "shifts": shifts_table, "stacks": stacks,
+                "seconds": time.perf_counter() - t0}
+
+    def _read(self, idx: list[int], cycle: int, channel: int) -> np.ndarray:
+        with telemetry.span("read"):
+            return self.store.read_sites(idx, cycle=cycle, channel=channel)
+
+    def launch_batch(self, batch: dict, prefetched: dict | None = None):
+        """Correct, lay out and upload one channel and dispatch its level
+        chain; returns ``(batch, ctx)`` with the un-fetched device levels
+        in ``ctx``.  Waits for a free slot of the in-flight plan first."""
+        if prefetched is None:
+            prefetched = self.prefetch_batch(batch)
+        slots = self._flight().slots
+        slots.acquire()
+        try:
+            return batch, self._launch(batch, prefetched)
+        except BaseException:
+            slots.release()
+            raise
+
+    def persist_batch(self, batch: dict, ctx: dict) -> dict:
+        """Fetch and encode every level of one launched channel.  Entered
+        by several persist workers at once, each with another channel
+        (``pyramids/channelNN/`` is the channel's own)."""
+        try:
+            return self._persist(batch, ctx)
+        finally:
+            self._flight().slots.release()
+
+    def run_batch(self, batch: dict) -> dict:
+        """One channel from its reads to its tiles, on the calling thread:
+        the engine's sequential path and ``tmx illuminati run``."""
+        return self._persist(batch, self._launch(batch, self.prefetch_batch(batch)))
+
+    def _launch(self, batch: dict, pre: dict) -> dict:
+        t0 = time.perf_counter()
+        args = batch["args"]
+        exp = self.store.experiment
+        channel = batch["channel"]
+        cycle = args["cycle"]
+        plate, stats = pre["plate"], pre["stats"]
 
         # display range from corilla's exact raw-intensity percentiles
         # (reference: scale step); both bounds or neither
@@ -85,33 +268,20 @@ class PyramidBuilder(Step):
 
         # site grid geometry (shared helper — same layout as the static
         # outlines and the pyramid-depth computation)
-        from tmlibrary_tpu.models.mapobject import plate_grid, plate_mosaic_shape
-
-        rows, cols, spw_y, spw_x = plate_grid(exp, plate.name)
+        _, _, spw_y, spw_x = plate_grid(exp, plate.name)
         H, W = exp.site_height, exp.site_width
         mosaic = np.zeros(plate_mosaic_shape(exp, plate.name), np.float32)
 
-        refs = [
-            (SiteRef(plate.name, w.row, w.column, s.y, s.x), w, s)
-            for w in plate.wells
-            for s in w.sites
-        ]
-        shifts_table = (
-            self.store.read_shifts(cycle)
-            if args["align"] and self.store.has_shifts(cycle)
-            else np.zeros((self.store.n_sites, 2), np.int32)
-        )
-        for part in create_partitions(refs, args["batch_size"]):
-            idx = [self.store.site_linear_index(r) for r, _, _ in part]
-            with telemetry.span("read"):
-                stack = self.store.read_sites(idx, cycle=cycle, channel=channel)
+        for k, (part, idx) in enumerate(pre["parts"]):
+            stack = (self._read(idx, cycle, channel) if pre["stacks"] is None
+                     else pre["stacks"][k])
             # upload, the one `prep` program, and the fetch of its result
             with telemetry.span("prep", bytes=stack.nbytes):
                 prepped = np.asarray(
-                    prep(jnp.asarray(stack), jnp.asarray(shifts_table[idx]))
+                    prep(jnp.asarray(stack), jnp.asarray(pre["shifts"][idx]))
                 )
             with telemetry.span("mosaic"):
-                for (ref, w, s), img in zip(part, prepped):
+                for (_ref, w, s), img in zip(part, prepped):
                     y0 = (w.row * spw_y + s.y) * H
                     x0 = (w.column * spw_x + s.x) * W
                     mosaic[y0 : y0 + H, x0 : x0 + W] = img
@@ -137,27 +307,36 @@ class PyramidBuilder(Step):
                 levels = sharded_pyramid_levels(jnp.asarray(mosaic), mesh)
             else:
                 levels = pyramid_levels(jnp.asarray(mosaic))
-        out_dir = self.store.root / "pyramids" / f"channel{channel:02d}"
-        # PNG encode is host-side and embarrassingly parallel; cv2 releases
-        # the GIL during imencode, so a thread pool overlaps tile encodes
-        # (the reference fanned per-level tile jobs out to the cluster)
-        import concurrent.futures as cf
-        import os as _os
+        return {
+            "levels": levels,
+            "mosaic_shape": list(mosaic.shape),
+            "lower": float(lower),
+            "upper": float(upper),
+            "from_corilla": from_corilla,
+            "out_dir": self.store.root / "pyramids" / f"channel{channel:02d}",
+            # the channel's own seconds so far, waits between phases apart
+            "seconds": pre["seconds"] + time.perf_counter() - t0,
+        }
 
+    def _persist(self, batch: dict, ctx: dict) -> dict:
         import cv2
 
-        workers = min(8, _os.cpu_count() or 1)
+        t0 = time.perf_counter()
+        channel = batch["channel"]
+        levels, out_dir = ctx["levels"], ctx["out_dir"]
+        n_levels = len(levels)
         n_tiles = 0
-        with cf.ThreadPoolExecutor(max_workers=workers) as pool:
+        with self._encode_pool() as pool:
             # submit per level so only one level8 array is held at a time
             # (cut_tiles returns views into it) — encodes overlap the next
             # level's cut; futures are drained per level before the array
-            # is dropped
-            for li, level in enumerate(levels):
+            # is dropped, and a level leaves the device once it is fetched
+            for li in range(n_levels):
                 with telemetry.span("level_fetch"):
                     level8 = np.asarray(
-                        to_uint8(level, float(lower), float(upper)))
-                ldir = out_dir / f"{len(levels) - 1 - li}"
+                        to_uint8(levels[li], ctx["lower"], ctx["upper"]))
+                levels[li] = None
+                ldir = out_dir / f"{n_levels - 1 - li}"
                 ldir.mkdir(parents=True, exist_ok=True)
                 with telemetry.span("encode", bytes=level8.nbytes) as enc:
                     futures = {
@@ -170,29 +349,27 @@ class PyramidBuilder(Step):
                 if bad:
                     raise WorkflowError(
                         f"PNG tile encode failed for {len(bad)} tiles of "
-                        f"level {len(levels) - 1 - li}, e.g. {bad[0]}"
+                        f"level {n_levels - 1 - li}, e.g. {bad[0]}"
                     )
                 n_tiles += len(futures)
         layer = ChannelLayer(
             channel=f"channel{channel:02d}",
-            height=mosaic.shape[0],
-            width=mosaic.shape[1],
-            max_zoom=len(levels) - 1,
+            height=ctx["mosaic_shape"][0],
+            width=ctx["mosaic_shape"][1],
+            max_zoom=n_levels - 1,
         )
-        import json
-
         (out_dir / "layer.json").write_text(json.dumps(layer.to_dict()))
         telemetry.get_registry().throughput(
             "tmx_illuminati_tiles_per_sec"
-        ).add(n_tiles, time.perf_counter() - bt0)
+        ).add(n_tiles, ctx["seconds"] + time.perf_counter() - t0)
         return {
             "channel": channel,
-            "mosaic_shape": list(mosaic.shape),
-            "n_levels": len(levels),
+            "mosaic_shape": ctx["mosaic_shape"],
+            "n_levels": n_levels,
             "n_tiles": n_tiles,
-            "display_range": "corilla" if from_corilla else "mosaic",
-            "display_lower": float(lower),
-            "display_upper": float(upper),
+            "display_range": "corilla" if ctx["from_corilla"] else "mosaic",
+            "display_lower": ctx["lower"],
+            "display_upper": ctx["upper"],
         }
 
     def collect(self) -> dict:
