@@ -46,6 +46,19 @@ def module_section() -> list[str]:
     out = ["## jterator modules", "",
            "Registered JAX module implementations (`backend: tpu`); the "
            "signature's keyword arguments are the handles-file inputs.", "",
+           "A pipeline's `input.channels` entry is `{name, correct, align, "
+           "zstack, cycle}`.  `cycle` (an integer, optional) is the "
+           "acquisition cycle the channel's planes, illumination "
+           "statistics and alignment shifts are read from; without it the "
+           "channel comes from the jterator step's `cycle` argument, as "
+           "every channel of a one-cycle experiment does.  A multiplexed "
+           "plate segments on the first cycle's DAPI and measures a later "
+           "cycle's stain in those objects with `{name: Mito, align: true, "
+           "cycle: 1}`: each aligned channel goes through the batch "
+           "program under its own cycle's shift and every channel is "
+           "cropped to the window the `align` step stored.  A channel "
+           "asked from a cycle that holds no plane of it is a "
+           "`PipelineError` before anything is launched.", "",
            "| module | signature | reference |", "|---|---|---|"]
     for name in list_modules():
         fn = get_module(name)

@@ -304,3 +304,60 @@ def test_whole_site_program_compiles_for_v5e(config, size, batch, capacity,
     # GLCM's and the reductions' "native") are pure_callbacks, and none
     # may be in a program built for the chip
     assert "callback" not in compiled.as_text()
+
+
+# ------------------------------- the multiplexed plate's programs (PR 37)
+# (no limit on the compile's seconds here: the suite's six workers share
+# the host, and a guessed time fails on a loaded one; the suite's own
+# time limit ends a compile that never does)
+def test_registration_compiles_at_the_full_field(on_tpu):
+    """The align step's one program at a unit's launch: two wells'
+    eighteen pairs of 2160 x 2160 uint16 fields (2160 = 2^4 x 3^3 x 5 is
+    no power of two; nine pairs: 19.5 s and 764 MB of temporaries here).
+    What ``pairs_in_flight`` plans a pair with (16 float32 planes) has to
+    cover what the compiler takes."""
+    from tmlibrary_tpu.ops import registration
+
+    S = on_tpu
+    pairs = 18
+    stack = S((pairs, SMOKE_FIELD, SMOKE_FIELD), jnp.uint16)
+    t0 = time.perf_counter()
+    compiled = registration._batch_pcq_jit().lower(stack, stack).compile()
+    seconds = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    resident = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+    print(f"registration, {pairs} pairs: compiled in {seconds:.1f} s, "
+          f"temp {mem.temp_size_in_bytes / 1e6:.0f} MB")
+    assert resident <= pairs * 16 * 4 * SMOKE_FIELD * SMOKE_FIELD
+    assert "phase_correlation" in compiled.as_text()
+
+
+def test_multiplex_program_compiles_in_the_windows_frame(on_tpu):
+    """``cp3-multiplex``'s batch program at its top rung: seven uint16
+    planes, each under its own shift row, cropped to the stored window
+    (32 px a side: a 2096 x 2096 frame), twelve measure calls."""
+    import json
+    from pathlib import Path
+
+    from tmlibrary_tpu.jterator.description import PipelineDescription
+    from tmlibrary_tpu.jterator.pipeline import (ImageAnalysisPipeline,
+                                                 aligned_channels)
+
+    config = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
+                         / "configs" / "cp3-multiplex.json").read_text())
+    desc = PipelineDescription.from_dict(config["pipeline"])
+    names = aligned_channels(desc)
+    assert len(names) == 7
+    S = on_tpu
+    fn = ImageAnalysisPipeline(desc, SMOKE_CAPACITY).build_batch_fn(
+        window=(32, 32, 32, 32))
+    raw = {c: S((1, SMOKE_FIELD, SMOKE_FIELD), jnp.uint16) for c in names}
+    t0 = time.perf_counter()
+    compiled = fn.lower(raw, {}, S((1, len(names), 2), jnp.int32)).compile()
+    seconds = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    print(f"cp3-multiplex rung {SMOKE_CAPACITY}: compiled in {seconds:.1f} s,"
+          f" temp {mem.temp_size_in_bytes / 1e6:.0f} MB, code "
+          f"{mem.generated_code_size_in_bytes / 1e6:.0f} MB")
+    assert mem.temp_size_in_bytes < 4e9

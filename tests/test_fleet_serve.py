@@ -752,3 +752,35 @@ def test_shed_decisions_replay_identically_from_merged_ledgers(tmp_path):
         assert (replay.counter(name, **labels).value
                 == live.counter(name, **labels).value != 0), name
     execute_all(d1)
+
+
+# ================================== a claim's temp file is no claim (PR 36)
+@pytest.mark.parametrize("style", ["thread_temp_name", "pid_temp_name"])
+def test_reaper_takes_no_temp_file_of_a_claim_for_a_claim(tmp_path, style):
+    """PERF.md section 7's first race, as its deterministic witness: while
+    the lease renewer rewrites ``<job>.claim.<host>`` its temp file lies
+    beside it; ``job_claims`` read it as a claim of a host named
+    ``<host>.<pid>.tmp``, unreadable, and the daemon's own reaper swept
+    the live job back to ``incoming/`` (one re-spool).  None now, under
+    the temp name ``atomicio`` gives a writer thread and under the one it
+    gave a process before."""
+    from tmlibrary_tpu import atomicio
+
+    sroot = tmp_path / "srv"
+    store = make_exp(tmp_path, "exp")
+    serve.enqueue_job(sroot, spec("a-0", store.root))
+    d1 = daemon(sroot, "h1", lease=15.0)
+    d1._scan_incoming()
+    cpath = serve.claim_path(sroot, "a-0", "h1")
+    assert cpath.exists()
+    temp = (atomicio.temp_path(cpath) if style == "thread_temp_name"
+            else cpath.with_name(f"{cpath.name}.{os.getpid()}.tmp"))
+    temp.write_text('{"job_id": "a-0", "lease_dead')   # half a claim
+    assert temp.name.endswith(atomicio.TMP_SUFFIX)
+    assert [c[2] for c in serve.job_claims(sroot)] == ["h1"]
+    assert d1._reap_expired() == 0
+    assert not [e for e in merged(sroot)
+                if e.get("event") == "job_reclaimed"]
+    assert (serve.spool_dir(sroot, "admitted") / "a-0.json").exists()
+    temp.unlink()
+    assert execute_all(d1) == {"a-0": "done"}

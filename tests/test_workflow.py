@@ -660,8 +660,12 @@ def test_multiplexing_workflow_end_to_end(multiplex_source_dir, store):
     # stored roll that re-aligns cycle 1 is dy=-4 at every site
     np.testing.assert_array_equal(shifts, np.tile([[-4, 0]], (4, 1)))
     # rolling up by 4 exposes invalid rows at the bottom -> bottom margin
+    # of the intersection; what is stored, and cropped to, is its largest
+    # margin widened to the next multiple of 16 on every side
+    assert summary["align"]["collected"]["intersection"] == {
+        "top": 0, "bottom": 4, "left": 0, "right": 0}
     window = store.read_intersection()
-    assert window == {"top": 0, "bottom": 4, "left": 0, "right": 0}
+    assert window == {"top": 16, "bottom": 16, "left": 16, "right": 16}
 
 
 def test_workflow_resume_skips_completed_batches(source_dir, store):

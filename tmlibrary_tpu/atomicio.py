@@ -11,19 +11,41 @@ write the full payload to a sibling temp file, then rename over the
 target.  Readers either see the old complete file or the new complete
 file, nothing in between.
 
-The temp name embeds the writer's PID so two processes targeting the
-same path (a sampler thread and an engine ``finally`` block, or two
-fleet hosts mis-configured onto one file) cannot interleave partial
-writes into one temp file; the last rename wins, which is the same
-last-write-wins semantics whole-file writes always had.
+The temp name embeds the writer's PID and thread id, so neither two
+processes targeting the same path (two fleet hosts mis-configured onto
+one file) nor two threads of one process (a daemon's lease renewer and
+its main loop writing one heartbeat, the executor's workers) share a
+temp file: with the PID alone one thread's ``rename`` took the other's
+half-written file away.  The last rename wins, which is the same
+last-write-wins semantics whole-file writes always had.  The name starts
+with a dot, so no glob over a directory's artifacts (``*.claim.*``,
+``*.json``) takes a temp file for one of them.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import threading
 from pathlib import Path
 from typing import Any
+
+from tmlibrary_tpu import faults
+
+#: what every temp file's name ends in
+TMP_SUFFIX = ".tmp"
+
+
+def temp_path(path: Path) -> Path:
+    """The calling thread's own temp file beside ``path``."""
+    return path.with_name(
+        f".{path.name}.{os.getpid()}.{threading.get_ident()}{TMP_SUFFIX}")
+
+
+def atomic_write_bytes(path: Path | str, data: bytes,
+                       fsync: bool = False) -> None:
+    """``atomic_write_text`` for a binary payload."""
+    _atomic_write(Path(path), data, "wb", fsync)
 
 
 def atomic_write_text(path: Path | str, text: str,
@@ -35,14 +57,20 @@ def atomic_write_text(path: Path | str, text: str,
     crash-consistent — the ledger-adjacent artifacts default to
     consistency only, matching the ledger's own ``ledger_fsync`` knob.
     """
-    path = Path(path)
-    tmp = path.with_name(f"{path.name}.{os.getpid()}.tmp")
+    _atomic_write(Path(path), text, "w", fsync)
+
+
+def _atomic_write(path: Path, payload, mode: str, fsync: bool) -> None:
+    tmp = temp_path(path)
     try:
-        with open(tmp, "w") as f:
-            f.write(text)
+        with open(tmp, mode) as f:
+            f.write(payload)
             if fsync:
                 f.flush()
                 os.fsync(f.fileno())
+        # a writer dying here leaves the old complete target (or none) and
+        # its own temp file: never a partial target
+        faults.maybe_fire("atomic_rename", event=path.name)
         os.replace(tmp, path)
     finally:
         # a failure between open and replace must not litter temp files

@@ -31,6 +31,11 @@ Hook sites (``site`` field of a spec):
 ``device_probe``
     fired inside the device health probe — ``kind="hang"`` sleeps
     past the probe deadline (an unreachable device can hang, not error).
+``atomic_rename``
+    fired inside :mod:`tmlibrary_tpu.atomicio` between the temp file's
+    last byte and the rename over the target (context: ``event`` = the
+    target's file name) — a writer dying with the payload written and
+    unpublished: the target stays the old complete file, or absent.
 ``enqueue``
     fired inside :func:`tmlibrary_tpu.serve.enqueue_job` before the
     spec hits the spool (context: ``step`` = tenant, ``event`` = job

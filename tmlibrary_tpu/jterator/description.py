@@ -13,6 +13,9 @@ translate mechanically::
       channels:
         - {name: DAPI, correct: true, align: false}
         - {name: Actin, correct: true, align: false}
+        # a multiplexed plate: a stain of a later acquisition cycle,
+        # read from that cycle under that cycle's shifts
+        - {name: Mito, correct: true, align: true, cycle: 1}
     pipeline:
       - {handles: handles/smooth.handles.yaml, active: true}
       - {handles: handles/segment.handles.yaml, active: true}
@@ -41,6 +44,11 @@ class ChannelInput:
     #: (feeds generate_volume_image / segment_volume; correction and
     #: alignment are per-plane concerns and are skipped for volumes)
     zstack: bool = False
+    #: the acquisition cycle the channel's planes, illumination statistics
+    #: and shifts are read from; None = the jterator step's ``cycle``
+    #: argument.  It says where the pixels come from and nothing of what
+    #: is computed: descriptions that differ in it share their programs
+    cycle: int | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,6 +83,7 @@ class PipelineDescription:
                 correct=bool(c.get("correct", True)),
                 align=bool(c.get("align", False)),
                 zstack=bool(c.get("zstack", False)),
+                cycle=cls._cycle(c),
             )
             for c in inp.get("channels", []) or []
         ]
@@ -133,6 +142,18 @@ class PipelineDescription:
             modules=modules,
             objects_out=objects_out,
         )
+
+    @staticmethod
+    def _cycle(channel: dict) -> int | None:
+        cycle = channel.get("cycle")
+        if cycle is None:
+            return None
+        if isinstance(cycle, bool) or not isinstance(cycle, int) or cycle < 0:
+            raise PipelineDescriptionError(
+                f"channel '{channel.get('name')}': cycle must be a "
+                f"non-negative integer, got {cycle!r}"
+            )
+        return cycle
 
     @classmethod
     def load(cls, pipe_path: Path) -> "PipelineDescription":

@@ -173,9 +173,19 @@ def program_digest_extras(
 def _description_cache_key(description: PipelineDescription) -> str:
     import json
 
-    return json.dumps(
-        dataclasses.asdict(description), sort_keys=True, default=repr
-    )
+    content = dataclasses.asdict(description)
+    # a channel's cycle says where its planes are read from, not what the
+    # program computes: it splits no program, store entry or routing key
+    for channel in content["channels"]:
+        del channel["cycle"]
+    return json.dumps(content, sort_keys=True, default=repr)
+
+
+def aligned_channels(description: PipelineDescription) -> list[str]:
+    """The channels the batch program shifts, in the order of their rows
+    in its ``shifts`` argument (volumes are cropped, never shifted)."""
+    return [ch.name for ch in description.channels
+            if ch.align and not ch.zstack]
 
 
 def description_digest(description: PipelineDescription) -> str:
@@ -447,16 +457,22 @@ class ImageAnalysisPipeline:
         alignment (reference: ``ChannelImage.correct``/``align`` calls at the
         top of ``run_job``'s site loop).
 
-        Returns ``fn(raw: dict, stats: dict, shift: (2,) array) -> dict``
-        where ``raw`` maps channel name → (H, W) uint16 and ``stats`` maps
-        channel name → (mean_log, std_log) pairs (absent = no correction).
+        Returns ``fn(raw: dict, stats: dict, shifts: (C, 2) array) -> dict``
+        where ``raw`` maps channel name → (H, W) uint16, ``stats`` maps
+        channel name → (mean_log, std_log) pairs (absent = no correction)
+        and ``shifts`` holds one (dy, dx) row for each of
+        :func:`aligned_channels`, in that order: the channels of a
+        multiplexed plate come from several cycles, each under its own
+        cycle's shift (the reference cycle's: zeros, cropped only).  A
+        description with no aligned channel never reads the argument.
         """
         desc = self.description
+        row = {name: k for k, name in enumerate(aligned_channels(desc))}
 
         def preprocess(
             raw: dict[str, jax.Array],
             stats: dict[str, tuple[jax.Array, jax.Array]],
-            shift: jax.Array,
+            shifts: jax.Array,
         ) -> dict[str, jax.Array]:
             out: dict[str, jax.Array] = {}
             for ch in desc.channels:
@@ -475,7 +491,9 @@ class ImageAnalysisPipeline:
                     mean_log, std_log = stats[ch.name]
                     img = image_ops.correct_illumination(img, mean_log, std_log)
                 if ch.align:
-                    img = image_ops.align(img, shift[0], shift[1], window)
+                    k = row[ch.name]
+                    img = image_ops.align(img, shifts[k, 0], shifts[k, 1],
+                                          window)
                 elif window is not None:
                     # the intersection window applies to EVERY channel once
                     # cycles are aligned (reference SiteIntersection crops
@@ -497,8 +515,10 @@ class ImageAnalysisPipeline:
         """jit(vmap(preprocess ∘ site_fn)) over the site-batch axis.
 
         Signature: ``fn(raw: {ch: (B,H,W)}, stats: {ch: (mean,std)},
-        shifts: (B,2)) -> SiteResult`` with a leading batch axis on every
-        leaf.  ``stats`` fields broadcast (shared per channel).
+        shifts: (B,C,2)) -> SiteResult`` with a leading batch axis on every
+        leaf; ``C`` counts :func:`aligned_channels`, a row each (with none
+        the argument is not read, and any array with the batch axis
+        does).  ``stats`` fields broadcast (shared per channel).
         ``jit=False`` returns the traceable vmapped function (for callers
         composing their own jit, e.g. with explicit shardings).
 
@@ -524,9 +544,9 @@ class ImageAnalysisPipeline:
         preprocess = self.build_preprocess_fn(window)
         desc = self.description
 
-        def one_site(raw, stats, shift):
+        def one_site(raw, stats, shifts):
             with jax.named_scope("preprocess"):
-                images = preprocess(raw, stats, shift)
+                images = preprocess(raw, stats, shifts)
             # pass loaded objects (if any) through; label images loaded
             # from the store live in the uncropped site frame, so they
             # get the same intersection crop as the pixel channels

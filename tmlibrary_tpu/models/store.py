@@ -29,6 +29,7 @@ enumeration (:meth:`tmlibrary_tpu.models.experiment.Experiment.sites`).
 
 from __future__ import annotations
 
+import io
 import json
 import threading
 from pathlib import Path
@@ -36,6 +37,7 @@ from typing import Mapping, Sequence
 
 import numpy as np
 
+from tmlibrary_tpu.atomicio import atomic_write_bytes, atomic_write_json
 from tmlibrary_tpu.errors import StoreError
 from tmlibrary_tpu.models.experiment import Experiment, SiteRef
 
@@ -332,8 +334,14 @@ class ExperimentStore:
 
     # ------------------------------------------------------------- alignment
     def write_shifts(self, shifts: np.ndarray, cycle: int) -> None:
-        """``shifts``: (n_sites, 2) int32 (dy, dx) of this cycle vs cycle 0."""
-        np.save(self.root / "alignment" / f"shifts_cycle{cycle:02d}.npy", shifts)
+        """``shifts``: (n_sites, 2) int32 (dy, dx) of this cycle vs cycle 0.
+        The whole table, tmp + rename: a reader sees the old table or the
+        new one."""
+        buf = io.BytesIO()
+        np.save(buf, np.asarray(shifts))
+        atomic_write_bytes(
+            self.root / "alignment" / f"shifts_cycle{cycle:02d}.npy",
+            buf.getvalue())
 
     def read_shifts(self, cycle: int) -> np.ndarray:
         path = self.root / "alignment" / f"shifts_cycle{cycle:02d}.npy"
@@ -345,13 +353,17 @@ class ExperimentStore:
         return (self.root / "alignment" / f"shifts_cycle{cycle:02d}.npy").exists()
 
     def write_intersection(self, window: Mapping[str, int]) -> None:
-        (self.root / "alignment" / "intersection.json").write_text(json.dumps(dict(window)))
+        atomic_write_json(self.root / "alignment" / "intersection.json",
+                          dict(window))
 
     def read_intersection(self) -> dict[str, int]:
         path = self.root / "alignment" / "intersection.json"
         if not path.exists():
             raise StoreError("intersection window missing")
-        return json.loads(path.read_text())
+        # the four margins every consumer crops to; what else the align
+        # step wrote beside them (the exact intersection) is a record
+        stored = json.loads(path.read_text())
+        return {k: int(stored[k]) for k in ("top", "bottom", "left", "right")}
 
     # --------------------------------------------------------------- weights
     @property

@@ -129,11 +129,20 @@ def _batch_pc_jit():
 
 @functools.lru_cache(maxsize=1)
 def _batch_pcq_jit():
+    # ONE jitted program a process (a step instance comes and goes with
+    # every submit; the program and its executables stay).  The stacks
+    # arrive in the store's dtype (uint16: half the float32 bytes over
+    # the host link) and are converted on the device.
     def one(a, b):
         dy, dx, q = phase_correlation_quality(a, b)
         return jnp.stack([dy, dx]), q
 
-    return jax.jit(jax.vmap(one))
+    def phase_correlation_batch(reference_stack, target_stack):
+        # the name a device trace reads the registration's time by
+        with jax.named_scope("phase_correlation"):
+            return jax.vmap(one)(reference_stack, target_stack)
+
+    return jax.jit(phase_correlation_batch)
 
 
 def batch_phase_correlation(
@@ -146,8 +155,28 @@ def batch_phase_correlation(
 def batch_phase_correlation_quality(
     reference_stack: jax.Array, target_stack: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
-    """vmap over the site axis → ((B, 2) int32 shifts, (B,) quality)."""
+    """vmap over the site axis → ((B, 2) int32 shifts, (B,) quality).
+    The stacks may be uint16 (converted on the device)."""
     return _batch_pcq_jit()(reference_stack, target_stack)
+
+
+def pairs_in_flight(plane_bytes_f32: int, device_free: int | None,
+                    host_free: int | None) -> int:
+    """How many (reference, target) pairs one registration launch may
+    hold.  A pair in flight holds, of one float32 plane's bytes: on the
+    device its two uint16 planes (1), their float32 copies (2), the two
+    half spectra (2), the cross-power spectrum and its normalised twin
+    (2), the correlation surface (1) and the transforms' workspace, taken
+    as much again (8): 16; on the host the two uint16 stacks (1).  Half of
+    what is free is planned with, as ``illuminati.channels_in_flight``
+    does.  At least one; a platform that says nothing of its memory (the
+    CPU backend has no ``memory_stats``) bounds nothing."""
+    bounds = []
+    if device_free is not None:
+        bounds.append(device_free // 2 // max(1, 16 * plane_bytes_f32))
+    if host_free is not None:
+        bounds.append(host_free // 2 // max(1, plane_bytes_f32))
+    return max(1, min(bounds)) if bounds else 1 << 30
 
 
 def intersection_window(all_shifts: jax.Array) -> dict[str, int]:
@@ -174,3 +203,27 @@ def intersection_window(all_shifts: jax.Array) -> dict[str, int]:
         "left": int(np.clip(s[:, 1].max(), 0, None)),
         "right": int(np.clip(-s[:, 1].min(), 0, None)),
     }
+
+
+#: a stored window's margin is a multiple of this many pixels
+WINDOW_QUANTUM = 16
+
+
+def stored_window(window: dict[str, int]) -> dict[str, int]:
+    """The window an experiment stores and every consumer crops to: the
+    intersection's largest margin, widened to the next multiple of
+    :data:`WINDOW_QUANTUM`, on all four sides (no drift: no crop).
+
+    The window is static in the batch programs, so each distinct window
+    is a set of compiled rungs of its own (minutes of compile, hundreds
+    of megabytes of executable store at a 2160 x 2160 field).  The exact
+    intersection is four numbers that differ from plate to plate; the
+    stage's repositioning error that produces them does not.  One margin
+    on a grid of 16 leaves ``max_shift // 16 + 2`` windows an instrument
+    can ever produce (five at ``max_shift`` 50), and a plate of the same
+    instrument lands on the same one or its neighbour.  Every pixel
+    inside it is inside the intersection: what is given up is at most
+    the margins' difference plus 15 pixels a side (DESIGN.md §30)."""
+    widest = max(window.values(), default=0)
+    margin = -(-widest // WINDOW_QUANTUM) * WINDOW_QUANTUM
+    return dict.fromkeys(("top", "bottom", "left", "right"), int(margin))

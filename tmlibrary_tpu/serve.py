@@ -97,7 +97,8 @@ from contextlib import nullcontext
 from pathlib import Path
 
 from tmlibrary_tpu import aotstore, canary, faults, slo, telemetry, timeseries
-from tmlibrary_tpu.atomicio import atomic_write_json, claim_rename
+from tmlibrary_tpu.atomicio import (TMP_SUFFIX, atomic_write_json,
+                                    claim_rename)
 from tmlibrary_tpu.errors import FaultInjected, PreemptedError
 from tmlibrary_tpu.resilience import (
     EXIT_PREEMPTED,
@@ -208,7 +209,9 @@ def job_claims(serve_root: Path,
     pattern = f"{job_id}.claim.*" if job_id else "*.claim.*"
     for p in sorted(spool_dir(serve_root, "admitted").glob(pattern)):
         jid, _, host = p.name.rpartition(".claim.")
-        if jid and host:
+        # a claim being rewritten has its writer's temp file beside it:
+        # no claim, and no host's
+        if jid and host and not p.name.endswith(TMP_SUFFIX):
             out.append((p, jid, host))
     return out
 

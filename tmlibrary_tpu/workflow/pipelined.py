@@ -301,14 +301,19 @@ class PipelinedExecutor:
 
         @contextlib.contextmanager
         def _phase(phase: str, idx):
-            # a phase on the thread that works: span scope + stats record
-            t0 = time.perf_counter()
+            # a phase on the thread that works: span scope + stats record.
+            # Both clocks bracket the work alone: with the stats' clock
+            # around the span's own bookkeeping (its buffer's lock, the
+            # first import of the profiler) a loaded host put milliseconds
+            # between the two sums of one phase
             with telemetry.span_scope(step=step_name, batch=idx), \
                     telemetry.span(
                         phase, resource=profiling.PHASE_RESOURCE[phase]):
+                t0 = time.perf_counter()
                 yield
+                elapsed = time.perf_counter() - t0
             if stats is not None:
-                stats.record(phase, time.perf_counter() - t0)
+                stats.record(phase, elapsed)
 
         has_prefetch = hasattr(step, "prefetch_batch")
         prefetcher = None

@@ -5,11 +5,14 @@ level 0 stitches corrected/aligned/rescaled site images into the plate
 mosaic and cuts 256-px tiles; level L+1 jobs consume level L (inter-level
 dependency waves); tiles land in the DB (SURVEY.md §4.5).
 
-TPU execution: one batch per (plate, channel); correction + rescale run
-batched on device, the mosaic assembles host-side (it can exceed HBM for
-large plates), the downsample chain runs on device per level, PNG tiles go
-to ``pyramids/<channel>/<level>/<row>_<col>.png`` — a zoomify-style layout
-any slippy-map viewer can serve statically.
+TPU execution: one batch per (plate, cycle, channel) that holds planes — a
+multiplexed plate's stains live in one acquisition cycle each, and every
+one gets its layer, shifted by its cycle's stored shifts so that the layers
+register; correction + rescale run batched on device, the mosaic assembles
+host-side (it can exceed HBM for large plates), the downsample chain runs on
+device per level, PNG tiles go to ``pyramids/<layer>/<level>/<row>_<col>.png``
+(:func:`layer_name`) — a zoomify-style layout any slippy-map viewer can
+serve statically.  "Channel" below is such a channel-cycle.
 
 The channels are in flight together: the step exposes the pipelined
 executor's split (``workflow/pipelined.py``), so one channel's levels are
@@ -92,6 +95,14 @@ def channels_in_flight(mosaic_bytes: int, device_free: int | None,
     return max(1, min(bounds)) if bounds else 1
 
 
+def layer_name(cycle: int, channel: int) -> str:
+    """A channel-cycle's directory under ``pyramids/``: ``channelNN`` in
+    the first cycle, as a one-cycle experiment always had it, and
+    ``cycleCC_channelNN`` in a later one."""
+    name = f"channel{channel:02d}"
+    return name if cycle == 0 else f"cycle{cycle:02d}_{name}"
+
+
 class _Flight(NamedTuple):
     """The step's in-flight plan (:meth:`PyramidBuilder._flight`)."""
 
@@ -105,11 +116,15 @@ class _Flight(NamedTuple):
 class PyramidBuilder(Step):
     batch_args = ArgumentCollection(
         Argument("correct", bool, default=True, help="apply illumination stats"),
-        Argument("align", bool, default=False, help="apply cycle-0 alignment"),
+        Argument("align", bool, default=True,
+                 help="shift each cycle's sites by the shifts the align "
+                      "step stored for it (a cycle with none is tiled as "
+                      "it is)"),
         Argument("clip_percent", float, default=99.9,
                  help="upper clip percentile for display rescale"),
         Argument("batch_size", int, default=32, help="sites per device batch"),
-        Argument("cycle", int, default=0, help="cycle to tile"),
+        Argument("cycle", int, default=-1,
+                 help="cycle to tile (-1: every cycle that holds planes)"),
         Argument("n_devices", int, default=1,
                  help="row-shard the mosaic pyramid over this many devices "
                       "(mosaics larger than one chip's HBM)"),
@@ -125,11 +140,14 @@ class PyramidBuilder(Step):
 
     def create_batches(self, args):
         exp = self.store.experiment
+        cycles = (range(exp.n_cycles) if args["cycle"] < 0
+                  else [args["cycle"]])
         return [
-            {"plate": p.name, "channel": ch.index}
+            {"plate": p.name, "cycle": cycle, "channel": ch.index}
             for p in exp.plates
+            for cycle in cycles
             for ch in exp.channels
-            if self.store.has_plane(cycle=args["cycle"], channel=ch.index)
+            if self.store.has_plane(cycle=cycle, channel=ch.index)
         ]
 
     # ------------------------------------------------------ channels in flight
@@ -185,8 +203,7 @@ class PyramidBuilder(Step):
         t0 = time.perf_counter()
         args = batch["args"]
         exp = self.store.experiment
-        channel = batch["channel"]
-        cycle = args["cycle"]
+        channel, cycle = batch["channel"], batch["cycle"]
         plate = next(p for p in exp.plates if p.name == batch["plate"])
 
         stats = None
@@ -204,16 +221,19 @@ class PyramidBuilder(Step):
             (part, [self.store.site_linear_index(r) for r, _, _ in part])
             for part in create_partitions(refs, args["batch_size"])
         ]
+        # a cycle with no stored shifts (the reference cycle, every cycle
+        # of an experiment that was never aligned) runs the program that
+        # shifts nothing
+        aligned = args["align"] and self.store.has_shifts(cycle)
         shifts_table = (
-            self.store.read_shifts(cycle)
-            if args["align"] and self.store.has_shifts(cycle)
+            self.store.read_shifts(cycle) if aligned
             else np.zeros((self.store.n_sites, 2), np.int32)
         )
         stacks = None
         if self._flight().reads_ahead:
             stacks = [self._read(idx, cycle, channel) for _, idx in parts]
         return {"plate": plate, "stats": stats, "parts": parts,
-                "shifts": shifts_table, "stacks": stacks,
+                "shifts": shifts_table, "aligned": aligned, "stacks": stacks,
                 "seconds": time.perf_counter() - t0}
 
     def _read(self, idx: list[int], cycle: int, channel: int) -> np.ndarray:
@@ -237,7 +257,7 @@ class PyramidBuilder(Step):
     def persist_batch(self, batch: dict, ctx: dict) -> dict:
         """Fetch and encode every level of one launched channel.  Entered
         by several persist workers at once, each with another channel
-        (``pyramids/channelNN/`` is the channel's own)."""
+        (``pyramids/<layer>/`` is the channel-cycle's own)."""
         try:
             return self._persist(batch, ctx)
         finally:
@@ -252,8 +272,7 @@ class PyramidBuilder(Step):
         t0 = time.perf_counter()
         args = batch["args"]
         exp = self.store.experiment
-        channel = batch["channel"]
-        cycle = args["cycle"]
+        channel, cycle = batch["channel"], batch["cycle"]
         plate, stats = pre["plate"], pre["stats"]
 
         # display range from corilla's exact raw-intensity percentiles
@@ -264,7 +283,7 @@ class PyramidBuilder(Step):
             upper = stats.closest_percentile(args["clip_percent"])
         from_corilla = lower is not None and upper is not None
 
-        prep = image_ops.make_batch_prep(stats, apply_shift=args["align"])
+        prep = image_ops.make_batch_prep(stats, apply_shift=pre["aligned"])
 
         # site grid geometry (shared helper — same layout as the static
         # outlines and the pyramid-depth computation)
@@ -313,7 +332,7 @@ class PyramidBuilder(Step):
             "lower": float(lower),
             "upper": float(upper),
             "from_corilla": from_corilla,
-            "out_dir": self.store.root / "pyramids" / f"channel{channel:02d}",
+            "out_dir": self.store.root / "pyramids" / layer_name(cycle, channel),
             # the channel's own seconds so far, waits between phases apart
             "seconds": pre["seconds"] + time.perf_counter() - t0,
         }
@@ -353,7 +372,7 @@ class PyramidBuilder(Step):
                     )
                 n_tiles += len(futures)
         layer = ChannelLayer(
-            channel=f"channel{channel:02d}",
+            channel=out_dir.name,
             height=ctx["mosaic_shape"][0],
             width=ctx["mosaic_shape"][1],
             max_zoom=n_levels - 1,
@@ -364,6 +383,7 @@ class PyramidBuilder(Step):
         ).add(n_tiles, ctx["seconds"] + time.perf_counter() - t0)
         return {
             "channel": channel,
+            "cycle": batch["cycle"],
             "mosaic_shape": ctx["mosaic_shape"],
             "n_levels": n_levels,
             "n_tiles": n_tiles,

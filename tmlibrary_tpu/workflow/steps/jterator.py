@@ -433,8 +433,47 @@ class ImageAnalysisRunner(Step):
                 pipe_path = Path(args["pipe"])
                 if not pipe_path.is_absolute():
                     pipe_path = self.store.root / pipe_path
-                self._desc = PipelineDescription.load(pipe_path)
+                desc = PipelineDescription.load(pipe_path)
+                self._check_channel_planes(desc, args)
+                self._desc = desc
             return self._desc
+
+    def _channel_cycle(self, args, name: str) -> int:
+        """The acquisition cycle a channel's planes, illumination
+        statistics and shifts are read from: its description entry's
+        ``cycle``, else the step's ``cycle`` argument (every channel of a
+        one-cycle experiment; the spatial layout without a ``pipe``)."""
+        if args["pipe"]:
+            for ch in self._description(args).channels:
+                if ch.name == name and ch.cycle is not None:
+                    return ch.cycle
+        return args["cycle"]
+
+    def _check_channel_planes(self, desc, args) -> None:
+        """Every channel's plane exists in the cycle it is asked from —
+        found out when the description is first read, before anything is
+        read for a launch: a multiplexed plate's stains live in one cycle
+        each, and a typo in ``cycle`` would otherwise surface as a missing
+        ``.npy`` from a prefetch worker, batches into the step."""
+        exp = self.store.experiment
+        for ch in desc.channels:
+            cycle = args["cycle"] if ch.cycle is None else ch.cycle
+            zplanes = range(exp.n_zplanes) if ch.zstack else [args["zplane"]]
+            if not all(
+                self.store.has_plane(
+                    cycle=cycle, channel=exp.channel_index(ch.name),
+                    tpoint=args["tpoint"], zplane=zp)
+                for zp in zplanes
+            ):
+                held = sorted(
+                    c for c in range(exp.n_cycles)
+                    if self.store.has_plane(
+                        cycle=c, channel=exp.channel_index(ch.name),
+                        tpoint=args["tpoint"], zplane=args["zplane"]))
+                raise PipelineError(
+                    f"channel '{ch.name}' is asked from cycle {cycle}, "
+                    f"which holds no plane of it (cycles that do: {held})"
+                )
 
     def _pipeline(self, args, capacity: int | None = None):
         """The compiled batch program for ``capacity`` (default: the
@@ -823,21 +862,22 @@ class ImageAnalysisRunner(Step):
         apply at mosaic scale (it would shrink tiles out of the grid), so
         shifted-in edges are zero-filled exactly like the sites path's
         ``shift_image``."""
+        cycle = self._channel_cycle(args, next(
+            c.name for c in self.store.experiment.channels
+            if c.index == ch_index))
         with telemetry.span("stitch", bytes=4 * n_sy * h * n_sx * w):
             imgs = self.store.read_sites(
-                sites, cycle=args["cycle"], channel=ch_index,
+                sites, cycle=cycle, channel=ch_index,
                 tpoint=args["tpoint"], zplane=args["zplane"],
             )
-            if self.store.has_illumstats(cycle=args["cycle"], channel=ch_index):
+            if self.store.has_illumstats(cycle=cycle, channel=ch_index):
                 cont = IllumstatsContainer.from_store(
-                    self.store.read_illumstats(cycle=args["cycle"], channel=ch_index)
+                    self.store.read_illumstats(cycle=cycle, channel=ch_index)
                 )
                 imgs = _correct_batch(imgs, cont.mean_log, cont.std_log)
             shifts = None
-            if args.get("spatial_align", True) and self.store.has_shifts(
-                args["cycle"]
-            ):
-                shifts = self.store.read_shifts(args["cycle"])
+            if args.get("spatial_align", True) and self.store.has_shifts(cycle):
+                shifts = self.store.read_shifts(cycle)
             mosaic = np.zeros((n_sy * h, n_sx * w), np.float32)
             for img, r, site_idx in zip(imgs, srefs, sites):
                 if shifts is not None:
@@ -849,16 +889,16 @@ class ImageAnalysisRunner(Step):
             return mosaic
 
     def _stitch_validity(
-        self, sites, srefs, args, n_sy, n_sx, h, w
+        self, sites, srefs, args, n_sy, n_sx, h, w, cycle
     ) -> "np.ndarray | None":
         """Boolean mosaic of pixels that carry real data after the
         per-site alignment shift (zero-filled shifted-in edges are
         False).  None when no shift moved anything — every pixel is
         valid and callers can skip the masked-threshold path."""
         if not (args.get("spatial_align", True)
-                and self.store.has_shifts(args["cycle"])):
+                and self.store.has_shifts(cycle)):
             return None
-        shifts = self.store.read_shifts(args["cycle"])
+        shifts = self.store.read_shifts(cycle)
         if not any(
             int(shifts[s][0]) or int(shifts[s][1]) for s in sites
         ):
@@ -891,7 +931,8 @@ class ImageAnalysisRunner(Step):
         n_sy = max(r.site_y for r in srefs) + 1
         n_sx = max(r.site_x for r in srefs) + 1
         mosaic = self._stitched_channel(sites, srefs, idx, args, n_sy, n_sx, h, w)
-        valid = self._stitch_validity(sites, srefs, args, n_sy, n_sx, h, w)
+        valid = self._stitch_validity(sites, srefs, args, n_sy, n_sx, h, w,
+                                      self._channel_cycle(args, ch_name))
         return {
             "idx": idx, "channel": ch_name, "srefs": srefs, "h": h, "w": w,
             "n_sy": n_sy, "n_sx": n_sx, "mosaic": mosaic, "valid": valid,
@@ -1307,7 +1348,9 @@ class ImageAnalysisRunner(Step):
         sites = batch["sites"]
         desc = self._description(args)
         exp = self.store.experiment
-        cycle, tpoint, zplane = args["cycle"], args["tpoint"], args["zplane"]
+        tpoint, zplane = args["tpoint"], args["zplane"]
+        cycle_of = {ch.name: self._channel_cycle(args, ch.name)
+                    for ch in desc.channels}
 
         n_dev = args["n_devices"] or len(jax.devices())
         n_dev = min(n_dev, len(jax.devices()))
@@ -1321,6 +1364,7 @@ class ImageAnalysisRunner(Step):
         raw = {}
         for ch in desc.channels:
             idx = exp.channel_index(ch.name)
+            cycle = cycle_of[ch.name]
             if ch.zstack:
                 planes = [
                     self.store.read_sites(padded_sites, cycle=cycle, channel=idx,
@@ -1342,6 +1386,7 @@ class ImageAnalysisRunner(Step):
             # demand stats they will never use
             if ch.correct and not ch.zstack:
                 idx = exp.channel_index(ch.name)
+                cycle = cycle_of[ch.name]
                 if not self.store.has_illumstats(cycle=cycle, channel=idx):
                     raise PipelineError(
                         f"channel '{ch.name}' wants illumination correction but "
@@ -1352,13 +1397,28 @@ class ImageAnalysisRunner(Step):
                 )
                 stats[ch.name] = (cont.mean_log, cont.std_log)
 
+        # a row of (dy, dx) for each aligned channel, from its own cycle's
+        # table; a cycle with no table (the reference cycle, or no align
+        # step) shifts nothing
+        from tmlibrary_tpu.jterator.pipeline import aligned_channels
+
         shifts_np = None
-        if any(ch.align for ch in desc.channels) and self.store.has_shifts(cycle):
-            table = self.store.read_shifts(cycle)
-            shifts_np = table[np.asarray(padded_sites)]
+        aligned = aligned_channels(desc)
+        if aligned:
+            tables = {
+                cycle: self.store.read_shifts(cycle)[np.asarray(padded_sites)]
+                for cycle in {cycle_of[name] for name in aligned}
+                if self.store.has_shifts(cycle)
+            }
+            zeros = np.zeros((len(padded_sites), 2), np.int32)
+            shifts_np = np.stack(
+                [tables.get(cycle_of[name], zeros) for name in aligned],
+                axis=1).astype(np.int32)
 
         return {"padded_sites": padded_sites, "n_dev": n_dev,
-                "raw": raw, "stats": stats, "shifts_np": shifts_np}
+                "raw": raw, "stats": stats, "shifts_np": shifts_np,
+                "cycles_read": sorted(set(cycle_of.values())),
+                "aligned_channels": len(aligned)}
 
     def _launch(
         self, batch: dict, inputs: dict | None = None,
@@ -1391,6 +1451,8 @@ class ImageAnalysisRunner(Step):
         nbytes = sum(int(np.asarray(a).nbytes) for a in sent)
         if tally is not None:
             tally["h2d_bytes"] = tally.get("h2d_bytes", 0) + nbytes
+            tally["cycles_read"] = inputs["cycles_read"]
+            tally["aligned_channels"] = inputs["aligned_channels"]
         with telemetry.span("upload", bytes=nbytes):
             raw = {}
             for name, stack in inputs["raw"].items():
@@ -1398,8 +1460,11 @@ class ImageAnalysisRunner(Step):
                 raw[name] = jax.device_put(arr, sharding) if sharding else arr
 
             if inputs["shifts_np"] is not None:
-                shifts = jnp.asarray(inputs["shifts_np"])
+                shifts = jnp.asarray(inputs["shifts_np"])   # (B, C, 2)
             else:
+                # no channel is aligned: the program never reads the
+                # argument, and the placeholder keeps the shape it always
+                # had (the signature is part of a store entry's key)
                 shifts = jnp.zeros((len(padded_sites), 2), jnp.int32)
             if sharding is not None:
                 shifts = jax.device_put(shifts, sharding)
@@ -1685,14 +1750,15 @@ class ImageAnalysisRunner(Step):
             first_ch = next((c for c in desc.channels if not c.zstack), None)
             if first_ch is not None:
                 idx = self.store.experiment.channel_index(first_ch.name)
+                cycle = self._channel_cycle(args, first_ch.name)
                 base = self.store.read_sites(
-                    sites, cycle=args["cycle"], channel=idx,
+                    sites, cycle=cycle, channel=idx,
                     tpoint=tpoint, zplane=zplane,
                 )
-                if first_ch.align and self.store.has_shifts(args["cycle"]):
+                if first_ch.align and self.store.has_shifts(cycle):
                     # labels live in the aligned frame; shift the raw base
                     # the same way or boundaries draw offset from the cells
-                    table = self.store.read_shifts(args["cycle"])
+                    table = self.store.read_shifts(cycle)
                     base = np.stack([
                         _host_shift(base[b], *table[s])
                         for b, s in enumerate(sites)
@@ -1755,6 +1821,10 @@ class ImageAnalysisRunner(Step):
             summary["bucket_rungs_skipped"] = rungs_skipped
         # bytes handed to the device: the first launch and every re-launch
         summary["h2d_bytes"] = int(tally.get("h2d_bytes", 0))
+        # where the planes came from: the cycles read, and how many
+        # channels went through the program under a shift row of their own
+        summary["cycles_read"] = tally.get("cycles_read", [args["cycle"]])
+        summary["aligned_channels"] = int(tally.get("aligned_channels", 0))
         self._note_bucket(cap, ceiling, total_objects, slots, escalations,
                           rungs_skipped)
         # object-capacity saturation must be LOUD: clip_label_count silently
