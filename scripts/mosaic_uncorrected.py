@@ -6,8 +6,9 @@ of the description (no statistics, so the spatial chain corrects
 nothing), on the devices that are there.  A z-score of nine samples tops
 out at 2.67, so corrected nuclei are flat-topped and the Actin mask is
 speckle; a plate's statistics over 3,456 fields would not do that.  The
-objects found, the watershed's adopt steps and the host's seconds of
-hulls, intensity passes and stitches of both units are printed, so that
+objects found, the watershed's adopt steps, each step's seconds and the
+host's seconds of hulls, intensity passes and stitches (sums over spans
+that may be open at once) of both units are printed, so that
 a later claim in the cell is not tuned to the artifact (PERF.md section
 7).  On fewer devices than the configuration's the step shrinks its
 mesh: counts and adopt steps are the mesh's to leave alone, host seconds
@@ -66,6 +67,7 @@ def main(argv=None) -> int:
                         and e.get("span") in seconds):
                     seconds[e["span"]] += float(e["elapsed"])
             out[name] = {"unit_s": unit.seconds, "steps": steps,
+                         "step_s": ledger.step_seconds(events),
                          "jterator": result, "span_s": seconds}
     finally:
         shutil.rmtree(work, ignore_errors=True)

@@ -7,8 +7,12 @@ the cell forced in the program: the squarest grid, the root table's bound
 from ``max_objects``, programs built once a process, planes that go to
 their shards directly, the counters in ``batch_done.result``."""
 
+import io
 import json
+import os
 import sys
+import threading
+import time
 from pathlib import Path
 
 import numpy as np
@@ -192,7 +196,12 @@ def test_batch_done_says_the_mesh_the_bytes_and_what_was_counted(units):
         # Otsu's cut a stain, as used (before the secondary factor)
         assert set(result["otsu_cut"]) == {"DAPI", "Actin"}
         assert all(300 < cut < 65535 for cut in result["otsu_cut"].values())
-    assert jterator_result(units[0][1]) == jterator_result(units[1][1])
+    # everything but how many host tasks happened to run at one instant
+    first, second = (dict(jterator_result(events)) for _, events in units)
+    for result in (first, second):
+        assert result.pop("host_tasks") == 5 + 2 * (3 + 5)
+        assert 1 <= result.pop("host_peak_concurrency") <= 8
+    assert first == second
 
 
 def test_the_spans_the_readers_read_are_in_the_ledger(units):
@@ -204,6 +213,8 @@ def test_the_spans_the_readers_read_are_in_the_ledger(units):
         by_name.setdefault(e["span"], []).append(e)
     side = FIELDS_X * SIZE
     assert len(by_name["stitch"]) == 5           # one a stain
+    # all five stitched ahead, on the host pool, from the launch
+    assert {e["parent"] for e in by_name["stitch"]} == {"dispatch"}
     assert all(e["bytes"] == 4 * side * side for e in by_name["stitch"])
     assert [e["bytes"] for e in by_name["upload"]] == [2 * 4 * side * side]
     assert len(by_name["segment"]) == len(by_name["device_wait"]) == 1
@@ -217,6 +228,16 @@ def test_the_spans_the_readers_read_are_in_the_ledger(units):
     assert len(by_name["intensity"]) == 2 * 5    # both types, every stain
     for name in ("morph", "solidity", "write_labels", "write_features"):
         assert len(by_name[name]) == 2, name
+    # pool tasks keep the step, batch and enclosing span of the thread
+    # that submitted them: the persist readers select on both
+    for name in ("morph", "solidity", "write_labels", "write_features",
+                 "intensity"):
+        assert {e["parent"] for e in by_name[name]} == {"persist"}, name
+        assert {e["batch"] for e in by_name[name]} == {0}, name
+    pooled = {e["thread"] for name in ("morph", "solidity", "intensity",
+                                       "write_labels", "stitch")
+              for e in by_name[name]}
+    assert all(t.startswith("tmx-mosaic-host") for t in pooled), pooled
     objects = jterator_result(events)["objects"]["nuclei"]
     for e in by_name["write_features"]:
         # area, centroid (2), box (2), solidity, five statistics a stain
@@ -246,6 +267,217 @@ def test_the_counters_are_in_the_registry(units):
                  "tmx_jterator_mosaic_adopt_steps_total",
                  "tmx_jterator_mosaic_roots_max_per_shard"):
         assert name in text
+
+
+# ------------------------------------ the host pool of the spatial step
+def serial_stitch(store, channel: str) -> np.ndarray:
+    """One stain's mosaic as the serial step stitched it: the nine fields
+    corrected by the step's own program, laid side by side."""
+    from tmlibrary_tpu.models.image import IllumstatsContainer
+    from tmlibrary_tpu.workflow.steps.jterator import _correct_batch
+
+    index = store.experiment.channel_index(channel)
+    stats = IllumstatsContainer.from_store(
+        store.read_illumstats(channel=index))
+    fields = _correct_batch(store.read_sites(list(range(9)), channel=index),
+                            stats.mean_log, stats.std_log)
+    return reference_stitch(fields)
+
+
+def reference_stitch(stack: np.ndarray) -> np.ndarray:
+    rows = [np.concatenate(list(stack[r * FIELDS_X:(r + 1) * FIELDS_X]),
+                           axis=1) for r in range(len(stack) // FIELDS_X)]
+    return np.concatenate(rows, axis=0)
+
+
+def serial_table(labels: np.ndarray, count: int, planes: dict, well):
+    """One family's feature table, one native pass after another, in the
+    column order of the step before its host pool."""
+    import pandas as pd
+
+    from tmlibrary_tpu import native
+
+    area_i, cy_sum, cx_sum, ymin, ymax, xmin, xmax = \
+        native.mosaic_morph_host(labels, count)
+    area = area_i[1:].astype(np.float64)
+    denom = np.maximum(area, 1)
+    cols = {"site_index": -1, "plate": well[0], "well_row": well[1],
+            "well_col": well[2], "site_y": -1, "site_x": -1,
+            "label": np.arange(1, count + 1, dtype=np.int64),
+            "Morphology_area": area,
+            "Morphology_centroid_y": cy_sum[1:] / denom,
+            "Morphology_centroid_x": cx_sum[1:] / denom,
+            "Morphology_bbox_height": (ymax[1:] - ymin[1:] + 1).astype(
+                np.float64),
+            "Morphology_bbox_width": (xmax[1:] - xmin[1:] + 1).astype(
+                np.float64),
+            "Morphology_solidity": native.solidity_host(
+                labels, count, areas=area).astype(np.float64)}
+    for stain, plane in planes.items():
+        s, q, mn, mx = native.mosaic_intensity_host(labels, plane, count)
+        mean = s[1:] / denom
+        cols[f"Intensity_mean_{stain}"] = mean
+        cols[f"Intensity_sum_{stain}"] = s[1:]
+        cols[f"Intensity_std_{stain}"] = np.sqrt(
+            np.maximum(q[1:] / denom - mean * mean, 0.0))
+        cols[f"Intensity_min_{stain}"] = np.where(area > 0, mn[1:], 0.0)
+        cols[f"Intensity_max_{stain}"] = np.where(area > 0, mx[1:], 0.0)
+    out = io.BytesIO()
+    pd.DataFrame(cols).to_parquet(out, index=False)
+    return out.getvalue()
+
+
+def test_the_pooled_outputs_are_the_serial_passes_byte_for_byte(units):
+    """Label stacks and both feature shards of a submit whose host work
+    ran side by side equal, byte for byte, what one pass after another
+    writes from the same labels and stitched planes."""
+    import pandas as pd
+
+    (store, events), (again, _) = units
+    # the experiment's channel order is the columns' order
+    planes = {c.name: serial_stitch(store, c.name)
+              for c in store.experiment.channels}
+    count = jterator_result(events)["objects"]["nuclei"]
+    for name in ("nuclei", "cells"):
+        stack = store.root / "segmentations" / f"{name}_t000_z000.npy"
+        assert stack.read_bytes() == (
+            again.root / "segmentations" / stack.name).read_bytes()
+        labels = reference_stitch(store.read_labels(None, name))
+        (shard,) = sorted((store.root / "features" / name).glob("*.parquet"))
+        head = pd.read_parquet(shard).iloc[0]
+        well = (str(head["plate"]), int(head["well_row"]),
+                int(head["well_col"]))
+        assert shard.read_bytes() == serial_table(labels, count, planes,
+                                                  well), name
+
+
+#: the cell's per-layer metrics read from the run ledger alone (the others
+#: read the device trace, or the harness's clocks and meters)
+LEDGER_READERS = (
+    "jterator_ms_per_site", "device_block_ms_per_site", "persist_ms_per_site",
+    "persist_fetch_ms_per_site", "persist_labels_ms_per_site",
+    "persist_features_ms_per_site", "persist_solidity_ms_per_site",
+    "h2d_mb_per_site", "feature_values_per_site", "mosaic_stitch_ms_per_site",
+    "mosaic_upload_ms_per_site", "mosaic_device_wait_ms_per_site",
+    "mosaic_measure_ms_per_site", "mosaic_seam_rounds", "mosaic_adopt_steps",
+    "mosaic_roots_max_per_shard",
+)
+
+
+@pytest.mark.parametrize("name", LEDGER_READERS)
+def test_the_cells_ledger_readers_read_the_pooled_spans(units, name):
+    from benchmark.drivers.plate import PlateRun, Unit
+
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    (metric,) = [m for m in bench["per_layer"] if m["name"] == name]
+    assert "cp3-mosaic.x4" in metric["workloads"]
+    _, events = units[0]
+    run = PlateRun(CONFIG, {"platform": "cpu"}, SIZE, SIZED["max_objects"])
+    unit = Unit("rehearsal", 9)
+    unit.events = events
+    run.units.append(unit)
+    module = harness.load_module(
+        str(REPO / "benchmark" / "metrics" / f"{name}.py"))
+    value = module.read(run)
+    assert isinstance(value, (int, float)) and value >= 0, value
+
+
+@pytest.mark.skipif((os.cpu_count() or 1) < 2, reason="one host thread")
+def test_two_intensity_passes_run_at_once(monkeypatch, tmp_path, devices):
+    """The first two intensity passes wait for each other: one pass after
+    another would break the barrier and fail the batch."""
+    from tmlibrary_tpu.workflow.steps import jterator
+
+    real = jterator._mosaic_intensity_stats
+    meet = threading.Barrier(2, timeout=60)
+    calls = []
+    lock = threading.Lock()
+
+    def both_at_once(*args):
+        with lock:
+            calls.append(threading.current_thread().name)
+            first_two = len(calls) <= 2
+        if first_two:
+            meet.wait()
+        return real(*args)
+
+    monkeypatch.setattr(jterator, "_mosaic_intensity_stats", both_at_once)
+    planes, _ = seam_planes(np.random.default_rng(7))
+    _, events = submit(tmp_path, "side", planes)
+    assert len(set(calls[:2])) == 2
+    assert jterator_result(events)["host_peak_concurrency"] >= 2
+
+
+def test_a_failed_host_task_fails_the_batch_and_ends_the_pool(
+        monkeypatch, tmp_path, devices):
+    from tmlibrary_tpu import native
+    from tmlibrary_tpu.models.store import ExperimentStore
+    from tmlibrary_tpu.workflow.steps.jterator import HOST_THREAD_PREFIX
+
+    def hull_fails(*args, **kw):
+        raise RuntimeError("hull fault")
+
+    monkeypatch.setattr(native, "solidity_host", hull_fails)
+    planes, _ = seam_planes(np.random.default_rng(8))
+    src = str(tmp_path / "src")
+    mosaic.write_well(src, plate.well_names(1)[0], planes, SIZE, FIELDS_X)
+    root = str(tmp_path / "fault")
+    harness.tmx(["create", "--name", "fault", "--root", root])
+    wf = mosaic.write_description(root, src, CONFIG, SIZED["max_objects"])
+    rc = harness.tmx_exit(["workflow", "submit", "--description", wf,
+                           "--root", root])
+    assert rc != 0
+    events = ledger.run_ledger(root)
+    failed = [e for e in events if e.get("event") == "batch_failed"
+              and e.get("step") == "jterator"]
+    assert failed and "hull fault" in json.dumps(failed)
+    assert not ledger.batch_results(events, "jterator")
+    assert not list((Path(root) / "features").glob("*/*.parquet"))
+    deadline = time.time() + 10
+    while time.time() < deadline and any(
+            t.name.startswith(HOST_THREAD_PREFIX)
+            for t in threading.enumerate()):
+        time.sleep(0.05)
+    assert not [t.name for t in threading.enumerate()
+                if t.name.startswith(HOST_THREAD_PREFIX)]
+    assert ExperimentStore.open(Path(root)).n_sites == 9
+
+
+def test_concurrent_first_calls_build_one_correction_program(monkeypatch):
+    import jax
+
+    from tmlibrary_tpu.workflow.steps import jterator
+
+    monkeypatch.setattr(jterator, "_CORRECT_JIT", None)
+    built = []
+    real = jax.jit
+
+    def slow_jit(fn, *args, **kw):
+        if getattr(fn, "__name__", "") == "correct_illumination":
+            built.append(fn)
+            time.sleep(0.2)     # a racing second caller would build too
+        return real(fn, *args, **kw)
+
+    monkeypatch.setattr(jax, "jit", slow_jit)
+    imgs = np.arange(2 * 8 * 8, dtype=np.uint16).reshape(2, 8, 8)
+    mean_log = np.full((8, 8), 1.5, np.float32)
+    std_log = np.full((8, 8), 0.5, np.float32)
+    start = threading.Barrier(6, timeout=30)
+
+    def first_call():
+        start.wait()
+        return jterator._correct_batch(imgs, mean_log, std_log)
+
+    threads_out = []
+    threads = [threading.Thread(target=lambda: threads_out.append(
+        first_call())) for _ in range(6)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(60)
+    assert len(threads_out) == 6
+    assert len(built) == 1
+    assert all(np.array_equal(out, threads_out[0]) for out in threads_out)
 
 
 @pytest.mark.parametrize("kind, requested, shape, want", [

@@ -601,6 +601,28 @@ def span_scope(step: str | None = None, batch: Any = None) -> Iterator[None]:
         _span_local.ctx = prev
 
 
+def carry_span_context(fn: Callable[..., Any]) -> Callable[..., Any]:
+    """``fn`` run under the calling thread's span context, on any thread.
+
+    For a task handed to a pool: the spans it opens on the worker carry
+    the submitter's ambient ``step``/``batch`` and enclosing span, as if
+    opened where it was submitted."""
+    ctx = getattr(_span_local, "ctx", None)
+    stack = _span_stack()
+    outer = stack[-1:]
+
+    def carried(*args: Any, **kwargs: Any) -> Any:
+        prev = (getattr(_span_local, "ctx", None),
+                getattr(_span_local, "stack", None))
+        _span_local.ctx, _span_local.stack = ctx, list(outer)
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _span_local.ctx, _span_local.stack = prev
+
+    return carried
+
+
 def _span_record(name, parent, t0: float, elapsed: float, attrs) -> dict:
     head = {"event": "span", "span": name, "t0": round(t0, 6),
             "elapsed": round(elapsed, 6),

@@ -15,6 +15,8 @@ host-traced polygons.  Metric: sites/sec/chip (BASELINE.json).
 
 from __future__ import annotations
 
+import concurrent.futures as cf
+import os
 import threading
 import time
 
@@ -45,6 +47,10 @@ def _mosaic_intensity_stats(labels, vals_mosaic, count):
 
 
 _CORRECT_JIT = None
+_CORRECT_LOCK = threading.Lock()
+
+#: thread names of a spatial batch's host pool (:class:`_HostTasks`)
+HOST_THREAD_PREFIX = "tmx-mosaic-host"
 
 
 def _well_shard(batch: dict) -> str:
@@ -109,17 +115,63 @@ def _correct_batch(imgs, mean_log, std_log) -> "np.ndarray":
     from tmlibrary_tpu.ops import image_ops
 
     global _CORRECT_JIT
-    if _CORRECT_JIT is None:
-        _CORRECT_JIT = jax.jit(
-            jax.vmap(image_ops.correct_illumination, in_axes=(0, None, None))
-        )
+    # stains are stitched side by side: two first calls must not build
+    # two programs (a second compile, in the window if it came late)
+    with _CORRECT_LOCK:
+        if _CORRECT_JIT is None:
+            _CORRECT_JIT = jax.jit(jax.vmap(
+                image_ops.correct_illumination, in_axes=(0, None, None)))
+        program = _CORRECT_JIT
     return np.asarray(
-        _CORRECT_JIT(
+        program(
             jnp.asarray(imgs, jnp.float32),
             jnp.asarray(mean_log),
             jnp.asarray(std_log),
         )
     )
+
+
+class _HostTasks:
+    """One spatial batch's host work as concurrent tasks: the stitches,
+    and per object type the label write, the morphology pass, the hulls
+    and one intensity pass a stain.  Each is a native call that drops
+    the GIL, so they run side by side on ``min(8, cpu_count)`` threads
+    (illuminati's PNG pool's size).  A task opens its spans under the
+    submitting thread's step, batch and enclosing span.  A task that
+    waits on another's future was submitted after it, so the pool's
+    FIFO queue has already started what it waits on."""
+
+    def __init__(self):
+        self._pool = cf.ThreadPoolExecutor(
+            max_workers=min(8, os.cpu_count() or 1),
+            thread_name_prefix=HOST_THREAD_PREFIX)
+        self._lock = threading.Lock()
+        self._running = 0
+        #: tasks submitted, and the most that ran at one instant
+        self.submitted = 0
+        self.peak = 0
+
+    def submit(self, fn, *args) -> cf.Future:
+        carried = telemetry.carry_span_context(fn)
+
+        def task():
+            with self._lock:
+                self._running += 1
+                self.peak = max(self.peak, self._running)
+            try:
+                return carried(*args)
+            finally:
+                with self._lock:
+                    self._running -= 1
+
+        with self._lock:
+            self.submitted += 1
+        return self._pool.submit(task)
+
+    def close(self) -> None:
+        """Drop what has not started and wait for what has: no thread
+        of the pool outlives the batch."""
+        self._pool.shutdown(wait=True, cancel_futures=True)
 
 
 def _host_shift(img: np.ndarray, dy: int, dx: int) -> np.ndarray:
@@ -805,11 +857,15 @@ class ImageAnalysisRunner(Step):
             # SiteResult is a registered pytree: block on all leaves
             jax.block_until_ready(payload[0])
             return
-        with telemetry.span("device_wait"):
-            jax.block_until_ready(payload["labels_dev"])
-            jax.block_until_ready(payload["count_dev"])
-            if payload["sec"] is not None:
-                jax.block_until_ready(payload["sec"][2])
+        try:
+            with telemetry.span("device_wait"):
+                jax.block_until_ready(payload["labels_dev"])
+                jax.block_until_ready(payload["count_dev"])
+                if payload["sec"] is not None:
+                    jax.block_until_ready(payload["sec"][2])
+        except BaseException:
+            payload["tasks"].close()
+            raise
 
     @property
     def persist_serial(self) -> bool:
@@ -919,7 +975,11 @@ class ImageAnalysisRunner(Step):
     def _prefetch_spatial(self, batch: dict) -> dict:
         """Host half of the spatial launch: resolve the well geometry and
         stitch the segmentation channel's mosaic (store reads + host
-        assembly) ahead of device dispatch."""
+        assembly) ahead of device dispatch.  With a secondary channel
+        every stain is stitched at once on the batch's host pool (each
+        mosaic is used at least twice, so all are kept anyway): the
+        launch waits for the two it segments, the others are read,
+        corrected and laid out while the device segments."""
         args = batch["args"]
         sites = batch["sites"]
         exp = self.store.experiment
@@ -930,15 +990,47 @@ class ImageAnalysisRunner(Step):
         h, w = exp.site_height, exp.site_width
         n_sy = max(r.site_y for r in srefs) + 1
         n_sx = max(r.site_x for r in srefs) + 1
-        mosaic = self._stitched_channel(sites, srefs, idx, args, n_sy, n_sx, h, w)
-        valid = self._stitch_validity(sites, srefs, args, n_sy, n_sx, h, w,
-                                      self._channel_cycle(args, ch_name))
+        tasks = _HostTasks()
+        try:
+            stitches = {}
+            sec_ch = args.get("spatial_secondary_channel", "")
+            if sec_ch:
+                # the two segmented stains first: the pool starts its
+                # queue in order
+                for i in dict.fromkeys(
+                        [idx, exp.channel_index(sec_ch)]
+                        + [c.index for c in exp.channels]):
+                    stitches[i] = tasks.submit(
+                        self._stitched_channel, sites, srefs, i, args,
+                        n_sy, n_sx, h, w)
+                mosaic = stitches[idx].result()
+            else:
+                mosaic = self._stitched_channel(
+                    sites, srefs, idx, args, n_sy, n_sx, h, w)
+            valid = self._stitch_validity(sites, srefs, args, n_sy, n_sx,
+                                          h, w, self._channel_cycle(args,
+                                                                    ch_name))
+        except BaseException:
+            tasks.close()
+            raise
         return {
             "idx": idx, "channel": ch_name, "srefs": srefs, "h": h, "w": w,
             "n_sy": n_sy, "n_sx": n_sx, "mosaic": mosaic, "valid": valid,
+            "tasks": tasks, "stitches": stitches,
         }
 
     def _launch_spatial(self, batch: dict, prefetched: dict | None = None) -> dict:
+        """:meth:`_dispatch_spatial` of the prefetched well; a launch that
+        fails shuts the batch's host pool down."""
+        if prefetched is None:
+            prefetched = self._prefetch_spatial(batch)
+        try:
+            return self._dispatch_spatial(batch, prefetched)
+        except BaseException:
+            prefetched["tasks"].close()
+            raise
+
+    def _dispatch_spatial(self, batch: dict, prefetched: dict) -> dict:
         """Whole-mosaic segmentation of one well (``--layout spatial``) —
         the LAUNCH half: host stitch + async device dispatch (primary
         segmentation and, when configured, the chained secondary
@@ -976,8 +1068,6 @@ class ImageAnalysisRunner(Step):
         exp = self.store.experiment
         tpoint, zplane = args["tpoint"], args["zplane"]
 
-        if prefetched is None:
-            prefetched = self._prefetch_spatial(batch)
         idx = prefetched["idx"]
         srefs = prefetched["srefs"]
         h, w = prefetched["h"], prefetched["w"]
@@ -1027,22 +1117,21 @@ class ImageAnalysisRunner(Step):
         # whole plane, and nothing is re-sharded on the device
         sharding = NamedSharding(mesh, PartitionSpec(*mesh.axis_names))
 
-        # with a secondary channel every stitched mosaic is used at least
-        # twice (watershed input + both families' intensity loops), so
-        # memoize — accepting a peak of one mosaic per channel.  Without
-        # one, each channel is read exactly once: caching would only
-        # regress peak memory (plate-scale mosaics are GBs each), so
-        # stitch on demand and let each mosaic go out of scope.
+        # with a secondary channel every stain was stitched ahead on the
+        # host pool (each mosaic is used at least twice: the watershed's
+        # input and both families' intensity passes).  Without one, each
+        # stain is read exactly once: keeping it would only regress peak
+        # memory (plate-scale mosaics are GBs each), so stitch on demand
+        # and let each mosaic go out of scope.
         sec_ch = args.get("spatial_secondary_channel", "")
-        stitched = {idx: mosaic}
+        stitches = prefetched["stitches"]
 
         def get_channel(i: int) -> np.ndarray:
-            if i in stitched:
-                return stitched[i]
-            m = self._stitched_channel(sites, srefs, i, args, n_sy, n_sx, h, w)
-            if sec_ch:
-                stitched[i] = m
-            return m
+            if i in stitches:
+                return stitches[i].result()
+            if i == idx:
+                return mosaic
+            return self._stitched_channel(sites, srefs, i, args, n_sy, n_sx, h, w)
 
         sec_np = None
         if sec_ch:
@@ -1100,6 +1189,7 @@ class ImageAnalysisRunner(Step):
             "sites": sites, "srefs": srefs, "mesh_shape": [nr, nc],
             "tpoint": tpoint, "zplane": zplane, "h2d_bytes": h2d_bytes,
             "info": info, "adopt_steps_dev": steps_dev, "otsu_dev": otsu,
+            "tasks": prefetched["tasks"], "stitched_ahead": bool(stitches),
         }
 
     def _persist_spatial(self, batch: dict, ctx: dict) -> dict:
@@ -1107,12 +1197,19 @@ class ImageAnalysisRunner(Step):
         the host half of the stitch → device → write overlap
         (``run_batches_pipelined`` launches well N+1's stitch while this
         blocks on well N's arrays).  Peak memory holds two wells'
-        mosaics while the pipeline is full."""
+        mosaics while the pipeline is full.  The measurements of both
+        object families run side by side on the batch's host pool; the
+        pool is shut down before this returns or raises."""
+        tasks = ctx["tasks"]
+        try:
+            return self._write_spatial(tasks, batch, ctx)
+        finally:
+            tasks.close()
+
+    def _write_spatial(self, tasks: _HostTasks, batch: dict,
+                       ctx: dict) -> dict:
         args = batch["args"]
         sites = ctx["sites"]
-        srefs = ctx["srefs"]
-        tpoint, zplane = ctx["tpoint"], ctx["zplane"]
-        get_channel = ctx["get_channel"]
         with telemetry.span("fetch") as fetched:
             labels = np.asarray(ctx["labels_dev"])
             count = int(ctx["count_dev"])
@@ -1127,34 +1224,30 @@ class ImageAnalysisRunner(Step):
             fetched["otsu_cut"] = otsu_cut
         shard = _well_shard(batch)
 
-        def emit_figure(fam_name, fam_mosaic, fam_labels):
-            if not args.get("figures"):
-                return
-            from tmlibrary_tpu.jterator.figures import write_mosaic_figure
-
-            write_mosaic_figure(
-                self.store.root / "figures", fam_name, fam_mosaic,
-                fam_labels, shard,
-            )
-
-        name = args["spatial_objects"]
-        self._persist_mosaic_objects(
-            name, labels, count, batch, args, sites, srefs, get_channel,
-            tpoint, zplane, shard,
-        )
-        objects = {name: count}
-        emit_figure(name, ctx["mosaic"], labels)
-
+        # (name, labels, the plane its figure is drawn on); watershed
+        # preserves seed ids: the secondary family's id space (and count)
+        # is the primary's, so features join across the two families
+        families = [(args["spatial_objects"], labels, ctx["mosaic"])]
         if sec_labels is not None:
-            sec_name, sec_np, _ = ctx["sec"]
-            # watershed preserves seed ids: the id space (and count) is
-            # the primary's, so features join across the two families
-            self._persist_mosaic_objects(
-                sec_name, sec_labels, count, batch, args, sites, srefs,
-                get_channel, tpoint, zplane, shard,
-            )
-            objects[sec_name] = count
-            emit_figure(sec_name, sec_np, sec_labels)
+            families.append((ctx["sec"][0], sec_labels, ctx["sec"][1]))
+        measured = [
+            self._measure_mosaic_objects(tasks, fam_name, fam_labels, count,
+                                         ctx)
+            for fam_name, fam_labels, _ in families
+        ]
+        objects = {}
+        for (fam_name, fam_labels, fam_mosaic), futures in zip(families,
+                                                              measured):
+            self._write_mosaic_objects(fam_name, fam_labels, count, batch,
+                                       args, futures, shard)
+            objects[fam_name] = count
+            if args.get("figures"):
+                from tmlibrary_tpu.jterator.figures import write_mosaic_figure
+
+                write_mosaic_figure(
+                    self.store.root / "figures", fam_name, fam_mosaic,
+                    fam_labels, shard,
+                )
 
         self._note_sites(len(sites))
         summary = {
@@ -1166,6 +1259,9 @@ class ImageAnalysisRunner(Step):
             # bytes handed to the device: every plane, once, to its shards
             "h2d_bytes": ctx["h2d_bytes"],
             "otsu_cut": otsu_cut,
+            # the batch's host pool: tasks run, and the most at one instant
+            "host_tasks": tasks.submitted,
+            "host_peak_concurrency": tasks.peak,
         }
         # what the sharded programs counted (a 1-device CPU mesh runs the
         # native union-find and frontier flood, which count nothing): the
@@ -1175,75 +1271,122 @@ class ImageAnalysisRunner(Step):
         if ctx["adopt_steps_dev"] is not None:
             counted["adopt_steps"] = int(ctx["adopt_steps_dev"])
         summary.update(counted)
-        if counted and telemetry.enabled():
+        if telemetry.enabled():
             reg = telemetry.get_registry()
+            reg.gauge("tmx_jterator_mosaic_host_peak_concurrency").set(
+                tasks.peak)
             for key in ("seam_rounds", "adopt_steps"):
                 if key in counted:
                     reg.counter(
                         f"tmx_jterator_mosaic_{key}_total").inc(counted[key])
-            reg.gauge("tmx_jterator_mosaic_roots_max_per_shard").set(
-                counted["roots_max_per_shard"])
+            if counted:
+                reg.gauge("tmx_jterator_mosaic_roots_max_per_shard").set(
+                    counted["roots_max_per_shard"])
         return summary
 
-    def _persist_mosaic_objects(
-        self, name, labels, count, batch, args, sites, srefs,
-        get_channel, tpoint, zplane, shard,
-    ) -> None:
-        """Persist one mosaic-scale object family: per-site label stacks
-        carrying the global ids, the ragged host-side feature table
-        (morphology + per-channel intensity + Zernike), and optional
-        mosaic-frame polygons.  ``get_channel(i)`` returns the stitched
-        (corrected) mosaic of channel ``i`` — memoized by the caller so
-        families share one stitch per channel."""
-        import pandas as pd
+    def _measure_mosaic_objects(
+        self, tasks: _HostTasks, name, labels, count, ctx: dict,
+    ) -> dict:
+        """Submit one mosaic-scale object family's host work to the
+        batch's pool and return its futures: the per-site label stacks
+        carrying the global ids, one native morphology pass (area,
+        centroid sums, bounding boxes), the hulls, one intensity pass a
+        stain and the Zernike moments.  ``ctx["get_channel"](i)``
+        returns the stitched (corrected) mosaic of channel ``i``: a
+        stitch future's result where the stains were stitched ahead,
+        else a stitch on demand — then the passes run one after another,
+        so that one such mosaic is alive at a time."""
+        from tmlibrary_tpu import native as native_mod
 
         exp = self.store.experiment
         h, w = exp.site_height, exp.site_width
-        per_site = np.stack([
-            labels[r.site_y * h:(r.site_y + 1) * h,
-                   r.site_x * w:(r.site_x + 1) * w]
-            for r in srefs
-        ])
-        with telemetry.span("write_labels"):
-            self.store.write_labels(per_site, sites, name,
-                                    tpoint=tpoint, zplane=zplane)
+        args = ctx["batch"]["args"]
+        get_channel = ctx["get_channel"]
 
-        # ragged global features, host-side (object count is dynamic here —
-        # nothing is padded to max_objects in the mosaic path).  ONE
-        # native C pass over the mosaic (area + centroid sums + bounding
-        # boxes), chunked-vectorized numpy fallback — no O(H)
-        # interpreter loop on a plate-scale mosaic.
-        from tmlibrary_tpu import native as native_mod
+        def write_labels():
+            per_site = np.stack([
+                labels[r.site_y * h:(r.site_y + 1) * h,
+                       r.site_x * w:(r.site_x + 1) * w]
+                for r in ctx["srefs"]
+            ])
+            with telemetry.span("write_labels"):
+                self.store.write_labels(per_site, ctx["sites"], name,
+                                        tpoint=ctx["tpoint"],
+                                        zplane=ctx["zplane"])
 
-        with telemetry.span("morph"):
-            area_i, cy_sum, cx_sum, ymin, ymax, xmin, xmax = (
-                native_mod.mosaic_morph_host(labels, count)
+        def morph():
+            # ragged global features, host-side (object count is dynamic
+            # here — nothing is padded to max_objects in the mosaic path)
+            with telemetry.span("morph"):
+                return native_mod.mosaic_morph_host(labels, count)
+
+        def solidity(morphed):
+            area = morphed.result()[0][1:].astype(np.float64)
+            with telemetry.span("solidity"):
+                return native_mod.solidity_host(
+                    labels, count, areas=area
+                ).astype(np.float64)
+
+        def intensity(ch, after):
+            if after is not None:
+                after.result()
+            vals_mosaic = get_channel(ch.index)
+            with telemetry.span("intensity", channel=ch.name):
+                return _mosaic_intensity_stats(labels, vals_mosaic, count)
+
+        futures = {"morph": tasks.submit(morph)}
+        # hull solidity uses the native helper when the library built; its
+        # pure-python fallback is O(count * H * W) — at mosaic scale that
+        # is effectively a hang, so degrade to NaN instead
+        futures["solidity"] = None
+        if count and native_mod.available():
+            futures["solidity"] = tasks.submit(solidity, futures["morph"])
+        elif count:
+            logger.info(
+                "native library unavailable: mosaic solidity emitted "
+                "as NaN (the python hull fallback is quadratic at "
+                "mosaic scale)"
             )
+        futures["write_labels"] = tasks.submit(write_labels)
+        passes, after = [], None
+        if count:
+            for ch in exp.channels:
+                passes.append(tasks.submit(intensity, ch, after))
+                if not ctx["stitched_ahead"]:
+                    after = passes[-1]
+        futures["intensity"] = passes
+        # shape moments: the public ragged host Zernike handles a dynamic
+        # object count in row blocks (mahotas semantics; default degree 9
+        # matches the sites layout's measure_zernike default, 0 disables)
+        futures["zernike"] = None
+        if args["spatial_zernike_degree"] > 0:
+            from tmlibrary_tpu.ops.measure import zernike_host_features
+
+            futures["zernike"] = tasks.submit(
+                zernike_host_features, labels, count,
+                args["spatial_zernike_degree"])
+        return futures
+
+    def _write_mosaic_objects(
+        self, name, labels, count, batch, args, futures: dict, shard,
+    ) -> None:
+        """One family's feature table from its measured futures, in the
+        column order of the serial pass, written as the well's Parquet
+        shard; then the optional mosaic-frame polygons.  The first task
+        that failed raises here."""
+        import pandas as pd
+
+        futures["write_labels"].result()
+        area_i, cy_sum, cx_sum, ymin, ymax, xmin, xmax = (
+            futures["morph"].result())
         area = area_i[1:].astype(np.float64)
         denom = np.maximum(area, 1)
         cy = cy_sum[1:] / denom
         cx = cx_sum[1:] / denom
         bbox_h = (ymax[1:] - ymin[1:] + 1).astype(np.float64)
         bbox_w = (xmax[1:] - xmin[1:] + 1).astype(np.float64)
-
-        # hull solidity uses the native helper when the library built; its
-        # pure-python fallback is O(count * H * W) — at mosaic scale that
-        # is effectively a hang, so degrade to NaN instead
-        from tmlibrary_tpu import native as native_mod
-
-        if count and native_mod.available():
-            with telemetry.span("solidity"):
-                solidity = native_mod.solidity_host(
-                    labels, count, areas=area
-                ).astype(np.float64)
-        else:
-            if count:
-                logger.info(
-                    "native library unavailable: mosaic solidity emitted "
-                    "as NaN (the python hull fallback is quadratic at "
-                    "mosaic scale)"
-                )
-            solidity = np.full(count, np.nan)
+        solidity = (np.full(count, np.nan) if futures["solidity"] is None
+                    else futures["solidity"].result())
         plate, well_row, well_col = batch["well"]
         cols = {
             "site_index": -1,  # mosaic objects may span several sites
@@ -1261,20 +1404,16 @@ class ImageAnalysisRunner(Step):
             "Morphology_solidity": solidity,
         }
         # intensity over EVERY channel (sites-layout parity:
-        # measure_intensity per channel), one stitched mosaic at a time;
-        # the segmentation channel reuses the already-corrected stitch.
-        # Zero-object wells still emit the (empty) columns so every
-        # well's parquet shard carries the same schema.
-        for ch in exp.channels:
+        # measure_intensity per channel).  Zero-object wells still emit
+        # the (empty) columns so every well's parquet shard carries the
+        # same schema.
+        for i, ch in enumerate(self.store.experiment.channels):
             if count == 0:
                 empty = np.zeros(0)
                 for stat in ("mean", "sum", "std", "min", "max"):
                     cols[f"Intensity_{stat}_{ch.name}"] = empty
                 continue
-            vals_mosaic = get_channel(ch.index)
-            with telemetry.span("intensity", channel=ch.name):
-                s2, q2, mn2, mx2 = _mosaic_intensity_stats(
-                    labels, vals_mosaic, count)
+            s2, q2, mn2, mx2 = futures["intensity"][i].result()
             mean2 = s2[1:] / denom
             var2 = np.maximum(q2[1:] / denom - mean2 * mean2, 0.0)
             cols[f"Intensity_mean_{ch.name}"] = mean2
@@ -1282,18 +1421,12 @@ class ImageAnalysisRunner(Step):
             cols[f"Intensity_std_{ch.name}"] = np.sqrt(var2)
             cols[f"Intensity_min_{ch.name}"] = np.where(area > 0, mn2[1:], 0.0)
             cols[f"Intensity_max_{ch.name}"] = np.where(area > 0, mx2[1:], 0.0)
-        # shape moments: the public ragged host Zernike handles a dynamic
-        # object count in row blocks (mahotas semantics; default degree 9
-        # matches the sites layout's measure_zernike default, 0 disables)
-        z_degree = args["spatial_zernike_degree"]
-        if z_degree > 0:
-            from tmlibrary_tpu.ops.measure import (
-                _zernike_coeffs,
-                zernike_host_features,
-            )
+        if futures["zernike"] is not None:
+            from tmlibrary_tpu.ops.measure import _zernike_coeffs
 
-            zern = zernike_host_features(labels, count, z_degree)
-            for z_idx, (n_z, m_z, _) in enumerate(_zernike_coeffs(z_degree)):
+            zern = futures["zernike"].result()
+            for z_idx, (n_z, m_z, _) in enumerate(
+                    _zernike_coeffs(args["spatial_zernike_degree"])):
                 cols[f"Zernike_{n_z}_{m_z}"] = zern[:, z_idx].astype(np.float64)
         with telemetry.span("write_features") as written:
             table = pd.DataFrame(cols)
