@@ -1,7 +1,10 @@
 """The plain reference of ``cp3-serve``: k nearest neighbours by float64
-brute force in numpy over the standardized feature matrix the query named
-(the store's own matrix: "the same matrix"), after ``chip_smoke.py``'s
-``phase_serve``.
+brute force in numpy over the feature matrix the query named, read from
+the experiment's raw feature shards and z-scored here in float64
+(``feature_matrix``), after ``chip_smoke.py``'s ``phase_serve``.  It takes
+nothing the program prepared: a wrong mean or scale, a wrong column or a
+wrong object order in the program's standardization reads as a wrong
+answer.
 
 What "equal" can mean.  The system evaluates ``|q|^2 - 2 q.x + |x|^2`` in
 float32, so a squared distance carries an error of a few ulps of the
@@ -20,7 +23,10 @@ of magnitude.
   slot, or one whose float64 squared distance differs from that one's by
   less than the resolution of the two (a tie float32 cannot decide)."""
 
+from pathlib import Path
+
 import numpy as np
+import pandas as pd
 
 #: float32 ulps of |q|^2 + |x|^2 allowed on a squared distance
 RESOLUTION = 16.0
@@ -30,6 +36,36 @@ RESOLUTION = 16.0
 #: stands between (PERF.md section 2): the program's worst over its runs
 #: on the chip, and the least the control below reads
 LIMIT_UNITS = 1.0
+
+
+def feature_matrix(experiment, objects: str, features: list) -> np.ndarray:
+    """The matrix a query over ``features`` of ``objects`` runs on, float64:
+    the experiment's feature shards (``features/<objects>/*.parquet``) in
+    name order, the object order the store states, each value as the store
+    keeps it (float32), z-scored here (``standardize``)."""
+    shards = sorted((Path(experiment) / "features" / objects).glob("*.parquet"))
+    if not shards:
+        raise FileNotFoundError(f"no feature shards of {objects!r}")
+    raw = pd.concat([pd.read_parquet(p, columns=list(features))
+                     for p in shards], ignore_index=True)
+    return standardize(raw[list(features)].to_numpy(np.float32))
+
+
+def standardize(raw: np.ndarray) -> np.ndarray:
+    """z-scores of each column in float64: a cell that is not finite takes
+    the mean of its column's finite cells (0 where there is none) before
+    the mean and the population deviation are taken; a column whose
+    deviation is under 1e-9 is only centred."""
+    x = np.asarray(raw, np.float64)
+    finite = np.isfinite(x)
+    if not finite.all():
+        counts = finite.sum(0)
+        sums = np.where(finite, x, 0.0).sum(0)
+        fill = np.divide(sums, counts, out=np.zeros(x.shape[1]),
+                         where=counts > 0)
+        x = np.where(finite, x, fill[None, :])
+    sd = x.std(0)
+    return (x - x.mean(0)) / np.where(sd > 1e-9, sd, 1.0)
 
 
 def brute_knn(x: np.ndarray, k: int) -> tuple:
@@ -81,7 +117,7 @@ def control_answer(x: np.ndarray, k: int, cross=None) -> dict:
 
 def check_answer(values, x: np.ndarray, k: int) -> dict:
     """One kNN answer (the ``ToolResult.values`` frame) against brute
-    force on ``x``, the matrix in the store's canonical object order."""
+    force on ``x``, the matrix ``feature_matrix`` reads."""
     d2, ref_idx = brute_knn(x, k)
     got_idx = np.stack([np.asarray(values[f"nn{j}"]) for j in range(k)], 1)
     got_dist = np.stack([np.asarray(values[f"nnd{j}"]) for j in range(k)], 1)

@@ -4,14 +4,15 @@ the entries' files are.  A later PR appends a cell, joins it to some of
 the lists and brings metrics of its own; every check here is made on the
 file as committed, on a copy with the pending serve entries
 (``benchmark/pending/cp3-serve.entries.json``: the cell is built and
-measured, and stays out until the program's fault that fails its
-``correct`` is repaired, PERF.md section 7) laid after it, AND on a copy
+measured, and stays out until its store's size and its traffic are
+settled, PERF.md section 7) laid after it, AND on a copy
 with a made-up cell and metric appended after those — so a check that
 would stop an append fails here first.  Nothing here needs a chip."""
 
 import copy
 import json
 import shutil
+import subprocess
 import sys
 from pathlib import Path
 
@@ -284,9 +285,9 @@ def test_run_py_gives_each_cell_the_metrics_that_list_it(entered):
 
 # ------------------------------------------------ what is pending, and why
 def test_the_serve_cell_is_pending_and_not_in_the_committed_file():
-    """It stays out while a run of it can come out not correct through a
-    fault of the program (PERF.md section 7); its entries wait where
-    ``run.py --entries`` finds them."""
+    """It stays out until its store's size and its traffic are settled
+    (PERF.md section 7); its entries wait where ``run.py --entries``
+    finds them."""
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     pending = json.loads((REPO / PENDING).read_text())
     assert SERVE not in [w["name"] for w in bench["workloads"]]
@@ -300,6 +301,29 @@ def test_the_serve_cell_is_pending_and_not_in_the_committed_file():
     assert not set(names) & {m["name"] for m in
                              bench["end_to_end"] + bench["per_layer"]}
     assert not (REPO / "tests" / "benchmark" / "conftest.py").exists()
+
+
+def test_entries_are_laid_after_the_committed_file_list_by_list():
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    pending = json.loads((REPO / PENDING).read_text())
+    laid = harness.load_benchmark(PENDING)
+    for group in ("configs", "workloads", "end_to_end", "per_layer"):
+        assert laid[group] == bench[group] + pending[group]
+    assert harness.load_benchmark() == bench
+
+
+def test_run_py_finds_a_cell_of_an_entries_file_by_name():
+    """``run.py --entries``: an unknown name exits 2 before JAX is
+    touched, naming every cell it knows — the committed ones and those
+    of the entries file."""
+    argv = [sys.executable, str(REPO / "benchmark" / "run.py"),
+            "--workload", "no-such-cell", "--seed", "1", "--seconds", "1"]
+    for extra, knows_serve in (([], False), (["--entries", PENDING], True)):
+        done = subprocess.run(argv + extra, cwd=REPO, capture_output=True,
+                              text=True, timeout=120)
+        assert done.returncode == 2 and done.stdout == ""
+        assert CP4 in done.stderr and CP3[0] in done.stderr
+        assert (SERVE in done.stderr) == knows_serve
 
 
 # ------------------------------- the serve cell's end-to-end metrics by hand

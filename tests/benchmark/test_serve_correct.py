@@ -3,7 +3,8 @@ control (the reference in the program's place, one precision down) reads
 over the limit at a size a test can hold, a float32 evaluation reads under
 it, an altered answer is caught by the comparison — and a whole run of the
 harness over a program with the fault planted where the answer is
-produced ends with ``correct`` false, where the sound program's ends true.
+produced ends with ``correct`` false, where the sound program's ends true,
+and the reference makes its matrix from the raw shards itself.
 The chip readings the limit stands between are in PERF.md section 2."""
 
 import json
@@ -13,6 +14,7 @@ import sys
 from pathlib import Path
 
 import numpy as np
+import pandas as pd
 import pytest
 
 REPO = Path(__file__).resolve().parents[2]
@@ -88,8 +90,73 @@ def test_brute_force_is_nearest_first_with_ties_by_row_order():
     assert idx[3, 0] == 7 and idx[7, 0] == 3
 
 
+def raw_table(seed: int, rows: int) -> pd.DataFrame:
+    """Raw intensities as a shard holds them: large offsets over small
+    spreads, float64, with a NaN and an infinite cell."""
+    rng = np.random.default_rng(seed)
+    table = pd.DataFrame({
+        "site_index": 0, "plate": "plate00", "well_row": 0, "well_col": 0,
+        "label": np.arange(1, rows + 1),
+        "Intensity_mean_DAPI": rng.normal(3000.0, 40.0, rows),
+        "Intensity_max_DAPI": np.exp(rng.normal(8.0, 0.3, rows)),
+        "Intensity_sum_DAPI": rng.normal(2.0e6, 1.0e5, rows)})
+    table.loc[3, "Intensity_mean_DAPI"] = np.nan
+    table.loc[5, "Intensity_max_DAPI"] = np.inf
+    return table
+
+
+def test_the_reference_reads_the_shards_in_name_order(tmp_path):
+    """``feature_matrix`` concatenates ``features/<objects>/*.parquet`` by
+    name, takes the query's columns in the query's order and z-scores the
+    float32 values it finds there in float64."""
+    shards = tmp_path / "features" / "nuclei"
+    shards.mkdir(parents=True)
+    first, second = raw_table(3000000907, 40), raw_table(3000000908, 30)
+    second.to_parquet(shards / "batch_001.parquet", index=False)
+    first.to_parquet(shards / "batch_000.parquet", index=False)
+    names = ["Intensity_sum_DAPI", "Intensity_mean_DAPI"]
+    x = reference.feature_matrix(tmp_path, "nuclei", names)
+    raw = pd.concat([first, second], ignore_index=True)[names]
+    assert x.dtype == np.float64 and x.shape == (70, 2)
+    assert np.array_equal(x, reference.standardize(
+        raw.to_numpy(np.float32)))
+    with pytest.raises(FileNotFoundError):
+        reference.feature_matrix(tmp_path, "cells", names)
+
+
+def test_the_reference_standardizes_as_the_store_states(tmp_path):
+    """The reference's z-scores agree with the store's float32 ones to
+    float32 resolution (the same imputation, mean and population
+    deviation), and a store whose mean, scale, columns or object order
+    is wrong lies far off them."""
+    from tmlibrary_tpu.analytics.store import FeatureStore
+    from tmlibrary_tpu.models.experiment import grid_experiment
+    from tmlibrary_tpu.models.store import ExperimentStore
+
+    exp = grid_experiment(name="serve-reference", well_rows=1, well_cols=1,
+                          sites_per_well=(1, 1), site_shape=(8, 8))
+    store = ExperimentStore.create(tmp_path / "exp", exp)
+    store.append_features("nuclei", raw_table(3000000909, 300), "batch_000")
+    store.append_features("nuclei", raw_table(3000000910, 200), "batch_001")
+    names = ["Intensity_max_DAPI", "Intensity_mean_DAPI",
+             "Intensity_sum_DAPI"]
+    _, program, _ = FeatureStore.ensure(store, "nuclei").standardized(names)
+    x = reference.feature_matrix(store.root, "nuclei", names)
+    assert np.isfinite(x).all()
+    assert abs(x[3, 1]) < 1e-12              # imputed at the finite mean
+    np.testing.assert_allclose(x.mean(0), 0.0, atol=1e-12)
+    np.testing.assert_allclose(x.std(0), 1.0, rtol=1e-12)
+    gap = np.abs(program - x).max()
+    assert gap < 1e-5
+    for wrong in (program + 1e-3, program * 1.001, program[:, ::-1],
+                  np.roll(program, 1, axis=0)):
+        assert np.abs(wrong - x).max() > 100.0 * gap
+
+
 @pytest.mark.parametrize("fault, correct", [("none", True),
-                                            ("answer_altered", False)])
+                                            ("answer_altered", False),
+                                            ("half_left_out", False),
+                                            ("rows_misordered", False)])
 def test_a_whole_run_is_correct_only_without_the_fault(fault, correct,
                                                        tmp_path):
     """``run.main`` from argument parsing to the result line, in a
